@@ -1,13 +1,10 @@
-"""deprecated-api: version-drifting JAX spellings, allow/deny table.
+"""deprecated-api: removed / superseded JAX spellings, allow/deny table.
 
-The concrete motivating case: ``jax.shard_map`` exists only on new JAX
-and ``jax.experimental.shard_map`` only on old — spelling either one
-directly makes the package version-bound (this exact drift broke 3
-tier-1 tests across 5 call sites before ``jax_compat.shard_map``
-centralized it). The table also covers the removed xmap-era APIs and the
-pjit axis-resources spellings. The shim module itself carries an inline
-``# graftlint: disable=deprecated-api`` — the one place a drifting
-spelling is allowed to live.
+The repo is written for the one installation there is (``pyproject.toml``
+pins it): ``jax.shard_map`` is the spelling, and the table below denies
+what that installation deprecates or no longer has —
+``jax.experimental.shard_map`` (raises a ``DeprecationWarning``), the
+xmap-era APIs, the pjit axis-resources spellings, ``jax.tree_map``.
 """
 
 from __future__ import annotations
@@ -24,14 +21,7 @@ from marl_distributedformation_tpu.analysis.linter import (
 # Dotted-name prefixes -> guidance. Matched against attribute chains and
 # import statements; the longest (most specific) match wins.
 DENYLIST = {
-    "jax.shard_map": (
-        "exists only on jax >= 0.6 — route through "
-        "marl_distributedformation_tpu.jax_compat.shard_map"
-    ),
-    "jax.experimental.shard_map": (
-        "removed on new jax — route through "
-        "marl_distributedformation_tpu.jax_compat.shard_map"
-    ),
+    "jax.experimental.shard_map": "deprecated; use jax.shard_map",
     "jax.experimental.maps": "xmap-era API, removed from jax",
     "jax.experimental.pjit": (
         "use jax.jit with in_shardings/out_shardings"
@@ -56,7 +46,7 @@ class DeprecatedApi(Rule):
     name = "deprecated-api"
     default_severity = "error"
     description = (
-        "version-drifting / removed JAX API spelling — see the "
+        "removed / superseded JAX API spelling — see the "
         "allow/deny table in analysis/rules/deprecated.py"
     )
 

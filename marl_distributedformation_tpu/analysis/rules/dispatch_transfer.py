@@ -5,8 +5,8 @@ engine/registry construction and the reload coordinator's commit — the
 once-per-SWAP placement events. A ``device_put`` inside a dispatch loop
 (the ``while``-loop shape every serve/poll worker in this repo has) is
 the per-request spelling of the same call: a full host->device weight
-upload on EVERY iteration, which on a tunneled TPU is a full RTT per
-request and silently caps throughput at the PCIe/link rate — the
+upload on EVERY iteration, which puts a host->device copy in front of
+every request and silently caps throughput at the PCIe/link rate — the
 serving twin of the per-iteration host-sync hazards rules 4 and 12
 police on the training side. The fix is always the same: hoist the
 placement to the swap/commit seam (``ModelRegistry.refresh``,

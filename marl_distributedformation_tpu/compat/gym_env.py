@@ -103,8 +103,8 @@ class FormationGymEnv(gym.Env):
         self._steps += 1
         # ONE device fetch for the whole transition: per-field np.asarray
         # would pay ~a dozen blocking round trips per step (obs, reward,
-        # done, each metric) — ruinous on a tunneled device for exactly
-        # the per-step external training loops this adapter serves.
+        # done, each metric) — ruinous for exactly the per-step external
+        # training loops this adapter serves.
         tr = jax.device_get(tr)
         done = bool(tr.done[0])
         # Timeout-only episodes (Q3) are truncation in gymnasium terms. A

@@ -26,12 +26,12 @@ it structurally (no dot_general in the lowering).
 
 from __future__ import annotations
 
+import functools
+import sys
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-
-from marl_distributedformation_tpu.jax_compat import manual_axis_context
 
 Array = jax.Array
 
@@ -133,64 +133,66 @@ def _resolve_auto_impl(points: Array) -> str:
     """The ``impl="auto"`` dispatch predicate, factored out so tests can
     pin the backend: on TPU, the fused kernel when the whole per-formation
     problem fits VMEM (N <= 640), the chunked-streaming kernel beyond that
-    (no N ceiling); xla on other backends or when the SPMD partitioner
+    (N <= 16384); xla on other backends or when the SPMD partitioner
     controls the batch (a pallas_call is a Mosaic custom call it cannot
-    split; shard_map-wrapped callers re-enter with local blocks)."""
+    split; shard_map-wrapped callers re-enter with local blocks).
+    Interpret mode is never chosen here — it is a CPU-test spelling only
+    (``impl="pallas_interpret"``)."""
+    return _resolve_auto(points)[0]
+
+
+def _resolve_auto(points: Array) -> Tuple[str, str]:
+    """``(impl, why)`` for ``impl="auto"``; ``why`` is what
+    :func:`knn_batch` prints, so no choice made from the platform or the
+    placement is silent."""
     from marl_distributedformation_tpu.ops.knn_pallas import (
         fits_big_kernel,
         fits_vmem,
     )
 
-    if jax.default_backend() != "tpu" or _spmd_partitioner_controlled(
-        points
-    ):
-        return "xla"
     n = points.shape[1]
+    backend = jax.default_backend()
+    if backend != "tpu":
+        return "xla", f"backend is {backend}, the Pallas kernels are TPU-only"
+    if _spmd_partitioner_controlled(points):
+        return "xla", (
+            "the batch is under SPMD-partitioner control, which cannot "
+            "split a Mosaic custom call (wrap the step in shard_map — "
+            "parallel.make_dp_step — to get the kernel on local blocks)"
+        )
     if fits_vmem(n):
-        return "pallas"
+        return "pallas", f"N={n} fits the fused kernel's VMEM budget"
     # The chunked kernel's column loop is a static unroll — auto caps it
     # where compile time stays sane (explicit impl="pallas_big" can go
     # further; see knn_batch_pallas_big).
-    return "pallas_big" if fits_big_kernel(n) else "xla"
+    if fits_big_kernel(n):
+        return "pallas_big", f"N={n} is past the fused kernel's VMEM cliff"
+    return "xla", f"N={n} is past the chunked kernel's unroll ceiling"
+
+
+@functools.lru_cache(maxsize=None)
+def _announce(line: str) -> None:
+    """Print each distinct resolution once per process (trace time)."""
+    print(line, file=sys.stderr)
 
 
 def _spmd_partitioner_controlled(points: Array) -> bool:
     """True when ``points`` lives on (or is traced under) a multi-device
     mesh whose axes the XLA SPMD partitioner controls.
 
-    Concrete arrays are easy on every JAX: committed to >1 device means
-    the implicit jit around the kernel would need the partitioner -> True.
-    Tracers split by JAX generation:
-
-    - sharding-in-types avals (jax >= 0.6): aval mesh non-empty with any
-      Auto/Explicit axis (plain ``jit`` under a mesh) -> the partitioner
-      will place this op -> True; under ``shard_map`` (all axes Manual)
-      or with no mesh -> the kernel sees a per-device local block ->
-      False.
-    - legacy avals (jax <= 0.4.x, no sharding on tracers): inside
-      ``shard_map``/``pmap`` the mesh axes are bound as named axis frames
-      (``jax_compat.manual_axis_context``) -> local block -> False;
-      under plain ``jit`` the tracer cannot reveal its placement, so on a
-      multi-device process we conservatively assume the partitioner may
-      control it -> True (sharded training re-enters through the
-      shard_map wrappers in ``parallel/``, where Pallas is selected
-      again; only a single-process plain-jit multi-device run pays the
-      xla fallback). Single device -> False.
+    Concrete arrays: committed to >1 device means the implicit jit around
+    the kernel would need the partitioner -> True. Tracers carry their
+    sharding on the aval: a mesh with any Auto/Explicit axis (plain
+    ``jit`` over sharded operands, or under ``jax.set_mesh``) -> the
+    partitioner will place this op -> True; under ``shard_map`` (all axes
+    Manual) or with no mesh (single-device operands — also on a host with
+    several chips) -> the kernel sees a per-device local block -> False.
     """
     if not isinstance(points, jax.core.Tracer):
-        sharding = getattr(points, "sharding", None)
+        sharding = getattr(points, "sharding", None)  # numpy: host data
         return sharding is not None and len(sharding.device_set) > 1
-    aval = getattr(points, "aval", None)
-    aval_sharding = getattr(aval, "sharding", None)
-    if aval_sharding is not None:
-        mesh = getattr(aval_sharding, "mesh", None)
-        if mesh is None or not getattr(mesh, "axis_types", None):
-            return False
-        axis_type = jax.sharding.AxisType
-        return any(t != axis_type.Manual for t in mesh.axis_types)
-    if manual_axis_context():
-        return False
-    return len(jax.devices()) > 1
+    mesh = points.aval.sharding.mesh
+    return any(t != jax.sharding.AxisType.Manual for t in mesh.axis_types)
 
 
 def knn_batch(
@@ -221,7 +223,8 @@ def knn_batch(
     sharded training).
     """
     if impl == "auto":
-        impl = _resolve_auto_impl(points)
+        impl, why = _resolve_auto(points)
+        _announce(f"[knn] impl=auto -> {impl}: {why}")
     if impl in ("pallas", "pallas_interpret"):
         from marl_distributedformation_tpu.ops.knn_pallas import (
             knn_batch_pallas,

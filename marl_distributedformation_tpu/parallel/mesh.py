@@ -23,7 +23,6 @@ import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from marl_distributedformation_tpu.jax_compat import shard_map
 
 
 def resolve_axis_sizes(
@@ -95,7 +94,7 @@ def make_dp_step(params: Any, mesh: Mesh) -> Callable:
     spec = P("dp")
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec, spec),
         out_specs=(spec, spec),

@@ -38,7 +38,6 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from marl_distributedformation_tpu.env import EnvParams, FormationState, Transition
-from marl_distributedformation_tpu.jax_compat import shard_map
 from marl_distributedformation_tpu.env.formation import (
     _in_obstacle,
     compute_obs,
@@ -222,7 +221,7 @@ def make_ring_step(params: EnvParams, mesh: Mesh):
         formation_spec,  # done
         formation_spec,  # metrics (dict of (m,) arrays)
     )
-    sharded = shard_map(
+    sharded = jax.shard_map(
         block_step, mesh=mesh, in_specs=in_specs, out_specs=out_specs
     )
 

@@ -98,7 +98,11 @@ from marl_distributedformation_tpu.serving.sharded import (
     ShardedPolicyEngine,
     ShardedSpec,
 )
-from marl_distributedformation_tpu.serving.smoke import run_smoke_benchmark
+from marl_distributedformation_tpu.serving.smoke import (
+    RUNG_SWEEP_TOL,
+    run_rung_sweep,
+    run_smoke_benchmark,
+)
 
 __all__ = [
     "BackpressureError",
@@ -125,6 +129,8 @@ __all__ = [
     "plans_equivalent",
     "replay_recorder",
     "run_load",
+    "RUNG_SWEEP_TOL",
+    "run_rung_sweep",
     "run_smoke_benchmark",
     "synthetic_trace",
 ]

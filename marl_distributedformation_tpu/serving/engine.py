@@ -16,9 +16,11 @@ silently recompiling per call.
 
 Params are an *argument* of the compiled function, not a closure
 constant: a hot-swapped checkpoint with the same architecture reuses the
-existing executable — swapping weights never recompiles. The padded
-observation buffer and the per-dispatch PRNG key are donated (both are
-freshly built per call, so the engine never aliases a live buffer).
+existing executable — swapping weights never recompiles. Nothing is
+donated: the only output is the ``(rows, act_dim)`` action block, which
+can alias neither the ``(rows, obs_dim)`` obs buffer nor the key — on
+the TPU a donation here is refused by XLA with a warning per rung
+("Some donated buffers were not usable", measured PR 21).
 
 ``dtype="bfloat16"`` opts a rung ladder into bf16 inference: each rung's
 compiled program casts the float params and the obs to bf16 ON DEVICE
@@ -39,14 +41,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# Settle jax_compat's global PRNG normalization (jax_threefry_partitionable)
-# BEFORE any engine compiles: jax config values key the jit cache, so a
-# later lazy import (e.g. parallel.mesh, pulled in the first time a fleet
-# builds a mesh-sharded replica) flipping the flag would invalidate every
-# already-warmed engine's programs — each next dispatch then retraces
-# against its budget-1 guard and the replica circuit-breaks. Importing it
-# here means "an engine exists" implies "the config is final".
-from marl_distributedformation_tpu import jax_compat as _jax_compat  # noqa: F401
 from marl_distributedformation_tpu.analysis.guards import (
     RetraceGuard,
     ledgered_jit,
@@ -80,6 +74,12 @@ class BucketedPolicyEngine:
         "bfloat16" compiles each rung with an in-program cast of float
         params + obs to bf16 (actions come back f32). Opt-in: the
         divergence budget is tests/bf16_budget.py's, not zero.
+      device: the device this engine's dispatches run on (a fleet passes
+        replica *i*'s device, where it also placed the params). The
+        base PRNG key is committed there, so the per-dispatch
+        ``fold_in`` runs on — and its key stays on — that device
+        instead of being made on jax's default device and copied over
+        on every dispatch. ``None`` leaves placement to jax.
     """
 
     def __init__(
@@ -89,6 +89,7 @@ class BucketedPolicyEngine:
         max_traces_per_bucket: Optional[int] = 1,
         seed: int = 0,
         dtype: Optional[str] = None,
+        device: Any = None,
     ) -> None:
         self.policy = policy
         self.buckets = tuple(sorted({int(b) for b in buckets}))
@@ -109,6 +110,8 @@ class BucketedPolicyEngine:
         }
         self._acts = {b: self._build_act(b) for b in self.buckets}
         self._base_key = jax.random.PRNGKey(seed)
+        if device is not None:
+            self._base_key = jax.device_put(self._base_key, device)
         self._dispatches = 0  # graftlock: guarded-by=_lock
         self._lock = threading.Lock()
         # Trailing row shape, recorded on the first successful dispatch:
@@ -150,21 +153,16 @@ class BucketedPolicyEngine:
         def _act(nn_params, obs, key, deterministic):
             return self._act_core(nn_params, obs, key, deterministic)
 
-        # obs + key are freshly materialized per dispatch — donate both.
         # ``deterministic`` rides as a traced bool scalar so ONE program
         # per bucket covers both modes (a static arg would double the
         # compile count for no win: the sampled branch is a cheap fused
-        # normal draw). The CPU backend cannot alias input buffers
-        # (donation there only emits a warning per compile), so donation
-        # engages on accelerators only.
-        donate = () if jax.default_backend() == "cpu" else (1, 2)
+        # normal draw).
         dtype_tag = "bf16" if self.dtype is not None else "f32"
         return ledgered_jit(
             _act,
             self.guards[bucket],
             subsystem="serving",
             program=f"act_rung{bucket}_{dtype_tag}",
-            donate_argnums=donate,
         )
 
     # -- bucketing ------------------------------------------------------

@@ -142,7 +142,7 @@ class FleetReloadCoordinator:
         ``refresh()`` may also be called directly.
       commit_timeout_s: bound on waiting for any single replica's
         barrier at commit time. A worker wedged inside a device dispatch
-        (a hung tunnel op) holds its lock indefinitely; without the
+        (a hung device op) holds its lock indefinitely; without the
         bound, one wedged replica would park the WHOLE fleet behind
         closed gates. On timeout the commit aborts cleanly — locks
         released, gates reopened, a recorded ``load_errors`` entry —

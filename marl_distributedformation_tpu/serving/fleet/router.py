@@ -204,7 +204,7 @@ class FleetRouter:
         for i in range(n):
             dev = devs[i % len(devs)]
             engine = BucketedPolicyEngine(
-                policy, buckets=buckets, seed=seed + i
+                policy, buckets=buckets, seed=seed + i, device=dev
             )
             if lanes is not None:
                 # One (params, step) cell per lane, all device-resident
@@ -658,6 +658,7 @@ class FleetRouter:
             self.policy,
             buckets=tuple(buckets) if buckets is not None else self._buckets,
             seed=self._seed + index,
+            device=dev,
         )
         registry = ReplicaRegistry(
             jax.device_put(params, dev), step=step, device=dev

@@ -204,6 +204,12 @@ class MetaRouter:
                 status, payload, echoed = self._forward(
                     host.data_url, body, trace_id, remaining
                 )
+                if status == 200 and "actions" not in payload:
+                    # A 200 whose body did not parse (post_json hands
+                    # back {"error": <prefix>}): the host died between
+                    # the status line and the end of its answer — a
+                    # kill -9 mid-write. Same signal as a reset.
+                    raise http.client.IncompleteRead(b"")
             except (OSError, http.client.HTTPException) as e:
                 # Nobody answered: the host-death signal. Break it,
                 # fail the request over while the hop budget lasts.

@@ -14,9 +14,10 @@ rungs run over a device-mesh *slice*, with
 - **batch-axis request sharding**: the padded request buffer is placed
   ``P("dp")`` so each mesh device computes its block of rows. With
   replicated params that is classic data-parallel inference — the
-  per-row math is IDENTICAL to the single-device program, which is why
-  the sharded==replicated parity gate is *bitwise* at f32, not a
-  tolerance;
+  per-row program is IDENTICAL to the single-device one; the bits agree
+  wherever the backend picks the same matmul kernel for the shard's row
+  count as for the whole rung (TPU v5e at dp=4: every rung; XLA:CPU:
+  from 4 rows per device, one ulp below — docs/serving.md);
 - an optional ``"mp"`` mesh axis for rules that split wide kernels over
   their OUTPUT feature axis (contraction dim intact — no reduction
   reordering, parity stays bitwise). Rules whose axes the mesh lacks, or
@@ -322,10 +323,7 @@ class ShardedPolicyEngine(BucketedPolicyEngine):
         # _run, where the lowered/compiled artifacts are in hand).
         dtype_tag = "bf16" if self.dtype is not None else "f32"
         _act.__name__ = f"sharded_act_rung{bucket}_{dtype_tag}"
-        donate = () if jax.default_backend() == "cpu" else (1,)
-        return jax.jit(
-            self.guards[bucket].wrap(_act), donate_argnums=donate
-        )
+        return jax.jit(self.guards[bucket].wrap(_act))
 
     def _next_key(self):
         # The counter rides as a strong uint32 scalar (no weak-type

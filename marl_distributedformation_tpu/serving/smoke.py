@@ -30,6 +30,61 @@ from marl_distributedformation_tpu.serving.scheduler import (
 # one just past a rung boundary (worst-case padding), one large.
 DEFAULT_SIZES = (1, 3, 8, 9, 40, 100)
 
+# Bound on |served action - LoadedPolicy.predict| in the rung sweep. The
+# two programs differ only in batch shape; on a TPU the default f32
+# matmul rounds its multiplicands to bf16 (2^-9 relative per product) and
+# may tile or place the contraction differently per shape, so the bound
+# is bf16-level through the three layers onto actions clipped to [-1, 1]
+# — not float32 epsilon. On the CPU the measured difference is ~1e-7.
+RUNG_SWEEP_TOL = 2.0**-7
+
+
+def run_rung_sweep(
+    scheduler: MicroBatchScheduler,
+    row_shape: Tuple[int, ...],
+    policy: object,
+    expect_step: int = 0,
+    seed: int = 0,
+) -> Dict[str, float]:
+    """Answer one deterministic request at every rung's exact size, and
+    one a row past the top rung, and compare each with
+    ``policy.predict`` on the same rows.
+
+    The mixed-size load of :func:`run_smoke_benchmark` coalesces, so the
+    small rungs may never run under it; here the requests go one at a
+    time through a quiet scheduler, so every compiled rung answers and
+    the split-past-the-top path runs. ``policy`` is the
+    ``LoadedPolicy`` the served params came from, ``expect_step`` its
+    checkpoint step: an answer served at another step (a hot swap landed
+    mid-sweep) is counted, not compared."""
+    engine = scheduler.engine
+    client = ServingClient(scheduler, max_retries=0)
+    rng = np.random.default_rng(seed)
+    sizes = (*engine.buckets, engine.max_bucket + 1)
+    max_err = 0.0
+    other_step = 0
+    for n in sizes:
+        obs = rng.standard_normal((n, *row_shape), dtype=np.float32)
+        # Generous deadline: each first answer includes the rung's compile.
+        actions, step = client.predict(
+            obs, deterministic=True, timeout_s=300.0
+        )
+        if actions.shape[0] != n or not np.isfinite(actions).all():
+            raise RuntimeError(
+                f"rung sweep: {n}-row request answered with shape "
+                f"{actions.shape}, finite={np.isfinite(actions).all()}"
+            )
+        if step != expect_step:
+            other_step += 1
+            continue
+        expected, _ = policy.predict(obs, deterministic=True)
+        max_err = max(max_err, float(np.abs(actions - expected).max()))
+    return {
+        "rung_sweep_sizes": ",".join(str(n) for n in sizes),
+        "rung_sweep_max_abs_err": max_err,
+        "rung_sweep_other_step": float(other_step),
+    }
+
 
 def run_smoke_benchmark(
     scheduler: MicroBatchScheduler,

@@ -2,13 +2,21 @@
 
 from marl_distributedformation_tpu.utils.config import (  # noqa: F401
     Config,
+    announce_device,
     apply_overrides,
+    cpu_requested,
+    device_residency,
+    device_stamp,
+    ensure_devices,
     env_params_from_config,
     load_config,
     repo_root,
+    run_dir,
     scenario_schedule_from_config,
+    setup_compile_cache,
     setup_platform,
     validate_override_keys,
+    widen_cpu_pool,
 )
 from marl_distributedformation_tpu.utils.checkpoint import (  # noqa: F401
     AsyncCheckpointWriter,

@@ -35,6 +35,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from marl_distributedformation_tpu.utils import (  # noqa: E402
+    device_stamp,
     env_params_from_config,
     load_config,
     repo_root,
@@ -153,14 +154,7 @@ def main(argv=None) -> dict:
         "eval_compiles": search.compile_count,
         "candidates_per_sec": round(search.candidates_per_sec(), 1),
     }
-    try:
-        import jax
-
-        dev = jax.devices()[0]
-        report["resolved_platform"] = dev.platform
-        report["resolved_device"] = dev.device_kind
-    except Exception:  # noqa: BLE001 — provenance never kills a report
-        pass
+    report.update(device_stamp())
 
     # Human-readable slice: the minimal break point per checkpoint.
     print(

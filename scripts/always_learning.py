@@ -42,10 +42,12 @@ sys.path.insert(0, str(REPO_ROOT))
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 from marl_distributedformation_tpu.utils import (  # noqa: E402
+    announce_device,
     env_params_from_config,
     load_config,
     setup_platform,
     validate_override_keys,
+    widen_cpu_pool,
 )
 
 PIPELINE_KEYS = (
@@ -253,17 +255,11 @@ def main(argv=None) -> dict:
     # for the gate's own assignment (docs/sebulba.md). Anakin only needs
     # a device per serving replica.
     want_devices = max(replicas, actor_devices + 2) if sebulba else replicas
-    import jax
-
-    if (
-        jax.default_backend() == "cpu"
-        and len(jax.local_devices()) < want_devices
-    ):
-        # The forced multi-device CPU mesh (the dev/bench shape): widen
-        # the device pool so each serving replica gets a real device.
-        from serve_policy import _ensure_cpu_devices
-
-        _ensure_cpu_devices(want_devices)
+    # With the CPU asked for by name (the dev shape) each serving replica
+    # and slice gets its own virtual device; on an accelerator they share
+    # the devices the hardware has.
+    widen_cpu_pool(want_devices)
+    stamp = announce_device("pipeline")
 
     import train as train_entry
     from marl_distributedformation_tpu.pipeline import (
@@ -728,6 +724,7 @@ def main(argv=None) -> dict:
         except OSError:
             pass
 
+    report.update(stamp)
     out = cfg.get("out")
     if out:
         Path(out).parent.mkdir(parents=True, exist_ok=True)

@@ -942,33 +942,6 @@ def run_sebulba_campaign(
     return report
 
 
-def _widen_cpu_devices(n: int) -> None:
-    """Best-effort CPU device-pool widening (mirrors serve_policy.py's
-    _ensure_cpu_devices): the elastic campaign wants >= 2 devices so
-    re-splits exercise the sharded slice path, but runs honestly on
-    whatever pool it gets."""
-    import os
-
-    import jax
-
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "--xla_force_host_platform_device_count" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + f" --xla_force_host_platform_device_count={n}"
-        ).strip()
-    if len(jax.local_devices()) >= n or jax.default_backend() != "cpu":
-        return
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except (AttributeError, RuntimeError):
-        try:
-            import jax.extend.backend as jeb
-
-            jeb.clear_backends()
-        except Exception:  # noqa: BLE001 — widening is best-effort
-            pass
-
-
 def run_elastic_campaign(
     seed: int = 0,
     faults: int = 9,
@@ -1014,7 +987,12 @@ def run_elastic_campaign(
 
     t_start = time.perf_counter()
     deadline = t_start + budget_s
-    _widen_cpu_devices(2)
+    # >= 2 devices so re-splits exercise the sharded slice path — virtual
+    # ones only where the CPU was asked for by name; otherwise the
+    # campaign runs on the pool the hardware has.
+    from marl_distributedformation_tpu.utils import widen_cpu_pool
+
+    widen_cpu_pool(2)
     import jax
     import jax.numpy as jnp
 

@@ -1,23 +1,20 @@
 #!/usr/bin/env python
 """Validate a bench JSON line as committable chip evidence.
 
-The chip-window burster stamps a stage only when its bench record is
-real hardware evidence. Each stage needs the same gate — no CPU
-fallback, no watchdog error, no degraded ("skipped"/"failed") phases —
-plus a per-stage list of required rate fields. This is that gate in ONE
-place, so the acceptance criteria cannot drift between stages:
+A bench record counts only when it is hardware evidence: a TPU platform,
+no error, no degraded ("skipped"/"failed") phases — plus a per-use list
+of required rate fields. This is that gate in ONE place:
 
-    python scripts/check_bench_record.py /tmp/bench_tpu.json \
+    python scripts/check_bench_record.py bench.json \
         --require train_env_steps_per_sec knn_env_steps_per_sec \
         --expect knn_impl=pallas
 
 Exit 0 iff the record passes. ``--require F`` asserts float(rec[F]) > 0;
-``--expect K=V`` asserts str(rec[K]) == V. Input parsing is shared with
-scripts/mirror_bench.py (bench.py stdout or a driver BENCH_r*.json
-wrapper), so the gate and the mirror can never disagree on a file.
+``--expect K=V`` asserts str(rec[K]) == V. The input is bench.py's stdout
+(ONE JSON line, possibly preceded by other output).
 
-Census mode — the chip-window acceptance gate for the program ledger
-(obs/ledger.py, ROADMAP item 5):
+Census mode — the acceptance gate for the program ledger
+(obs/ledger.py):
 
     python scripts/check_bench_record.py COMMITTED_census.json \
         --census logs/run/program_ledger.json [--census-tolerance 0.25]
@@ -32,12 +29,24 @@ a throughput regression later.
 from __future__ import annotations
 
 import argparse
+import json
 import math
 import sys
 from pathlib import Path
 
-sys.path.insert(0, str(Path(__file__).resolve().parent))
-from mirror_bench import _load_record as load_record  # noqa: E402
+
+def load_record(src: Path) -> dict:
+    """The bench record in ``src``: the last JSON line carrying a
+    ``metric`` field (bench.py prints exactly one, after its log
+    lines)."""
+    for line in reversed(src.read_text().strip().splitlines()):
+        line = line.strip()
+        if line.startswith("{"):
+            rec = json.loads(line)
+            if "metric" in rec:
+                return rec
+    raise SystemExit(f"no bench JSON record found in {src}")
+
 
 # bench.py writes this sentinel into the rate fields of phases disabled
 # by BENCH_SKIP_* env vars — "explicitly not run", distinct from both a
@@ -1017,10 +1026,10 @@ def _elastic_problems(rec: dict) -> list[str]:
 def check(rec: dict, require: list[str], expect: list[str]) -> list[str]:
     """Return the list of violations (empty = evidence-grade record)."""
     problems = []
-    if rec.get("fallback"):
-        problems.append("fallback: true — CPU run, not hardware evidence")
-    if rec.get("platform") == "cpu":
-        problems.append("platform is cpu")
+    if rec.get("platform") != "tpu":
+        problems.append(
+            f"platform is {rec.get('platform')!r} — not hardware evidence"
+        )
     if "error" in rec:
         problems.append(f"error field present: {rec['error']!r}")
     notes = str(rec.get("notes", ""))

@@ -37,6 +37,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 from marl_distributedformation_tpu.utils import (  # noqa: E402
+    device_stamp,
     env_params_from_config,
     load_config,
     repo_root,
@@ -117,14 +118,7 @@ def main(argv=None) -> dict:
         deterministic=bool(cfg.get("eval_deterministic", True)),
     )
     report["name"] = str(cfg.name)
-    try:
-        import jax
-
-        dev = jax.devices()[0]
-        report["resolved_platform"] = dev.platform
-        report["resolved_device"] = dev.device_kind
-    except Exception:  # noqa: BLE001 — provenance never kills a report
-        pass
+    report.update(device_stamp())
 
     # Human-readable slice: per checkpoint x scenario, return at the
     # highest severity vs clean (degradation is the robustness headline).

@@ -31,9 +31,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-# Bitwise-stream tests must see the threefry-partitionable flag before
-# any draws (tests/test_scenarios.py NB).
-from marl_distributedformation_tpu import jax_compat  # noqa: F401
 from marl_distributedformation_tpu.algo import PPOConfig
 from marl_distributedformation_tpu.env import EnvParams
 from marl_distributedformation_tpu.models import MLPActorCritic

@@ -30,8 +30,8 @@ def test_bench_runner_compiles_and_steps():
 
 @pytest.mark.slow
 def test_bench_emits_parseable_json_on_cpu(monkeypatch, capsys):
-    """The one-JSON-line contract must survive any backend state: force the
-    CPU fallback path with tiny shapes and parse the output."""
+    """The one-JSON-line contract, with the CPU asked for by name
+    (BENCH_FORCE_CPU=1) and tiny shapes."""
     import json
 
     monkeypatch.setenv("BENCH_FORCE_CPU", "1")
@@ -64,51 +64,54 @@ def test_bench_emits_parseable_json_on_cpu(monkeypatch, capsys):
     assert set(rec["train_fused_scan_compiles"].values()) == {1}
     assert rec["dispatch_overhead_pct"] >= 0.0
     assert "error" not in rec and "notes" not in rec
-    # Provenance pin (VERDICT.md r3 weak #5): the parity field replays a
-    # committed chip artifact, so it must carry the artifact's recorded
-    # date — a CPU-fallback JSON must not read like same-run TPU parity.
-    sentinels = (
-        "no committed artifact",
-        "no fused-kernel leg in artifact",
-        "no big-kernel leg in artifact",
-    )
-    parity = rec["knn_device_parity"]
-    if parity not in sentinels:
-        assert parity.startswith("recorded 20"), parity
-        assert "PARITY" in parity
-        # Each phase's field replays the artifact leg for the kernel it
-        # actually benchmarks: fused for knn (N=100), chunked for knn-big.
-        assert "pallas_big" not in parity
-    big = rec["knn_big_device_parity"]  # phase 4 always carries provenance
-    if big not in sentinels:
-        assert big.startswith("recorded 20"), big
-        assert "pallas_big" in big or "PARITY_FAIL(big)" in big
+    assert "phases_failed" not in rec
+    # The record names the device it ran on, as jax reports it.
+    assert rec["platform"] == "cpu"
+    assert rec["device_kind"] and rec["device_count"] >= 1
 
 
-@pytest.mark.slow
-def test_fallback_json_carries_recorded_chip_story(monkeypatch, capsys):
-    """A CPU-fallback line must point at the last real chip record with
-    its date (VERDICT r3 weak #1) — not leave only CPU numbers beside a
-    bare fallback flag."""
+def test_bench_without_a_tpu_exits_nonzero_and_measures_nothing(
+    monkeypatch, capsys
+):
+    """No probe, no fallback: on a machine without a TPU (and without
+    BENCH_FORCE_CPU=1 asking for the CPU by name) the bench names the
+    device it found, prints no record, and exits non-zero."""
+    monkeypatch.delenv("BENCH_FORCE_CPU", raising=False)
+    with pytest.raises(SystemExit) as exc:
+        bench_mod.main()
+    assert exc.value.code not in (0, None)
+    captured = capsys.readouterr()
+    assert captured.out.strip() == ""  # no JSON record
+    assert "platform=cpu" in captured.err
+    assert "no TPU" in captured.err
+
+
+def test_bench_failed_phase_makes_exit_code_nonzero(monkeypatch, capsys):
+    """A phase that raises is named in ``phases_failed`` and fails the
+    run AFTER the record prints — never a caught exception and exit 0."""
     import json
 
-    monkeypatch.setattr(bench_mod, "probe_backend", lambda *a, **k: None)
-    monkeypatch.delenv("BENCH_FORCE_CPU", raising=False)
-    for phase in ("TRAIN", "KNN", "KNN_BIG"):
-        monkeypatch.setenv(f"BENCH_SKIP_{phase}", "1")
+    monkeypatch.setenv("BENCH_FORCE_CPU", "1")
     monkeypatch.setattr(bench_mod, "M", 8)
     monkeypatch.setattr(bench_mod, "CHUNK", 4)
     monkeypatch.setattr(bench_mod, "MIN_TIMED_S", 0.05)
-    bench_mod.main()
+    for phase in (
+        "TRAIN", "KNN", "KNN_BIG", "ENVS", "SERVING", "PIPELINE",
+        "ADVERSARIAL", "CHAOS", "MESH", "LINT", "SEBULBA", "SWEEP",
+    ):
+        monkeypatch.setenv(f"BENCH_SKIP_{phase}", "1")
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("scenario runner exploded")
+
+    monkeypatch.setattr(bench_mod, "make_scenario_runner", boom)
+    with pytest.raises(SystemExit) as exc:
+        bench_mod.main()
+    assert exc.value.code == 1
     rec = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rec["fallback"] is True
-    assert rec["recorded_chip_bench"].startswith("recorded ")
-    # The pointer must reference the NEWEST committed chip record — it is
-    # parsed from docs/acceptance/tpu_bench_r*.md at runtime, never a
-    # string frozen at some round's numbers.
-    assert "tpu_bench_r" in rec["recorded_chip_bench"]
-    assert "formation-steps/s" in rec["recorded_chip_bench"]
-    assert "unreachable" in rec["notes"]
+    assert rec["phases_failed"] == ["scenario"]
+    assert "scenario phase failed" in rec["notes"]
+    assert rec["value"] > 0  # the phases before it still measured
 
 
 def test_graft_entry_compiles():

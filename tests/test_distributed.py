@@ -10,6 +10,7 @@ CPU mesh (conftest.py).
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from marl_distributedformation_tpu.env import EnvParams
@@ -144,16 +145,43 @@ def test_hetero_reset_batch_sharded_matches_unsharded():
     assert not sharded.agents.sharding.is_fully_replicated
 
 
-def test_init_distributed_cluster_marker_fallback(monkeypatch):
-    """A cluster env marker without a reachable coordinator must degrade to
-    single-process (with a warning), not crash."""
+def test_init_distributed_multi_host_launch_failure_raises(monkeypatch):
+    """A launch environment describing several hosts that cannot be wired
+    up must raise — N hosts quietly training as N independent
+    single-process jobs is a fallback, not a run."""
     import marl_distributedformation_tpu.parallel.distributed as dist
 
     monkeypatch.setattr(dist, "_initialized", False)
     monkeypatch.setenv("SLURM_JOB_NUM_NODES", "2")
-    # jax.distributed.initialize will raise (no real Slurm env) — wrapped.
+    # No real Slurm env (and the backend is already up): initialize raises.
+    with pytest.raises(Exception):
+        dist.init_distributed()
+    assert not dist._initialized
+
+
+def test_init_distributed_single_host_tpu_vm_stays_single_process(
+    monkeypatch,
+):
+    """A single-host TPU VM sets the pod-slice variables too
+    (``TPU_WORKER_ID=0``, ``TPU_WORKER_HOSTNAMES=localhost``): one host is
+    nothing to wire up, and cluster detection on a sealed machine can
+    hang — ``jax.distributed.initialize`` must not be called."""
+    import marl_distributedformation_tpu.parallel.distributed as dist
+
+    def _must_not_run(*args, **kwargs):
+        raise AssertionError("jax.distributed.initialize was called")
+
+    monkeypatch.setattr(dist, "_initialized", False)
+    monkeypatch.setattr(jax.distributed, "initialize", _must_not_run)
+    monkeypatch.setenv("TPU_WORKER_ID", "0")
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "localhost")
     assert dist.init_distributed() is False
     assert dist._initialized
+    # ... and a pod slice (several hostnames) does call it.
+    monkeypatch.setattr(dist, "_initialized", False)
+    monkeypatch.setenv("TPU_WORKER_HOSTNAMES", "10.0.0.1,10.0.0.2")
+    with pytest.raises(AssertionError, match="initialize was called"):
+        dist.init_distributed()
 
 
 def test_save_checkpoint_returns_path_single_process(tmp_path):

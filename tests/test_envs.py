@@ -25,10 +25,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-# Force the threefry-partitionable flag BEFORE any draws: the knn path
-# lazily imports jax_compat (which flips it), and bitwise-identity tests
-# must not compare streams drawn on both sides of that flip.
-from marl_distributedformation_tpu import jax_compat  # noqa: F401
 from marl_distributedformation_tpu import envs
 from marl_distributedformation_tpu.algo import PPOConfig
 from marl_distributedformation_tpu.env import EnvParams

@@ -99,9 +99,11 @@ def test_policy_act_fn_stochastic_samples():
     assert np.abs(np.asarray(v1)).max() > 0
 
 
-def test_evaluate_cli_roundtrip(tmp_path, monkeypatch, capsys):
-    """evaluate.py discovers the latest checkpoint of a named run and
-    emits the machine-readable JSON line with the comparison fields."""
+def test_evaluate_cli_roundtrip(tmp_path, capsys):
+    """evaluate.py discovers the latest checkpoint of a run directory
+    (``log_dir=``, the key train.py wrote it under — never ``logs/`` in
+    the checkout) and emits the machine-readable JSON line with the
+    comparison fields and the device it ran on."""
     import sys
 
     from pathlib import Path
@@ -110,28 +112,37 @@ def test_evaluate_cli_roundtrip(tmp_path, monkeypatch, capsys):
     import evaluate as evaluate_cli
     import train as train_cli
 
-    monkeypatch.setattr(
-        "marl_distributedformation_tpu.utils.repo_root", lambda: tmp_path
-    )
-    train_cli.main(
+    run = tmp_path / "evalrun"
+    trained = train_cli.main(
         [
             "name=evalrun",
+            f"log_dir={run}",
             "num_formation=4",
             "total_timesteps=800",
             "max_steps=20",
             "strict_parity=false",
         ]
     )
+    assert trained["log_dir"] == str(run)
+    assert (run / "config.json").exists()
     result = evaluate_cli.main(
         [
             "name=evalrun",
+            f"log_dir={run}",
             "eval_formations=4",
             "max_steps=20",
             "strict_parity=false",
         ]
     )
     out = capsys.readouterr().out
+    # Both entry points name their device first...
+    assert out.splitlines()[0].startswith("[train] device: platform=cpu")
+    assert "[eval] device: platform=cpu" in out
     last_json = json.loads(out.strip().splitlines()[-1])
+    # ... and in their result.
+    for rec in (trained, last_json):
+        assert rec["platform"] == "cpu"
+        assert rec["device_kind"] and rec["device_count"] == 8
     for key in (
         "policy_episode_return_per_agent",
         "baseline_episode_return_per_agent",
@@ -157,6 +168,7 @@ def test_evaluate_cli_sweep_mode(tmp_path, capsys):
     train_cli.main(
         [
             "name=evalsweep",
+            f"log_dir={tmp_path / 'evalsweep'}",
             "num_seeds=2",
             "num_formation=4",
             "total_timesteps=720",
@@ -171,6 +183,7 @@ def test_evaluate_cli_sweep_mode(tmp_path, capsys):
     result = evaluate_cli.main(
         [
             "name=evalsweep",
+            f"log_dir={tmp_path / 'evalsweep'}",
             "eval_formations=4",
             "max_steps=20",
             "num_agents_per_formation=3",

@@ -35,6 +35,7 @@ from marl_distributedformation_tpu.compat.policy import (  # noqa: E402
 from marl_distributedformation_tpu.models import MLPActorCritic  # noqa: E402
 from marl_distributedformation_tpu.serving import (  # noqa: E402
     BackpressureError,
+    BucketedPolicyEngine,
     ServingClient,
 )
 from marl_distributedformation_tpu.serving.fleet import (  # noqa: E402
@@ -107,6 +108,27 @@ def test_replicas_land_on_distinct_devices():
         params, step = r.registry.active()
         leaf = jax.tree_util.tree_leaves(params)[0]
         assert leaf.devices() == {r.device}
+
+
+def test_a_replicas_dispatch_stays_on_its_own_device():
+    """Replica *i*'s rung runs on device *i* and nothing it needs per
+    dispatch is made on jax's default device first: the per-dispatch key
+    is folded on the replica's device (the base key is committed there),
+    and the actions come back from it."""
+    router = FleetRouter(_make_policy(), num_replicas=4, buckets=(1, 8))
+    default = jax.devices()[0]
+    for r in router.replicas[1:]:
+        assert r.device != default
+        key = r.engine._next_key()
+        assert key.devices() == {r.device}
+        params, _ = r.registry.active()
+        actions = r.engine._run(
+            8, params, np.zeros((8, OBS_DIM), np.float32), key, np.bool_(True)
+        )
+        assert actions.devices() == {r.device}
+    # A single engine built without a device leaves placement to jax.
+    engine = BucketedPolicyEngine(_make_policy(), buckets=(1,))
+    assert engine._next_key().devices() == {default}
 
 
 def test_router_routes_around_a_slow_replica():

@@ -19,9 +19,6 @@ import jax
 import numpy as np
 import pytest
 
-# Bitwise PRNG-stream comparisons need partitionable threefry forced
-# before any key math (see PR 3's note in CHANGES.md).
-from marl_distributedformation_tpu import jax_compat  # noqa: F401
 from marl_distributedformation_tpu.algo import PPOConfig
 from marl_distributedformation_tpu.env import EnvParams
 from marl_distributedformation_tpu.train import (

@@ -208,10 +208,8 @@ def test_spmd_detection_contexts():
     seen = []
     jax.jit(lambda y: seen.append(ctl(y)) or y)(x_dp)
     assert seen[-1], "tracer under jit+mesh must report partitioner control"
-    from marl_distributedformation_tpu.jax_compat import shard_map
-
     jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda y: seen.append(ctl(y)) or y,
             mesh=mesh, in_specs=P("dp"), out_specs=P("dp"),
         )

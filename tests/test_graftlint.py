@@ -269,13 +269,15 @@ FIXTURES = [
         import jax
 
         def make(mesh, spec, f):
-            return jax.shard_map(f, mesh=mesh, in_specs=spec, out_specs=spec)
+            return jax.experimental.shard_map.shard_map(
+                f, mesh=mesh, in_specs=spec, out_specs=spec
+            )
         """,
         """
-        from marl_distributedformation_tpu.jax_compat import shard_map
+        import jax
 
         def make(mesh, spec, f):
-            return shard_map(f, mesh=mesh, in_specs=spec, out_specs=spec)
+            return jax.shard_map(f, mesh=mesh, in_specs=spec, out_specs=spec)
         """,
     ),
     (
@@ -1945,17 +1947,6 @@ def test_suppression_is_rule_specific():
     rules = fired(src)
     assert "print-in-jit" not in rules
     assert "host-sync-in-jit" in rules, "other rules must survive"
-
-
-def test_shim_module_needs_its_suppression():
-    """jax_compat.py spells the legacy import on purpose; without its
-    inline disable the deprecated-api rule must flag it (proves the
-    suppression there is load-bearing, not decorative)."""
-    shim = (PACKAGE / "jax_compat.py").read_text()
-    assert "graftlint: disable=deprecated-api" in shim
-    stripped = shim.replace("# graftlint: disable=deprecated-api", "#")
-    violations = lint_source(stripped, "jax_compat.py")
-    assert any(v.rule == "deprecated-api" for v in violations)
 
 
 def test_suppression_prose_cannot_name_other_rules():

@@ -301,6 +301,38 @@ def test_meta_router_serves_and_routes_by_gossiped_drain(mesh2):
     assert snap["mesh_routed_total"] >= 2.0
 
 
+def test_an_answer_cut_off_mid_body_fails_over_like_a_reset(mesh2):
+    """A host killed between its status line and the end of its answer
+    leaves a 200 with an unparseable body (post_json degrades it to
+    ``{"error": prefix}``). That must take the host-death path — break
+    the host, fail the request over — not surface as a KeyError the
+    caller has no taxonomy for (the rare lost request of the real
+    kill -9 e2e below)."""
+    router = mesh2["router"]
+    real_forward = router._forward
+    calls = []
+
+    def cut_off_once(data_url, body, trace_id, timeout_s):
+        calls.append(data_url)
+        if len(calls) == 1:
+            return 200, {"error": '{"actions": [[0.1, 0.'}, None
+        return real_forward(data_url, body, trace_id, timeout_s)
+
+    before = router.failed_over_total
+    router._forward = cut_off_once
+    try:
+        result = router.predict(_obs())
+    finally:
+        del router._forward
+    assert result.actions.shape == (1, 2)
+    assert len(calls) == 2 and calls[0] != calls[1]
+    assert router.failed_over_total == before + 1
+    time.sleep(0.4)  # the broken host half-opens again (probe_interval_s)
+    assert {router.predict(_obs()).host for _ in range(8)} <= {
+        "host0", "host1",
+    }
+
+
 def test_global_swap_is_monotonic_in_completion_order(mesh2):
     """The tentpole invariant, in-process edition: responses completed
     across a coordinator-driven two-phase swap never carry a step going

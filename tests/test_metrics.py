@@ -361,12 +361,6 @@ def test_load_bench_record_prefers_newest_round_and_unwraps(tmp_path):
     assert load_bench_record(root=tmp_path / "empty") == ({}, None)
 
 
-def test_committed_bench_record_loads():
-    rec, src = load_bench_record(root=REPO)
-    assert src is not None and src.name.startswith("BENCH_r")
-    assert rec.get("metric"), "committed record lost its headline field"
-
-
 def _sentinel(record, trip_after=2, tolerance=0.5, **kwargs):
     return RegressionSentinel(
         [

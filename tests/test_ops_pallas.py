@@ -143,8 +143,8 @@ def test_reset_obs_batch_path():
     jax.default_backend() == "cpu",
     reason="compiled-mode Pallas needs a real TPU backend — run "
     "`MDF_TPU_TESTS=1 pytest` (conftest opt-out) or "
-    "`python tests/tpu_compiled_parity.py` on hardware (VERDICT.md "
-    "round-1 #5)",
+    "`python tests/tpu_compiled_parity.py` / `python chip_smoke.py` on "
+    "hardware",
 )
 def test_compiled_pallas_parity_on_tpu():
     """All three hardware legs: the north-star shape (fused, block_m=8),

@@ -20,9 +20,6 @@ import optax
 import pytest
 from flax.training.train_state import TrainState
 
-# Bitwise PRNG-stream comparisons need partitionable threefry forced
-# before any key math (see PR 3's note in CHANGES.md).
-from marl_distributedformation_tpu import jax_compat  # noqa: F401
 from marl_distributedformation_tpu.algo import PPOConfig
 from marl_distributedformation_tpu.chaos import (
     FaultSchedule,

@@ -23,10 +23,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-# Force the threefry-partitionable flag BEFORE any draws: the knn path
-# lazily imports jax_compat (which flips it), and a bitwise-identity test
-# must not compare streams drawn on both sides of that flip.
-from marl_distributedformation_tpu import jax_compat  # noqa: F401
 from marl_distributedformation_tpu.env import EnvParams
 from marl_distributedformation_tpu.env.formation import (
     reset_batch,

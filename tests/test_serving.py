@@ -704,3 +704,15 @@ def test_serve_policy_cli_smoke(tmp_path):
     assert report["batch_occupancy_pct"] > 0.0
     assert report["model_step"] == 30.0
     assert report["buckets"] == "1,8,64"
+    # Every rung answered once before the storm (which coalesces and may
+    # never touch the small ones) and agreed with LoadedPolicy.predict —
+    # one row past the top rung included.
+    assert report["rung_sweep_sizes"] == "1,8,64,65"
+    assert report["rung_sweep_max_abs_err"] < 1e-6
+    assert [report[f"compiles_bucket_{b}"] for b in (1, 8, 64)] == [1.0] * 3
+    # The device is named first and in the result.
+    assert out.stderr.splitlines()[0].startswith(
+        "[serve] device: platform=cpu"
+    )
+    assert report["platform"] == "cpu" and report["device_count"] == 1
+    assert report["residency_bytes"]["0"] > 0

@@ -52,17 +52,17 @@ def test_reference_signature_formation_env_constructs_and_steps():
     assert len(infos) == env.num_envs
 
 
-def test_shim_trains_and_snapshots_config(tmp_path, monkeypatch):
+def test_shim_trains_and_snapshots_config(tmp_path):
     """The documented verbatim command trains end-to-end and leaves the
-    hydra-snapshot analog (config.json); a resume does not clobber it."""
-    monkeypatch.setattr(train_cli, "repo_root", lambda: tmp_path)
+    hydra-snapshot analog (config.json); a resume does not clobber it.
+    (``log_dir=`` keeps the run out of the checkout's ``logs/``.)"""
+    run_dir = tmp_path / "logs" / "shimrun"
     args = [
         "name=shimrun", "platform=cpu", "num_formation=4",
         "num_agents_per_formation=3", "total_timesteps=120", "n_steps=10",
-        "save_freq=10", "use_wandb=false",
+        "save_freq=10", "use_wandb=false", f"log_dir={run_dir}",
     ]
     shim.main(args)
-    run_dir = tmp_path / "logs" / "shimrun"
     assert (run_dir / "config.json").exists()
     assert list(run_dir.glob("rl_model_*_steps.msgpack"))
     before = (run_dir / "config.json").read_text()

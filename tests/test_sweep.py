@@ -605,10 +605,10 @@ def test_cli_dispatch(tmp_path, monkeypatch):
 
     # resume=true now composes with sweeps (population resume): with no
     # prior sweep_state it just builds a fresh population.
-    monkeypatch.setattr(train_cli, "repo_root", lambda: tmp_path)
     cfg3 = load_config(
         ["name=x", "num_seeds=2", "resume=true", "platform=cpu",
-         "num_formation=4", "num_agents_per_formation=3"]
+         "num_formation=4", "num_agents_per_formation=3",
+         f"log_dir={tmp_path / 'x'}"]
     )
     trainer3 = train_cli.build_trainer(cfg3)
     assert isinstance(trainer3, SweepTrainer)

@@ -1,12 +1,14 @@
 #!/usr/bin/env python
-"""Compiled-mode Pallas k-NN parity check (VERDICT.md round-1 #5).
+"""Compiled-mode Pallas k-NN parity check.
 
 The pytest suite pins JAX to CPU (conftest.py), where the kernel only runs
 in interpret mode — Mosaic lowering is never exercised there. This module
 holds the single copy of the compiled-parity assertion:
 
+- ``python chip_smoke.py`` runs all three legs on the chip (leg 3);
 - on hardware, run it directly: ``python tests/tpu_compiled_parity.py``
-  (prints one PARITY_OK / PARITY_FAIL line), or run the whole suite with
+  (prints one PARITY_OK / PARITY_FAIL line per leg; exits non-zero on a
+  failure and when there is no TPU), or run the whole suite with
   ``MDF_TPU_TESTS=1 pytest tests/`` (conftest leaves the real backend on and
   ``test_ops_pallas.py::test_compiled_pallas_parity_on_tpu`` runs all
   three legs);
@@ -40,10 +42,18 @@ def _assert_matches_xla(pallas_out, xla_out) -> None:
     )
 
 
-def run_parity(m: int = 4096, n: int = 100, k: int = 4) -> str:
+def _mode(interpret: bool) -> str:
+    return "interpreted" if interpret else "compiled"
+
+
+def run_parity(
+    m: int = 4096, n: int = 100, k: int = 4, interpret: bool = False
+) -> str:
     """Assert compiled-pallas == xla == host-float64 ground truth at the
     north-star swarm shape; returns a human-readable OK message, raises
-    AssertionError on mismatch.
+    AssertionError on mismatch. ``interpret`` (here and in the other
+    legs) is the CPU spelling for tests of the callers — a parity claim
+    comes only from the compiled kernel.
 
     The float64 leg is the absolute-correctness anchor (added round 3):
     round 2's matmul-expansion XLA path agreed with nothing — 33.5% of its
@@ -61,7 +71,8 @@ def run_parity(m: int = 4096, n: int = 100, k: int = 4) -> str:
     xla_out = knn_batch(pts, k, impl="xla")
     idx_x, _, d_x = xla_out
     _assert_matches_xla(
-        jax.block_until_ready(knn_batch_pallas(pts, k)), xla_out
+        jax.block_until_ready(knn_batch_pallas(pts, k, interpret=interpret)),
+        xla_out,
     )
 
     # Host float64 ground truth (vectorized; ~0.5 GB peak at the default
@@ -87,14 +98,16 @@ def run_parity(m: int = 4096, n: int = 100, k: int = 4) -> str:
         "float64 truth"
     )
     return (
-        f"compiled pallas == xla == float64 truth on "
+        f"{_mode(interpret)} pallas == xla == float64 truth on "
         f"{jax.devices()[0].device_kind} (M={m}, N={n}, k={k}; "
         f"idx mismatch vs f64 {frac_idx_wrong:.2e}, "
         f"max dist err {max_d_err:.2e})"
     )
 
 
-def run_parity_mid(m: int = 256, n: int = 512, k: int = 4) -> str:
+def run_parity_mid(
+    m: int = 256, n: int = 512, k: int = 4, interpret: bool = False
+) -> str:
     """Compiled FUSED kernel at mid N (512 pads to 512 lanes, VMEM drives
     block_m to 2) vs the XLA search, on hardware. Pins the Mosaic sublane
     rule for sub-8 block_m blocks: a 2-D ``(block_m, n_pad)`` plane is not
@@ -108,16 +121,18 @@ def run_parity_mid(m: int = 256, n: int = 512, k: int = 4) -> str:
 
     pts = jax.random.uniform(jax.random.PRNGKey(2), (m, n, 2)) * 400.0
     _assert_matches_xla(
-        jax.block_until_ready(knn_batch_pallas(pts, k)),
+        jax.block_until_ready(knn_batch_pallas(pts, k, interpret=interpret)),
         knn_batch(pts, k, impl="xla"),
     )
     return (
-        f"compiled pallas (block_m=2 sublane regime) == xla on "
+        f"{_mode(interpret)} pallas (block_m=2 sublane regime) == xla on "
         f"{jax.devices()[0].device_kind} (M={m}, N={n}, k={k})"
     )
 
 
-def run_parity_big(m: int = 256, n: int = 1024, k: int = 4) -> str:
+def run_parity_big(
+    m: int = 256, n: int = 1024, k: int = 4, interpret: bool = False
+) -> str:
     """Compiled chunked-streaming kernel (ops/knn_pallas.py
     knn_batch_pallas_big — the path for swarms past the fused kernel's
     N <= 640 VMEM cliff) vs the XLA search, on hardware."""
@@ -130,11 +145,14 @@ def run_parity_big(m: int = 256, n: int = 1024, k: int = 4) -> str:
 
     pts = jax.random.uniform(jax.random.PRNGKey(1), (m, n, 2)) * 400.0
     _assert_matches_xla(
-        jax.block_until_ready(knn_batch_pallas_big(pts, k)),
+        jax.block_until_ready(
+            knn_batch_pallas_big(pts, k, interpret=interpret)
+        ),
         knn_batch(pts, k, impl="xla"),
     )
     return (
-        f"compiled pallas_big == xla on {jax.devices()[0].device_kind} "
+        f"{_mode(interpret)} pallas_big == xla on "
+        f"{jax.devices()[0].device_kind} "
         f"(M={m}, N={n}, k={k})"
     )
 
@@ -142,13 +160,17 @@ def run_parity_big(m: int = 256, n: int = 1024, k: int = 4) -> str:
 def main() -> None:
     import jax
 
-    if jax.default_backend() == "cpu":
-        print("PARITY_SKIP: no accelerator backend", flush=True)
-        return
+    if jax.default_backend() != "tpu":
+        print(
+            f"PARITY_FAIL: backend is {jax.default_backend()!r} — the "
+            "compiled kernels exist only on a TPU",
+            flush=True,
+        )
+        sys.exit(2)
     # Catch Exception, not just AssertionError: the failure class this
     # gate exists for (Mosaic lowering rejections, e.g. the sublane rule)
     # surfaces as XlaRuntimeError/ValueError — those must still print a
-    # PARITY_FAIL line for chip_checks.sh's grep, not a bare traceback.
+    # greppable PARITY_FAIL line, not only a traceback.
     for leg, label in (
         (run_parity, ""),
         (run_parity_mid, "(mid)"),
@@ -156,7 +178,7 @@ def main() -> None:
     ):
         try:
             msg = leg()
-        except Exception as e:  # noqa: BLE001 — report, don't die silently
+        except Exception as e:  # noqa: BLE001 — report, then exit non-zero
             err = f"{type(e).__name__}: {e}" if not isinstance(
                 e, AssertionError
             ) else str(e)

@@ -12,14 +12,18 @@ contract; hydra itself is optional — see utils/config.py).
 
 from __future__ import annotations
 
+import json
 import sys
+from pathlib import Path
 
 from marl_distributedformation_tpu.algo import PPOConfig
 from marl_distributedformation_tpu.train import TrainConfig, Trainer
 from marl_distributedformation_tpu.utils import (
+    announce_device,
+    device_residency,
     env_params_from_config,
     load_config,
-    repo_root,
+    run_dir,
     scenario_schedule_from_config,
     setup_platform,
 )
@@ -54,7 +58,7 @@ def train_config_from_config(cfg) -> TrainConfig:
         seed=cfg.seed,
         save_freq=cfg.save_freq,
         name=run_name,
-        log_dir=str(repo_root() / "logs" / run_name),
+        log_dir=str(run_dir(cfg)),
         use_wandb=cfg.use_wandb,
         use_tensorboard=bool(cfg.get("use_tensorboard", False)),
         resume=cfg.get("resume", False),
@@ -327,8 +331,8 @@ def build_hetero_trainer(cfg, env_params, ppo, train_cfg, shard_fn,
     )
 
 
-def _snapshot_config(cfg, log_dir) -> None:
-    """Save the resolved run config to ``logs/{name}/config.json`` — the
+def _snapshot_config(cfg, log_dir, stamp) -> None:
+    """Save the resolved run config to ``{log_dir}/config.json`` — the
     analog of hydra's per-run ``.hydra/config.yaml`` snapshot (the
     reference gets one implicitly via ``@hydra.main``; see
     docs/migration.md 'Run directory'). Only process 0 writes. A
@@ -336,9 +340,6 @@ def _snapshot_config(cfg, log_dir) -> None:
     ``config.json`` always describes the config the run was originally
     trained with; resumes snapshot to ``config_resume.json`` (latest
     resume wins)."""
-    import json
-    from pathlib import Path
-
     from marl_distributedformation_tpu.parallel import is_coordinator
 
     if not is_coordinator():
@@ -348,26 +349,25 @@ def _snapshot_config(cfg, log_dir) -> None:
     name = "config_resume.json" if cfg.get("resume") else "config.json"
     snap = dict(cfg)
     # The requested config says what the user asked for; these say what
-    # actually ran — an acceptance record claiming "TPU" must be able to
-    # prove it from the run directory (e.g. after a silent CPU fallback).
-    try:
-        import jax
-
-        dev = jax.devices()[0]
-        snap["resolved_platform"] = dev.platform
-        snap["resolved_device"] = dev.device_kind
-    except Exception:  # noqa: BLE001 — a snapshot never kills a run
-        pass
+    # actually ran — a record claiming "TPU" must be able to prove it
+    # from the run directory.
+    snap["resolved_platform"] = stamp["platform"]
+    snap["resolved_device"] = stamp["device_kind"]
+    snap["resolved_device_count"] = stamp["device_count"]
     with open(path / name, "w") as f:
         json.dump(snap, f, indent=2, default=str)
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> dict:
     cfg = load_config(sys.argv[1:] if argv is None else argv)
     setup_platform(cfg.get("platform"))
     from marl_distributedformation_tpu.parallel import init_distributed
 
-    if init_distributed():  # no-op single-process; env-var driven multi-host
+    # Before the first device query: jax.distributed.initialize() must
+    # precede backend start-up (no-op single-process).
+    multi_host = init_distributed()
+    stamp = announce_device("train")
+    if multi_host:
         import jax
 
         print(
@@ -376,7 +376,7 @@ def main(argv=None) -> None:
             f"of {len(jax.devices())} global devices"
         )
     trainer = build_trainer(cfg)
-    _snapshot_config(cfg, trainer.log_dir)
+    _snapshot_config(cfg, trainer.log_dir, stamp)
     # Live-metrics plane (obs/metrics.py, docs/observability.md): the
     # trainer records env-steps/s, chunk drain latency, checkpoint-writer
     # health, and compile counters into the process registry;
@@ -387,6 +387,7 @@ def main(argv=None) -> None:
         configure_ledger,
         configure_metrics,
         get_ledger,
+        get_registry,
     )
 
     configure_metrics(
@@ -395,8 +396,7 @@ def main(argv=None) -> None:
     )
     # Program ledger (obs/ledger.py): every compile this run performs
     # registers its executable's cost/memory facts; the census lands
-    # beside the checkpoints at exit for program_report.py / the
-    # chip-window census gate.
+    # beside the checkpoints at exit for program_report.py.
     configure_ledger(
         enabled=bool(cfg.get("ledger", True)),
         reservoir=int(cfg.get("ledger_reservoir", 256)),
@@ -418,16 +418,29 @@ def main(argv=None) -> None:
             telemetry.stop()
         ledger = get_ledger()
         if ledger.enabled and ledger.entries():
-            from pathlib import Path as _Path
-
             try:
                 path = ledger.write_census(
-                    _Path(trainer.log_dir) / "program_ledger.json"
+                    Path(trainer.log_dir) / "program_ledger.json"
                 )
                 print(f"[train] program ledger census -> {path}")
             except OSError as e:
                 print(f"[train] census write failed: {e!r}")
     print(f"[train] done at {trainer.num_timesteps} steps: {final}")
+    result = {
+        "entry": "train",
+        "name": str(cfg.name),
+        "log_dir": str(trainer.log_dir),
+        "num_timesteps": int(trainer.num_timesteps),
+        # RetraceGuard receipts as the lane published them (anakin: the
+        # one train program; sebulba: actor + learner).
+        "train_compiles": get_registry().snapshot().get("train_compiles"),
+        # Where the run's arrays sit while the trainer still holds them.
+        "residency_bytes": device_residency(),
+        "final": final,
+        **stamp,
+    }
+    print(json.dumps(result, default=str))
+    return result
 
 
 if __name__ == "__main__":
