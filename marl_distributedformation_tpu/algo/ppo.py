@@ -287,7 +287,8 @@ def ppo_update(
     grad_fn = jax.value_and_grad(ppo_loss, has_aux=True)
 
     def minibatch_step(ts: TrainState, idx: Array):
-        mb = jax.tree_util.tree_map(lambda x: x[idx], data)
+        with jax.named_scope("minibatch_gather"):
+            mb = jax.tree_util.tree_map(lambda x: x[idx], data)
         ent_coef = None
         if decay:
             # Two-limb float split of the integer step: a straight
@@ -315,38 +316,44 @@ def ppo_update(
                 log_std_ceiling = config.log_std_init + sprog * (
                     config.log_std_final - config.log_std_init
                 )
-        (_, metrics), grads = grad_fn(
-            ts.params, ts.apply_fn, mb, config, ent_coef
-        )
-        # Raw (pre-clip) global gradient norm: the divergence diagnostic
-        # the train lane's health word bounds (train/recovery.py) — the
-        # optimizer chain clips at max_grad_norm, so the clipped norm
-        # would saturate at 0.5 and hide every explosion.
-        metrics["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope("loss_and_grad"):
+            (_, metrics), grads = grad_fn(
+                ts.params, ts.apply_fn, mb, config, ent_coef
+            )
+            # Raw (pre-clip) global gradient norm: the divergence
+            # diagnostic the train lane's health word bounds
+            # (train/recovery.py) — the optimizer chain clips at
+            # max_grad_norm, so the clipped norm would saturate at 0.5
+            # and hide every explosion.
+            metrics["grad_norm"] = optax.global_norm(grads)
         if ent_decay:
             metrics["ent_coef"] = ent_coef
-        ts = ts.apply_gradients(grads=grads)
-        if std_decay:
-            # Project the log_std parameter under the decayed ceiling —
-            # every model family names its state-independent noise
-            # parameter "log_std" (models/mlp.py, ctde.py, gnn.py); the
-            # path-keyed clamp composes with vmapped populations (leaves
-            # gain a member axis, the name does not change).
-            metrics["log_std_ceiling"] = log_std_ceiling
+        with jax.named_scope("optimizer_step"):
+            ts = ts.apply_gradients(grads=grads)
+            if std_decay:
+                # Project the log_std parameter under the decayed ceiling
+                # — every model family names its state-independent noise
+                # parameter "log_std" (models/mlp.py, ctde.py, gnn.py);
+                # the path-keyed clamp composes with vmapped populations
+                # (leaves gain a member axis, the name does not change).
+                metrics["log_std_ceiling"] = log_std_ceiling
 
-            def clamp(path, leaf):
-                if _leaf_name(path[-1]) == "log_std":
-                    return jnp.minimum(leaf, log_std_ceiling)
-                return leaf
+                def clamp(path, leaf):
+                    if _leaf_name(path[-1]) == "log_std":
+                        return jnp.minimum(leaf, log_std_ceiling)
+                    return leaf
 
-            ts = ts.replace(
-                params=jax.tree_util.tree_map_with_path(clamp, ts.params)
-            )
+                ts = ts.replace(
+                    params=jax.tree_util.tree_map_with_path(
+                        clamp, ts.params
+                    )
+                )
         return ts, metrics
 
     def epoch_step(ts: TrainState, epoch_key: Array):
-        perm = jax.random.permutation(epoch_key, total)[:used]
-        idx = perm.reshape(num_minibatches, batch_size)
+        with jax.named_scope("epoch_shuffle"):
+            perm = jax.random.permutation(epoch_key, total)[:used]
+            idx = perm.reshape(num_minibatches, batch_size)
         ts, metrics = jax.lax.scan(minibatch_step, ts, idx)
         return ts, jax.tree_util.tree_map(lambda m: m.mean(), metrics)
 

@@ -110,5 +110,6 @@ def collect_rollout(
     (env_state, last_obs), batch = jax.lax.scan(
         body, (env_state, obs), step_keys
     )
-    _, _, last_value = policy(last_obs)
+    with jax.named_scope("policy"):  # the bootstrap value's forward pass
+        _, _, last_value = policy(last_obs)
     return env_state, last_obs, batch, last_value

@@ -109,7 +109,8 @@ class GNNActorCritic(nn.Module):
             )
         )
         for r in range(self.rounds):
-            h_nb = gather_nodes(h, idx)  # (..., N, k, E)
+            with jax.named_scope("neighbor_gather"):
+                h_nb = gather_nodes(h, idx)  # (..., N, k, E)
             h_self = jnp.broadcast_to(
                 h[..., :, None, :], h_nb.shape
             )
@@ -120,9 +121,10 @@ class GNNActorCritic(nn.Module):
                 )(msg_in)
             )
             if mask is not None:
-                nb_valid = gather_nodes(
-                    mask.astype(msg.dtype)[..., None], idx
-                )  # (..., N, k, 1)
+                with jax.named_scope("neighbor_gather"):
+                    nb_valid = gather_nodes(
+                        mask.astype(msg.dtype)[..., None], idx
+                    )  # (..., N, k, 1)
                 msg = msg * nb_valid
                 agg = msg.sum(axis=-2) / jnp.maximum(
                     nb_valid.sum(axis=-2), 1.0

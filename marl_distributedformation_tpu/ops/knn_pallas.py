@@ -308,6 +308,7 @@ def knn_batch_pallas_big(
             out_f32,
         ],
         interpret=interpret,
+        name="knn_streaming",
     )(x, y, x, y, vm)
     return _unpack_outputs(idx, offx, offy, dist, m, n)
 
@@ -374,5 +375,6 @@ def knn_batch_pallas(
             out_f32,
         ],
         interpret=interpret,
+        name="knn_fused",
     )(x, y, vm)
     return _unpack_outputs(idx, offx, offy, dist, m, n)

@@ -869,15 +869,25 @@ class Trainer:
                 extra = (self._next_scenario_chunk(rollouts),)
             else:
                 extra = (self.scenario_params,)
-            (
-                self.train_state,
-                self.env_state,
-                self.obs,
-                self.key,
-                metrics,
-            ) = self._iteration(
-                self.train_state, self.env_state, self.obs, self.key, *extra
-            )
+            # On the profiler's own clock (a relaxed atomic load while no
+            # trace is open): the dispatch index is the identifier every
+            # later span of this chunk shares.
+            with jax.profiler.StepTraceAnnotation(
+                "train_dispatch", step_num=self._dispatches
+            ):
+                (
+                    self.train_state,
+                    self.env_state,
+                    self.obs,
+                    self.key,
+                    metrics,
+                ) = self._iteration(
+                    self.train_state,
+                    self.env_state,
+                    self.obs,
+                    self.key,
+                    *extra,
+                )
         self._dispatches += 1
         # Live-metrics plane (obs/metrics.py, docs/observability.md):
         # recorded at the dispatch seam, never under trace (graftlint
@@ -1150,7 +1160,10 @@ class Trainer:
         Called after the NEXT chunk has been dispatched, so this blocks on
         the finished chunk while the device already runs the new one."""
         t_drain = time.perf_counter()
-        host = jax.device_get(stacked)
+        with jax.profiler.TraceAnnotation(
+            "train_drain", chunk=first_iteration
+        ):
+            host = jax.device_get(stacked)
         meter.tick(
             self._fused_chunk * self.ppo.n_steps * self.config.num_formations
         )
@@ -1472,135 +1485,6 @@ class Trainer:
         )
         self._vec_steps_since_save = 0
         return str(path)
-
-    def profile_breakdown(self, iters: int = 10) -> Dict[str, float]:
-        """Where does the train-iteration time go? Times the full jitted
-        iteration and its stages as standalone programs (fractions are
-        approximate — standalone stages miss cross-stage fusion, but the
-        split is the actionable signal: env vs policy vs update).
-
-        Returns seconds per iteration: ``total``, ``rollout`` (policy
-        sampling + env stepping), ``env`` (env stepping alone with fixed
-        actions), ``update`` (GAE + minibatch epochs), and derived
-        fractions ``frac_*`` of the stage sum.
-        """
-        import time
-
-        from marl_distributedformation_tpu.env.formation import step_batch
-
-        env_params, ppo = self.env_params, self.ppo
-        ts, env_state, obs, key = (
-            self.train_state, self.env_state, self.obs, self.key,
-        )
-        if self.scenario_params is not None:
-            # Time the stages through the SAME disturbance stack the total
-            # runs through (params close over as trace constants here —
-            # fine for a profiling twin), or the breakdown would book the
-            # scenario layers' cost to the update phase.
-            scenario_params = self.scenario_params
-            scenario_step = self._scenario_step_fn
-
-            def env_step_fn(s, v):
-                return scenario_step(s, v, scenario_params)
-        else:
-            env_step_fn = self._env_step_fn or (
-                lambda s, v: step_batch(s, v, env_params)
-            )
-        # Non-donating twin of self._iteration: the training jit donates its
-        # state buffers, which repeated timing calls would invalidate.
-        iteration_no_donate = jax.jit(self._iteration_core)
-
-        @jax.jit
-        def rollout_only(env_state, obs, key):
-            return collect_rollout(
-                ts.apply_fn, ts.params, env_state, obs, key, env_params,
-                ppo.n_steps, env_step_fn=env_step_fn,
-            )[2].rewards.sum()
-
-        @jax.jit
-        def env_only(env_state, key):
-            def body(carry, _):
-                state, key = carry
-                key, k = jax.random.split(key)
-                vel = env_params.max_speed * jax.random.uniform(
-                    k, (*state.agents.shape,), minval=-1.0, maxval=1.0
-                )
-                state, tr = env_step_fn(state, vel)
-                return (state, key), tr.reward.sum()
-
-            (_, _), r = jax.lax.scan(
-                body, (env_state, key), None, length=ppo.n_steps
-            )
-            return r.sum()
-
-        @jax.jit
-        def _collect(env_state, obs, key):
-            return collect_rollout(
-                ts.apply_fn, ts.params, env_state, obs, key, env_params,
-                ppo.n_steps, env_step_fn=env_step_fn,
-            )
-
-        _, last_obs, batch, last_value = _collect(env_state, obs, key)
-
-        @jax.jit
-        def update_only(key):
-            advantages, returns = compute_gae(
-                batch.rewards, batch.values, batch.dones, last_value,
-                ppo.gamma, ppo.gae_lambda,
-            )
-            n = env_params.num_agents
-            if self.per_formation:
-                row_shape = (n,)
-                update_ppo = dataclasses.replace(
-                    ppo, batch_size=max(1, ppo.batch_size // n)
-                )
-            else:
-                row_shape = ()
-                update_ppo = ppo
-            flat = MinibatchData(
-                obs=batch.obs.reshape(-1, *row_shape, env_params.obs_dim),
-                actions=batch.actions.reshape(
-                    -1, *row_shape, env_params.act_dim
-                ),
-                old_log_probs=batch.log_probs.reshape(-1, *row_shape),
-                advantages=advantages.reshape(-1, *row_shape),
-                returns=returns.reshape(-1, *row_shape),
-            )
-            _, m = ppo_update(
-                TrainState.create(
-                    apply_fn=ts.apply_fn, params=ts.params,
-                    tx=ppo.make_optimizer(),
-                ),
-                flat, key, update_ppo,
-            )
-            return m["loss"]
-
-        def timed(fn, *args):
-            jax.block_until_ready(fn(*args))  # compile + warmup
-            t0 = time.perf_counter()
-            for _ in range(iters):
-                out = fn(*args)
-            jax.block_until_ready(out)
-            return (time.perf_counter() - t0) / iters
-
-        extra = (
-            () if self.scenario_params is None else (self.scenario_params,)
-        )
-        result = {
-            "total": timed(
-                lambda: iteration_no_donate(ts, env_state, obs, key, *extra)[
-                    4
-                ]["loss"]
-            ),
-            "rollout": timed(rollout_only, env_state, obs, key),
-            "env": timed(env_only, env_state, key),
-            "update": timed(update_only, key),
-        }
-        result["policy"] = max(result["rollout"] - result["env"], 0.0)
-        stage_sum = result["env"] + result["policy"] + result["update"]
-        for k in ("env", "policy", "update"):
-            result[f"frac_{k}"] = result[k] / stage_sum if stage_sum else 0.0
-        return result
 
     # ------------------------------------------------------------------
     # Checkpointing (write/read contract: SURVEY.md §5)

@@ -160,14 +160,22 @@ def setup_compile_cache() -> str:
     """Place jax's persistent compilation cache and return its directory.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
-    nothing is set in code; otherwise the cache goes to
+    no directory is set in code; otherwise the cache goes to
     ``<repo>/.jax_cache`` (git-ignored). Call before the first
-    compilation."""
+    compilation.
+
+    Either way the cache's key takes in the program's metadata: jax
+    leaves it out by default, and an executable loaded from the cache
+    then carries the ``jax.named_scope`` names of whatever source first
+    compiled it, so a trace (and the benchmark's per-stage metrics, which
+    read those names from the executable) would show the stages of an
+    older checkout that shares the directory."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     path = str(repo_root() / COMPILE_CACHE_DIRNAME)
     jax.config.update("jax_compilation_cache_dir", path)
     return path

@@ -5,8 +5,10 @@ run can produce a TensorBoard-loadable TPU trace and throughput numbers.
 Wired into training via ``TrainConfig.profile`` / the ``profile=true`` CLI
 flag (train/trainer.py): the trainer captures a trace of a few post-warmup
 iterations into ``{log_dir}/profile/`` and the jitted iteration is
-``jax.named_scope``-annotated (rollout / policy / env_step / gae /
-ppo_update) so the trace viewer attributes time to pipeline stages.
+``jax.named_scope``-annotated (``DEVICE_SCOPES``) so the trace viewer
+attributes device time to pipeline stages, while the trainer's dispatch
+and drain seams carry ``jax.profiler`` annotations (``HOST_SPANS``) on
+the same clock.
 """
 
 from __future__ import annotations
@@ -34,6 +36,34 @@ from marl_distributedformation_tpu.analysis.guards import (  # noqa: F401
     register_aot_program,
     sample_device_watermark,
 )
+
+# The names the program gives its stages, in one place: the tests assert
+# each is an exact part of some compiled instruction's ``op_name``, and
+# the benchmark's per-layer readers (benchmarks/metrics/) key on them, so
+# a rename is one edit here and a test failure. docs/profiling.md has the
+# table of where each is opened and which metric reads it.
+#
+# ``jax.named_scope`` names. None is a jax primitive's or a jitted
+# helper's name (``gather``, ``sort``, ``shuffle`` are taken), so a path
+# part equal to one of these is that stage and nothing else.
+DEVICE_SCOPES = (
+    "rollout",  # train/trainer.py make_ppo_iteration
+    "policy",  # algo/rollout.py, under rollout
+    "env_step",  # algo/rollout.py, under rollout
+    "gae",  # train/trainer.py
+    "ppo_update",  # train/trainer.py
+    "epoch_shuffle",  # algo/ppo.py, under ppo_update
+    "minibatch_gather",  # algo/ppo.py, under ppo_update
+    "loss_and_grad",  # algo/ppo.py, under ppo_update
+    "optimizer_step",  # algo/ppo.py, under ppo_update
+    "neighbor_gather",  # models/gnn.py: under policy and loss_and_grad
+)
+# ``pl.pallas_call(name=...)`` of the two k-NN kernels (ops/knn_pallas.py):
+# N <= 512 fused, larger N streaming. Both keep the substring ``knn``.
+KERNEL_NAMES = ("knn_fused", "knn_streaming")
+# ``jax.profiler`` annotations on the trainer's host seams
+# (train/trainer.py ``_dispatch`` and ``_drain_chunk``).
+HOST_SPANS = ("train_dispatch", "train_drain")
 
 
 class TraceWindow:

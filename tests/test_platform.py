@@ -29,7 +29,8 @@ from marl_distributedformation_tpu.utils import (  # noqa: E402
 # caches, unpacked copies of the tree — not the tree.
 UNTRACKED_DIRS = {
     ".git", "__pycache__", ".pytest_cache", ".hypothesis", "logs",
-    "tensorboard", "outputs", ".jax_cache", "chiprun_out",
+    "tensorboard", "outputs", ".jax_cache", "chiprun_out", ".bench_out",
+    ".bench_proof",
 }
 
 
@@ -53,7 +54,8 @@ def test_compile_cache_env_var_wins_and_nothing_is_set_in_code(monkeypatch):
         jax.config, "update", lambda *a, **k: calls.append(a)
     )
     assert setup_compile_cache() == "/somewhere/else"
-    assert calls == []
+    # no directory; only the key is told to take in the scope names
+    assert calls == [("jax_compilation_cache_include_metadata_in_key", True)]
 
 
 def test_compile_cache_default_is_one_fixed_path_in_the_checkout(
@@ -67,7 +69,10 @@ def test_compile_cache_default_is_one_fixed_path_in_the_checkout(
     want = str(REPO / ".jax_cache")
     assert setup_compile_cache() == want
     assert setup_compile_cache() == want  # never a pid, a time, a tmp dir
-    assert calls == [("jax_compilation_cache_dir", want)] * 2
+    assert calls == [
+        ("jax_compilation_cache_include_metadata_in_key", True),
+        ("jax_compilation_cache_dir", want),
+    ] * 2
     assert ".jax_cache/" in (REPO / ".gitignore").read_text().splitlines()
 
 

@@ -1,0 +1,250 @@
+"""The names the program gives its stages (``utils.profiling.DEVICE_SCOPES``,
+``KERNEL_NAMES``, ``HOST_SPANS``) reach where the measurement reads them:
+the compiled iteration's ``op_name`` metadata, the Pallas kernels' names,
+the profiler's host plane, and the benchmark's per-layer readers."""
+
+import json
+import re
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmarks import harness, host_spans
+from marl_distributedformation_tpu.algo import PPOConfig
+from marl_distributedformation_tpu.env import EnvParams
+from marl_distributedformation_tpu.models import GNNActorCritic
+from marl_distributedformation_tpu.ops.knn_pallas import (
+    knn_batch_pallas,
+    knn_batch_pallas_big,
+)
+from marl_distributedformation_tpu.train import TrainConfig, Trainer
+from marl_distributedformation_tpu.utils.profiling import (
+    DEVICE_SCOPES,
+    HOST_SPANS,
+    KERNEL_NAMES,
+)
+
+ROOT = Path(__file__).resolve().parents[1]
+# The scope each stage is opened under; None at the iteration's top level.
+PARENTS = {
+    "rollout": (None,),
+    "policy": ("rollout",),
+    "env_step": ("rollout",),
+    "gae": (None,),
+    "ppo_update": (None,),
+    "epoch_shuffle": ("ppo_update",),
+    "minibatch_gather": ("ppo_update",),
+    "loss_and_grad": ("ppo_update",),
+    "optimizer_step": ("ppo_update",),
+    "neighbor_gather": ("policy", "loss_and_grad"),
+}
+GNN_ONLY = ("neighbor_gather",)
+
+
+def _tiny_trainer(policy, tmp_path, **config):
+    config = {
+        "num_formations": 4, "name": "scopes", "checkpoint": False,
+        "log_dir": str(tmp_path / "logs"), **config,
+    }
+    if policy == "mlp":
+        return Trainer(
+            EnvParams(num_agents=3),
+            ppo=PPOConfig(n_steps=4, batch_size=24, n_epochs=2),
+            config=TrainConfig(**config),
+        )
+    return Trainer(
+        EnvParams(num_agents=8, obs_mode="knn", knn_k=3),
+        ppo=PPOConfig(n_steps=4, batch_size=32, n_epochs=2),
+        model=GNNActorCritic(k=3, rounds=2),
+        config=TrainConfig(**config),
+    )
+
+
+@pytest.fixture(scope="module")
+def op_paths(tmp_path_factory):
+    """policy -> every ``op_name`` of the compiled tiny training iteration,
+    split into its ``/``-separated parts."""
+    paths = {}
+    for policy in ("mlp", "gnn"):
+        trainer = _tiny_trainer(policy, tmp_path_factory.mktemp(policy))
+        text = trainer._iteration.lower(
+            trainer.train_state, trainer.env_state, trainer.obs, trainer.key
+        ).compile().as_text()
+        # A reducer's own body (``to_apply``) carries a path cut at its
+        # head; a trace shows the instruction that calls it, whose path is
+        # whole and starts at the jitted program.
+        paths[policy] = [
+            name.split("/")
+            for name in set(re.findall(r'op_name="(jit\([^"]*)"', text))
+        ]
+    return paths
+
+
+@pytest.mark.parametrize("policy", ["mlp", "gnn"])
+@pytest.mark.parametrize("scope", DEVICE_SCOPES)
+def test_scope_is_an_exact_path_part_under_its_parent(op_paths, scope, policy):
+    assert set(PARENTS) == set(DEVICE_SCOPES)
+    found = set()
+    for parts in op_paths[policy]:
+        if scope in parts:
+            above = [p for p in parts[: parts.index(scope)] if p in DEVICE_SCOPES]
+            found.add(above[-1] if above else None)
+    if policy == "mlp" and scope in GNN_ONLY:
+        assert not found
+        return
+    assert found == set(PARENTS[scope]), (scope, policy, found)
+
+
+def test_no_name_is_a_primitive_or_helper_of_jax():
+    names = DEVICE_SCOPES + KERNEL_NAMES + HOST_SPANS
+    assert len(set(names)) == len(names)
+    # what jax itself writes into op_name on the update's path
+    assert not set(names) & {"gather", "sort", "shuffle", "scatter-add", "while", "body"}
+    assert all("knn" in name for name in KERNEL_NAMES)  # knn_roofline matches on it
+
+
+@pytest.mark.parametrize(
+    "name,kernel,n",
+    [
+        ("knn_fused", lambda p: knn_batch_pallas(p, 4), 100),
+        ("knn_streaming", lambda p: knn_batch_pallas_big(p, 4), 600),
+    ],
+)
+def test_pallas_call_carries_its_name(name, kernel, n):
+    """Lowered for the TPU from here (no chip, no TPU library): the Mosaic
+    call's ``kernel_name`` is what names the instruction in a trace."""
+    assert name in KERNEL_NAMES
+    text = (
+        jax.jit(kernel)
+        .trace(jax.ShapeDtypeStruct((8, n, 2), jnp.float32))
+        .lower(lowering_platforms=("tpu",))
+        .as_text()
+    )
+    assert "tpu_custom_call" in text
+    assert re.findall(r'kernel_name = "(\w+)"', text) == [name]
+
+
+def test_host_spans_one_dispatch_a_chunk_and_one_drain(tmp_path):
+    """Two chunks dispatched under the profiler, then a whole fused run:
+    the annotations are on the profiler's host plane, one ``train_dispatch``
+    a chunk with an increasing ``step_num``, one ``train_drain`` a chunk
+    drained, and the benchmark's helper reads exactly those names."""
+    trainer = _tiny_trainer("mlp", tmp_path, fused_chunk=1)
+    jax.block_until_ready(trainer.run_chunk())  # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        for _ in range(2):
+            jax.block_until_ready(trainer.run_chunk())
+    finally:
+        jax.profiler.stop_trace()
+    from benchmarks import trace
+
+    xplane = trace.find_xplane(tmp_path / "trace")
+    data = jax.profiler.ProfileData.from_file(str(xplane))
+    steps = sorted(
+        dict(ev.stats)["step_num"]
+        for plane in data.planes
+        if plane.name == host_spans.HOST_PLANE
+        for line in plane.lines
+        for ev in line.events
+        if ev.name == "train_dispatch"
+    )
+    assert steps == [1, 2]
+    spans = host_spans.read_host_spans(xplane, HOST_SPANS)
+    assert set(spans) == {"train_dispatch"}
+    assert [args["step_num"] for _, _, args in spans["train_dispatch"]] == [1, 2]
+    assert all(end > start for start, end, _ in spans["train_dispatch"])
+
+    trainer = _tiny_trainer(
+        "mlp", tmp_path, fused_chunk=1, total_timesteps=3 * 4 * 4 * 3,
+        log_dir=str(tmp_path / "fused"),
+    )
+    jax.profiler.start_trace(str(tmp_path / "trace_fused"))
+    try:
+        trainer.train()
+    finally:
+        jax.profiler.stop_trace()
+    spans = host_spans.read_host_spans(
+        trace.find_xplane(tmp_path / "trace_fused"), HOST_SPANS
+    )
+    assert [a["step_num"] for _, _, a in spans["train_dispatch"]] == [0, 1, 2]
+    assert [a["chunk"] for _, _, a in spans["train_drain"]] == [0, 1, 2]
+
+
+# ----------------------------------------------------------------------
+# The per-layer readers this adds, on a context made by hand
+# ----------------------------------------------------------------------
+
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+SCOPE_READERS = {
+    "update_shuffle_ms": "epoch_shuffle",
+    "update_gather_ms": "minibatch_gather",
+    "update_grad_ms": "loss_and_grad",
+    "update_optimizer_ms": "optimizer_step",
+    "env_step_ms": "env_step",
+    "policy_forward_ms": "policy",
+    "knn_ms": "knn_fused",
+    "gnn_neighbor_ms": "neighbor_gather",
+}
+
+
+def _context(scope_s, iterations=2):
+    return {"trace": {"scope_s": scope_s}, "iterations": iterations}
+
+
+def _reader(metric):
+    return harness.load_reader(ROOT / BENCH["paths"][0], metric)
+
+
+@pytest.mark.parametrize("metric,scope", sorted(SCOPE_READERS.items()))
+def test_scope_reader_reads_its_scope_or_nothing(metric, scope):
+    assert scope in DEVICE_SCOPES + KERNEL_NAMES
+    read = _reader(metric)
+    assert read(_context({"ppo_update": 3.0, "rollout": 1.0})) is None
+    assert read(_context({scope: 0.5, "ppo_update": 3.0})) == pytest.approx(250.0)
+
+
+def test_unattributed_is_the_update_less_its_stages():
+    read = _reader("update_unattributed_ms")
+    stages = {"epoch_shuffle": 1.0, "minibatch_gather": 6.0, "loss_and_grad": 2.0,
+              "optimizer_step": 0.5}
+    value = read(_context({"ppo_update": 10.0, "rollout": 4.0, **stages}))
+    assert value == pytest.approx(250.0)
+    by_stage = [
+        _reader(m)(_context({"ppo_update": 10.0, **stages}))
+        for m, s in SCOPE_READERS.items() if s in stages
+    ]
+    assert sum(by_stage) + value == pytest.approx(1e3 * 10.0 / 2)
+    # one stage the program lacks counts as nothing; no stage at all, or
+    # no update, is nothing to split
+    assert read(_context({"ppo_update": 10.0, "minibatch_gather": 6.0})) == pytest.approx(2000.0)
+    assert read(_context({"ppo_update": 10.0})) is None
+    assert read(_context({"minibatch_gather": 6.0})) is None
+
+
+def test_dispatch_enqueue_reads_the_mean_span_or_nothing(tmp_path, monkeypatch):
+    read = _reader("dispatch_enqueue_ms")
+    cell = harness.load_cell("mlp5-train-m262k", ROOT)
+    cell.bench_dir = tmp_path / "benchmarks"  # no trace was written here
+    assert read({"cell": cell}) is None
+    spans = {"train_dispatch": [(0.0, 1e6, {"step_num": 3}), (5e6, 8e6, {"step_num": 4})]}
+    monkeypatch.setattr(host_spans, "host_spans", lambda cell: spans)
+    assert _reader("dispatch_enqueue_ms")({"cell": cell}) == pytest.approx(2.0)
+
+
+def test_new_entries_name_their_cells_and_layers():
+    per_layer = {m["name"]: m for m in BENCH["per_layer"]}
+    added = list(SCOPE_READERS) + ["update_unattributed_ms", "dispatch_enqueue_ms"]
+    layers = {m["layer"] for m in BENCH["per_layer"][:8]}
+    for name in added:
+        entry = per_layer[name]
+        assert (entry["unit"], entry["better"], entry["moves"]) == (
+            "ms", "lower", "agent_steps_per_s")
+        assert entry["layer"] in layers
+    for name in ("knn_ms", "gnn_neighbor_ms"):
+        assert per_layer[name]["workloads"] == ["gnn100-train-m8k"]
+    mlp5 = [m["name"] for m in harness.load_cell("mlp5-train-m262k", ROOT).per_layer]
+    assert "knn_ms" not in mlp5 and "gnn_neighbor_ms" not in mlp5
+    assert "update_gather_ms" in mlp5 and "dispatch_enqueue_ms" in mlp5

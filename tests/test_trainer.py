@@ -233,22 +233,6 @@ def test_profile_flag_writes_trace(tmp_path):
     assert any(f.is_file() for f in files), "trace directory is empty"
 
 
-@pytest.mark.slow
-def test_profile_breakdown(tmp_path):
-    trainer = tiny_trainer(tmp_path, checkpoint=False)
-    bd = trainer.profile_breakdown(iters=2)
-    for k in ("total", "rollout", "env", "update", "policy"):
-        assert bd[k] >= 0.0, bd
-    assert bd["total"] > 0.0 and bd["rollout"] > 0.0
-    np.testing.assert_allclose(
-        bd["frac_env"] + bd["frac_policy"] + bd["frac_update"], 1.0,
-        rtol=1e-6,
-    )
-    # the trainer remains usable afterwards (no donated-buffer corruption)
-    metrics = trainer.run_iteration()
-    assert np.isfinite(float(metrics["loss"]))
-
-
 def test_throughput_windowed_rate():
     import time as time_mod
 
