@@ -16,7 +16,9 @@ whole update one XLA program; with default sizes the remainder is zero.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+import itertools
+import math
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -142,6 +144,42 @@ def _wmean(x: Array, weights: Array) -> Array:
     return (x * w).sum() / jnp.maximum(w.sum(), 1e-8)
 
 
+# One vreg's lanes: rows at most this wide are gathered as one packed row.
+_PACK_MAX_WIDTH = 128
+
+
+def _pack_rows(
+    data: MinibatchData,
+) -> Optional[Tuple[Array, Callable[[Array], MinibatchData]]]:
+    """Concatenate ``data``'s leaves into one float32 ``(total, width)``
+    table, so a minibatch's rows are looked up once instead of once a leaf
+    (the TPU pays a narrow-row gather per index, not per byte).
+
+    Returns ``(table, unpack)`` with ``unpack(table[idx])`` bitwise equal to
+    ``tree_map(lambda x: x[idx], data)``, or ``None`` where the rows do not
+    pack: a leaf that is not float32, or rows wider than one vreg's lanes
+    (per-formation rows are already wide contiguous blocks)."""
+    leaves, treedef = jax.tree_util.tree_flatten(data)
+    shapes = [x.shape[1:] for x in leaves]
+    widths = [math.prod(shape) for shape in shapes]
+    if sum(widths) > _PACK_MAX_WIDTH or any(
+        x.dtype != jnp.float32 for x in leaves
+    ):
+        return None
+    table = jnp.concatenate(
+        [x.reshape(-1, w) for x, w in zip(leaves, widths)], axis=1
+    )
+    splits = list(itertools.accumulate(widths))[:-1]
+
+    def unpack(rows: Array) -> MinibatchData:
+        return treedef.unflatten(
+            col.reshape(-1, *shape)
+            for col, shape in zip(jnp.split(rows, splits, axis=1), shapes)
+        )
+
+    return table, unpack
+
+
 def ppo_loss(
     nn_params: Any,
     apply_fn,
@@ -231,7 +269,8 @@ def ppo_update(
 
     ``data`` leaves are flat ``(total, ...)`` with ``total = T * M * N``
     agent-transitions — each agent is its own "environment", the reference's
-    parameter-sharing trick (vectorized_env.py:32).
+    parameter-sharing trick (vectorized_env.py:32). Narrow float32 rows are
+    packed into one table so a minibatch is one gather (``_pack_rows``).
     """
     total = data.obs.shape[0]
     # Clamp for rollouts smaller than batch_size (e.g. num_formation=1):
@@ -286,9 +325,18 @@ def ppo_update(
 
     grad_fn = jax.value_and_grad(ppo_loss, has_aux=True)
 
+    # Decided at trace time from what ``data`` holds; ``row_pack`` in a
+    # trace says the job's rows packed (docs/profiling.md).
+    with jax.named_scope("minibatch_gather"), jax.named_scope("row_pack"):
+        packed = _pack_rows(data)
+
     def minibatch_step(ts: TrainState, idx: Array):
         with jax.named_scope("minibatch_gather"):
-            mb = jax.tree_util.tree_map(lambda x: x[idx], data)
+            if packed is None:
+                mb = jax.tree_util.tree_map(lambda x: x[idx], data)
+            else:
+                table, unpack = packed
+                mb = unpack(table[idx])
         ent_coef = None
         if decay:
             # Two-limb float split of the integer step: a straight

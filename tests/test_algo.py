@@ -15,7 +15,12 @@ from marl_distributedformation_tpu.algo import (
     ppo_loss,
     ppo_update,
 )
-from marl_distributedformation_tpu.models import MLPActorCritic, distributions
+from marl_distributedformation_tpu.algo import ppo as ppo_module
+from marl_distributedformation_tpu.models import (
+    GNNActorCritic,
+    MLPActorCritic,
+    distributions,
+)
 from flax.training.train_state import TrainState
 
 
@@ -309,3 +314,107 @@ def test_ppo_update_batch_remainder_dropped():
     data = _make_batch(ts, jax.random.PRNGKey(6), n=100)
     ts2, metrics = ppo_update(ts, data, jax.random.PRNGKey(7), config)
     assert np.isfinite(float(metrics["loss"]))
+
+
+# ----------------------------------------------------------------------
+# Row packing: one gather a minibatch, the same rows bit for bit
+# ----------------------------------------------------------------------
+
+
+def _unpacked(monkeypatch, update, *args):
+    """``update(*args)`` with every leaf gathered on its own, as before rows
+    were packed: the reference the packed path has to equal bit for bit."""
+    with monkeypatch.context() as m:
+        m.setattr(ppo_module, "_pack_rows", lambda data: None)
+        return update(*args)
+
+
+def _assert_same_update(got, want):
+    (ts_got, metrics_got), (ts_want, metrics_want) = got, want
+    chex.assert_trees_all_equal(
+        (ts_got.params, ts_got.opt_state, ts_got.step, metrics_got),
+        (ts_want.params, ts_want.opt_state, ts_want.step, metrics_want),
+    )
+
+
+def _traces_row_pack(ts, data, config):
+    text = jax.jit(
+        lambda ts, data, key: ppo_update(ts, data, key, config)
+    ).lower(ts, data, jax.random.PRNGKey(0)).as_text(debug_info=True)
+    assert "minibatch_gather/" in text
+    return "minibatch_gather/row_pack/" in text
+
+
+def _per_formation_batch(key, rows, n, obs_dim, with_mask):
+    ks = jax.random.split(key, 6)
+    active = (jax.random.uniform(ks[5], (rows, n)) > 0.2).astype(jnp.float32)
+    active = active.at[:, 0].set(1.0)
+    return MinibatchData(
+        obs=jax.random.normal(ks[0], (rows, n, obs_dim)),
+        actions=jax.random.normal(ks[1], (rows, n, 2)),
+        old_log_probs=-jnp.abs(jax.random.normal(ks[2], (rows, n))),
+        advantages=jax.random.normal(ks[3], (rows, n)),
+        returns=jax.random.normal(ks[4], (rows, n)),
+        weights=active if with_mask else None,
+        mask=active if with_mask else None,
+    )
+
+
+@pytest.mark.parametrize("rows", [256, 100], ids=["whole", "remainder"])
+@pytest.mark.parametrize("weighted", [False, True], ids=["plain", "weights"])
+def test_packed_rows_equal_a_gather_a_leaf_bitwise(monkeypatch, weighted, rows):
+    ts, config = _make_train_state()
+    config = dataclasses.replace(config, batch_size=48)
+    data = _make_batch(ts, jax.random.PRNGKey(4), n=rows)
+    if weighted:
+        w = jax.random.uniform(jax.random.PRNGKey(8), (rows,)) > 0.25
+        data = data.replace(weights=w.astype(jnp.float32))
+    assert _traces_row_pack(ts, data, config)
+    args = (ts, data, jax.random.PRNGKey(5), config)
+    _assert_same_update(ppo_update(*args), _unpacked(monkeypatch, ppo_update, *args))
+
+
+def test_packed_rows_equal_under_vmap_over_two_members(monkeypatch):
+    ts, _ = _make_train_state()
+    config = PPOConfig(batch_size=48, n_epochs=2)
+    other = MLPActorCritic(act_dim=2).init(jax.random.PRNGKey(1), jnp.zeros((1, 8)))
+    states = [ts, ts.replace(params=other)]
+    datas = [
+        _make_batch(ts, jax.random.PRNGKey(4 + i), n=100)
+        for i, ts in enumerate(states)
+    ]
+    stack = lambda *xs: jnp.stack(xs)  # noqa: E731
+    members = (
+        jax.tree_util.tree_map(stack, *states),
+        jax.tree_util.tree_map(stack, *datas),
+        jax.random.split(jax.random.PRNGKey(5), 2),
+    )
+    update = jax.vmap(lambda ts, data, key: ppo_update(ts, data, key, config))
+    got = update(*members)
+    _assert_same_update(got, _unpacked(monkeypatch, update, *members))
+    # and each member is the run it would have been alone
+    alone = ppo_update(states[1], datas[1], members[2][1], config)
+    np.testing.assert_allclose(
+        np.asarray(got[1]["loss"][1]), np.asarray(alone[1]["loss"]), rtol=1e-5
+    )
+
+
+@pytest.mark.parametrize("with_mask", [False, True], ids=["wide", "mask"])
+def test_per_formation_rows_do_not_pack(monkeypatch, with_mask):
+    """Rows of a whole formation (9 agents x (16 + 2 + 3) floats, over one
+    vreg's 128 lanes) keep the gather a leaf: no ``row_pack`` scope, the
+    same result."""
+    n, obs_dim = 9, 16
+    model = GNNActorCritic(k=3, rounds=1)
+    config = PPOConfig(batch_size=8, n_epochs=2)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, n, obs_dim)))
+    ts = TrainState.create(
+        apply_fn=model.apply, params=params, tx=config.make_optimizer()
+    )
+    data = _per_formation_batch(jax.random.PRNGKey(1), 20, n, obs_dim, with_mask)
+    assert not _traces_row_pack(ts, data, config)
+    args = (ts, data, jax.random.PRNGKey(2), config)
+    got = ppo_update(*args)
+    _assert_same_update(got, _unpacked(monkeypatch, ppo_update, *args))
+    assert np.isfinite(float(got[1]["loss"]))
+    assert float(got[1]["grad_norm"]) > 0

@@ -36,11 +36,15 @@ PARENTS = {
     "ppo_update": (None,),
     "epoch_shuffle": ("ppo_update",),
     "minibatch_gather": ("ppo_update",),
+    "row_pack": ("minibatch_gather",),
     "loss_and_grad": ("ppo_update",),
     "optimizer_step": ("ppo_update",),
     "neighbor_gather": ("policy", "loss_and_grad"),
 }
 GNN_ONLY = ("neighbor_gather",)
+# Rows of 13 floats pack; a formation's rows (8 agents x 21 floats) are
+# over one vreg's lanes and keep the gather a leaf.
+MLP_ONLY = ("row_pack",)
 
 
 def _tiny_trainer(policy, tmp_path, **config):
@@ -63,15 +67,23 @@ def _tiny_trainer(policy, tmp_path, **config):
 
 
 @pytest.fixture(scope="module")
-def op_paths(tmp_path_factory):
+def compiled_text(tmp_path_factory):
+    """policy -> the compiled tiny training iteration's HLO text."""
+    texts = {}
+    for policy in ("mlp", "gnn"):
+        trainer = _tiny_trainer(policy, tmp_path_factory.mktemp(policy))
+        texts[policy] = trainer._iteration.lower(
+            trainer.train_state, trainer.env_state, trainer.obs, trainer.key
+        ).compile().as_text()
+    return texts
+
+
+@pytest.fixture(scope="module")
+def op_paths(compiled_text):
     """policy -> every ``op_name`` of the compiled tiny training iteration,
     split into its ``/``-separated parts."""
     paths = {}
-    for policy in ("mlp", "gnn"):
-        trainer = _tiny_trainer(policy, tmp_path_factory.mktemp(policy))
-        text = trainer._iteration.lower(
-            trainer.train_state, trainer.env_state, trainer.obs, trainer.key
-        ).compile().as_text()
+    for policy, text in compiled_text.items():
         # A reducer's own body (``to_apply``) carries a path cut at its
         # head; a trace shows the instruction that calls it, whose path is
         # whole and starts at the jitted program.
@@ -91,10 +103,22 @@ def test_scope_is_an_exact_path_part_under_its_parent(op_paths, scope, policy):
         if scope in parts:
             above = [p for p in parts[: parts.index(scope)] if p in DEVICE_SCOPES]
             found.add(above[-1] if above else None)
-    if policy == "mlp" and scope in GNN_ONLY:
+    if scope in {"mlp": GNN_ONLY, "gnn": MLP_ONLY}[policy]:
         assert not found
         return
     assert found == set(PARENTS[scope]), (scope, policy, found)
+
+
+@pytest.mark.parametrize("policy,gathers", [("mlp", 1), ("gnn", 5)])
+def test_a_minibatch_is_one_gather_where_rows_pack(compiled_text, policy, gathers):
+    """Packed rows are looked up once a minibatch; a leaf at a time (five
+    leaves) where they are not."""
+    found = [
+        line for line in compiled_text[policy].splitlines()
+        if re.search(r"= \S+ gather\(", line)
+        and re.search(r'op_name="[^"]*/minibatch_gather/', line)
+    ]
+    assert len(found) == gathers, found
 
 
 def test_no_name_is_a_primitive_or_helper_of_jax():
