@@ -16,8 +16,10 @@ from marl_distributedformation_tpu.models import (
     CTDEActorCritic,
     GNNActorCritic,
     MLPActorCritic,
+    TrunkActorCritic,
     distributions,
 )
+from marl_distributedformation_tpu.models.trunk import load_trunk_arch
 
 # Checkpoints record the policy architecture by class name (trainer
 # ``_checkpoint_target``); this registry maps it back for playback.
@@ -25,20 +27,24 @@ POLICY_REGISTRY = {
     "MLPActorCritic": MLPActorCritic,
     "CTDEActorCritic": CTDEActorCritic,
     "GNNActorCritic": GNNActorCritic,
+    "TrunkActorCritic": TrunkActorCritic,
 }
 
 
-def model_kwargs_for(policy: str, env_params=None) -> dict:
+def model_kwargs_for(policy: str, env_params=None, trunk=None) -> dict:
     """Extra constructor arguments a policy needs beyond ``act_dim``,
-    derived from the environment configuration (the checkpoint records only
-    the architecture name)."""
-    if policy == "GNNActorCritic":
+    derived from the environment configuration (the checkpoint records the
+    architecture's class name and, for the trunk, its file's name)."""
+    if policy in ("GNNActorCritic", "TrunkActorCritic"):
         if env_params is None:
             raise ValueError(
-                "GNNActorCritic playback needs env_params (for knn_k / "
+                f"{policy} playback needs env_params (for knn_k / "
                 "goal_in_obs); pass env_params to from_checkpoint"
             )
-        return {"k": env_params.knn_k, "goal_in_obs": env_params.goal_in_obs}
+        kwargs = {"k": env_params.knn_k, "goal_in_obs": env_params.goal_in_obs}
+        if policy == "TrunkActorCritic":
+            kwargs["arch"] = load_trunk_arch(str(trunk))
+        return kwargs
     return {}
 
 
@@ -121,7 +127,7 @@ class LoadedPolicy:
         policy = raw.get("policy", "MLPActorCritic")
         if num_agents is None and env_params is not None:
             num_agents = env_params.num_agents
-        kwargs = model_kwargs_for(policy, env_params)
+        kwargs = model_kwargs_for(policy, env_params, raw.get("trunk"))
         hidden = infer_hidden(raw["params"]["params"], policy)
         if hidden:
             kwargs["hidden"] = hidden

@@ -68,6 +68,9 @@ def fits_vmem(n: int) -> bool:
 _BIG_KERNEL_AUTO_MAX_N = 16384
 
 
+_STREAMING_VMEM_LIMIT = 64 * 1024 * 1024  # N=16384 unrolls 32 chunks
+
+
 def fits_big_kernel(n: int) -> bool:
     return n <= _BIG_KERNEL_AUTO_MAX_N
 
@@ -248,8 +251,8 @@ def knn_batch_pallas_big(
     points: Array,
     k: int,
     valid: Optional[Array] = None,
-    block_r: int = 256,
-    chunk_c: int = 512,
+    block_r: Optional[int] = None,
+    chunk_c: Optional[int] = None,
     block_m: int = 1,
     interpret: bool = False,
 ) -> Tuple[Array, Array, Array]:
@@ -269,9 +272,17 @@ def knn_batch_pallas_big(
     index).
 
     ``block_r``/``chunk_c`` must be lane-aligned (multiples of 128); N pads
-    to their lcm. Defaults stream ~3 MB of VMEM intermediates per program.
+    to their lcm. The defaults, 256 x 512 tiles, stream ~3 MB of VMEM
+    intermediates per program up to N=2048; past it the tiles are 128 rows
+    by a quarter of N, which is what keeps the compile short (for a
+    described v5e the kernel alone at N=8192: 68 s with 256 x 512 tiles, 16
+    chunks unrolled; 22 s with 256 x 2048; 6 s with 128 x 2048).
     """
     m, n, d = points.shape
+    if block_r is None:
+        block_r = 256 if n <= 2048 else 128
+    if chunk_c is None:
+        chunk_c = max(512, 128 * -(-n // (4 * 128)))
     assert d == 2, f"knn_batch_pallas_big is 2-D only, got d={d}"
     assert k < n, f"knn needs k < N (k={k}, N={n})"
     assert block_r % 128 == 0 and chunk_c % 128 == 0, (
@@ -309,6 +320,12 @@ def knn_batch_pallas_big(
         ],
         interpret=interpret,
         name="knn_streaming",
+        # the chunk loop is a static unroll and the compiler keeps every
+        # chunk's tile intermediates on the stack: 16.2 MB at N=8192, past
+        # the 16 MB a kernel gets unasked (a v5e core has 128 MB)
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_STREAMING_VMEM_LIMIT
+        ),
     )(x, y, x, y, vm)
     return _unpack_outputs(idx, offx, offy, dist, m, n)
 

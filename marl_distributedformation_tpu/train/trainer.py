@@ -378,7 +378,9 @@ class Trainer:
             )
         else:
             dummy_obs = jnp.zeros((1, env_params.obs_dim), jnp.float32)
-        params = self.model.init(k_init, dummy_obs)
+        # jitted: only the parameters leave it, so the forward pass that
+        # flax's init traces is dead code and never compiled or run
+        params = jax.jit(self.model.init)(k_init, dummy_obs)
         # lr backoff needs the rate IN the optimizer state (pure data,
         # no recompile on a rollback) — inject only when the knob is
         # live so the default opt-state layout (and its checkpoints)
@@ -1502,6 +1504,9 @@ class Trainer:
             # on mismatch).
             "learning_rate": float(self.ppo.learning_rate),
         }
+        if hasattr(self.model, "arch"):
+            # the architecture file playback rebuilds a trunk from
+            target["trunk"] = self.model.arch.name
         if not self._multihost:
             # dp-sharded env state is not coordinator-addressable across
             # hosts; multi-host checkpoints carry the learner state only and

@@ -58,6 +58,10 @@ DEVICE_SCOPES = (
     "loss_and_grad",  # algo/ppo.py, under ppo_update
     "optimizer_step",  # algo/ppo.py, under ppo_update
     "neighbor_gather",  # models/gnn.py: under policy and loss_and_grad
+    # models/trunk.py, under policy and loss_and_grad
+    "trunk_attention",  # q/k/v/o products, norms, RoPE, blocked softmax
+    "trunk_indexer",  # its three products, the index scores, the selection
+    "trunk_moe",  # router, the held experts' products, mask and combine
 )
 # ``pl.pallas_call(name=...)`` of the two k-NN kernels (ops/knn_pallas.py):
 # N <= 512 fused, larger N streaming. Both keep the substring ``knn``.

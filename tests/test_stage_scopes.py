@@ -14,7 +14,8 @@ import pytest
 from benchmarks import harness, host_spans
 from marl_distributedformation_tpu.algo import PPOConfig
 from marl_distributedformation_tpu.env import EnvParams
-from marl_distributedformation_tpu.models import GNNActorCritic
+from marl_distributedformation_tpu.models import GNNActorCritic, TrunkActorCritic
+from marl_distributedformation_tpu.models.trunk import load_trunk_arch
 from marl_distributedformation_tpu.ops.knn_pallas import (
     knn_batch_pallas,
     knn_batch_pallas_big,
@@ -40,8 +41,15 @@ PARENTS = {
     "loss_and_grad": ("ppo_update",),
     "optimizer_step": ("ppo_update",),
     "neighbor_gather": ("policy", "loss_and_grad"),
+    "trunk_attention": ("policy", "loss_and_grad"),
+    # what of them has no tangent (the selection's counting passes, the
+    # assignment sort) jax hoists out of the differentiated function, and
+    # the hoisted layer loop loses ``loss_and_grad`` from its path
+    "trunk_indexer": ("policy", "loss_and_grad", "ppo_update"),
+    "trunk_moe": ("policy", "loss_and_grad", "ppo_update"),
 }
 GNN_ONLY = ("neighbor_gather",)
+TRUNK_ONLY = ("trunk_attention", "trunk_indexer", "trunk_moe")
 # Rows of 13 floats pack; a formation's rows (8 agents x 21 floats) are
 # over one vreg's lanes and keep the gather a leaf.
 MLP_ONLY = ("row_pack",)
@@ -58,10 +66,14 @@ def _tiny_trainer(policy, tmp_path, **config):
             ppo=PPOConfig(n_steps=4, batch_size=24, n_epochs=2),
             config=TrainConfig(**config),
         )
+    if policy == "gnn":
+        agents, model = 8, GNNActorCritic(k=3, rounds=2)
+    else:  # 16 agents: the second block of 8 queries sees more than topk 8
+        agents, model = 16, TrunkActorCritic(arch=load_trunk_arch("tiny"), k=3)
     return Trainer(
-        EnvParams(num_agents=8, obs_mode="knn", knn_k=3),
-        ppo=PPOConfig(n_steps=4, batch_size=32, n_epochs=2),
-        model=GNNActorCritic(k=3, rounds=2),
+        EnvParams(num_agents=agents, obs_mode="knn", knn_k=3),
+        ppo=PPOConfig(n_steps=4, batch_size=4 * agents, n_epochs=2),
+        model=model,
         config=TrainConfig(**config),
     )
 
@@ -70,7 +82,7 @@ def _tiny_trainer(policy, tmp_path, **config):
 def compiled_text(tmp_path_factory):
     """policy -> the compiled tiny training iteration's HLO text."""
     texts = {}
-    for policy in ("mlp", "gnn"):
+    for policy in ("mlp", "gnn", "trunk"):
         trainer = _tiny_trainer(policy, tmp_path_factory.mktemp(policy))
         texts[policy] = trainer._iteration.lower(
             trainer.train_state, trainer.env_state, trainer.obs, trainer.key
@@ -94,7 +106,7 @@ def op_paths(compiled_text):
     return paths
 
 
-@pytest.mark.parametrize("policy", ["mlp", "gnn"])
+@pytest.mark.parametrize("policy", ["mlp", "gnn", "trunk"])
 @pytest.mark.parametrize("scope", DEVICE_SCOPES)
 def test_scope_is_an_exact_path_part_under_its_parent(op_paths, scope, policy):
     assert set(PARENTS) == set(DEVICE_SCOPES)
@@ -103,13 +115,18 @@ def test_scope_is_an_exact_path_part_under_its_parent(op_paths, scope, policy):
         if scope in parts:
             above = [p for p in parts[: parts.index(scope)] if p in DEVICE_SCOPES]
             found.add(above[-1] if above else None)
-    if scope in {"mlp": GNN_ONLY, "gnn": MLP_ONLY}[policy]:
+    absent = {
+        "mlp": GNN_ONLY + TRUNK_ONLY,
+        "gnn": MLP_ONLY + TRUNK_ONLY,
+        "trunk": MLP_ONLY + GNN_ONLY,
+    }
+    if scope in absent[policy]:
         assert not found
         return
     assert found == set(PARENTS[scope]), (scope, policy, found)
 
 
-@pytest.mark.parametrize("policy,gathers", [("mlp", 1), ("gnn", 5)])
+@pytest.mark.parametrize("policy,gathers", [("mlp", 1), ("gnn", 5), ("trunk", 5)])
 def test_a_minibatch_is_one_gather_where_rows_pack(compiled_text, policy, gathers):
     """Packed rows are looked up once a minibatch; a leaf at a time (five
     leaves) where they are not."""
@@ -210,7 +227,11 @@ SCOPE_READERS = {
     "env_step_ms": "env_step",
     "policy_forward_ms": "policy",
     "knn_ms": "knn_fused",
+    "knn_streaming_ms": "knn_streaming",
     "gnn_neighbor_ms": "neighbor_gather",
+    "trunk_attention_ms": "trunk_attention",
+    "trunk_indexer_ms": "trunk_indexer",
+    "trunk_moe_ms": "trunk_moe",
 }
 
 

@@ -145,6 +145,33 @@ def build_model(cfg, env_params, policy: str):
             log_std_init=cfg.log_std_init,
             **extra,
         )
+    if policy == "trunk":
+        if env_params.obs_mode != "knn":
+            raise SystemExit(
+                "policy=trunk reads the k-NN observation's layout: set "
+                "obs_mode=knn (and knn_k) in the config"
+            )
+        if not cfg.get("trunk"):
+            raise SystemExit(
+                "policy=trunk needs trunk=<name>, an architecture file "
+                "cfg/trunk/<name>.yaml (e.g. trunk=keye-vl2-a3b-ep8)"
+            )
+        from marl_distributedformation_tpu.models.trunk import (
+            TrunkActorCritic,
+            load_trunk_arch,
+        )
+
+        try:
+            arch = load_trunk_arch(str(cfg.trunk))
+        except ValueError as e:
+            raise SystemExit(str(e)) from e
+        return TrunkActorCritic(
+            arch=arch,
+            k=env_params.knn_k,
+            act_dim=env_params.act_dim,
+            goal_in_obs=env_params.goal_in_obs,
+            log_std_init=cfg.log_std_init,
+        )
     if policy == "mlp":
         if not hidden:
             return None
@@ -156,7 +183,8 @@ def build_model(cfg, env_params, policy: str):
             log_std_init=cfg.log_std_init,
         )
     raise SystemExit(
-        f"policy={policy!r} is not implemented; available: mlp, ctde, gnn"
+        f"policy={policy!r} is not implemented; available: mlp, ctde, gnn, "
+        "trunk"
     )
 
 
