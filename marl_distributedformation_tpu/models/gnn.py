@@ -8,8 +8,13 @@ per-agent MLP over a fixed ring view, vectorized_env.py:126; SURVEY.md §5
 - Nodes are agents; edges are each agent's ``k`` nearest neighbors, carried
   inside the observation produced by ``env.formation.compute_obs_knn``
   (offsets, distances, and neighbor indices — indices exact in float32).
-- ``rounds`` of message passing: gather neighbor embeddings with one
-  ``take_along_axis`` per round (a dense gather XLA lowers well), compute
+- ``rounds`` of message passing: fetch neighbor embeddings
+  (``gather_nodes``: where the node axis fits one MXU tile, N <= 128, an
+  exact product of a one-hot matrix with the node table, forward and
+  transposed, because the chip serves a ``take_along_axis`` of 64-float
+  rows an index at a time and its transpose as a sorted scatter-add;
+  larger swarms keep the ``take_along_axis``, whose cost grows as N where
+  the product's grows as N^2), compute
   edge messages from [h_i, h_j, edge_feats] with a shared MLP (batched
   matmuls on the MXU — no per-edge loop), mean-aggregate, GRU-free residual
   update. An agent's action therefore depends on its ``rounds``-hop
@@ -66,13 +71,57 @@ def parse_knn_obs(
     return jnp.concatenate(node_parts, axis=-1), edge, idx
 
 
-def gather_nodes(h: Array, idx: Array) -> Array:
-    """``h (..., N, E)``, ``idx (..., N, k)`` -> neighbor embeddings
-    ``(..., N, k, E)`` via one flat ``take_along_axis`` on the node axis."""
+# The widest node axis whose neighbor fetch is a one-hot product: one MXU
+# tile's contraction. The product's work grows as N^2 where the gather's
+# grows as N; alone on a v5e it still won at N=640 (PERF.md section 6, PR
+# 28), but no benchmark cell runs a swarm past 128 to place the crossing.
+ONEHOT_MAX_NODES = 128
+
+
+def neighbor_onehot(idx: Array) -> Optional[Array]:
+    """``idx (..., N, k)`` -> boolean ``(..., N*k, N)``, row ``i*k + j`` set
+    at column ``idx[..., i, j]``; None where ``N > ONEHOT_MAX_NODES``. A
+    comparison of integers: it has no tangent."""
     n, k = idx.shape[-2], idx.shape[-1]
-    flat = jnp.take_along_axis(
-        h, idx.reshape(*idx.shape[:-2], n * k, 1), axis=-2
-    )
+    if n > ONEHOT_MAX_NODES:
+        return None
+    flat = idx.reshape(*idx.shape[:-2], n * k, 1)
+    return flat == jnp.arange(n, dtype=idx.dtype)
+
+
+def gather_nodes(
+    h: Array, idx: Array, onehot: Optional[Array] = None
+) -> Array:
+    """``h (..., N, E)``, ``idx (..., N, k)`` -> neighbor embeddings
+    ``(..., N, k, E)``, the rows of ``h`` unrounded. The static shape picks
+    the path: where ``N <= ONEHOT_MAX_NODES`` a product of
+    ``neighbor_onehot(idx)`` (pass it where several calls share ``idx``)
+    with ``h``, exact for float32 and narrower, whose transpose is the
+    transposed product; past that one flat ``take_along_axis`` on the node
+    axis. Indices lie in ``[0, N)`` by construction (``compute_obs_knn``,
+    self-loops for padded agents). Outside it the paths differ: the
+    product gives a row of zeros, ``take_along_axis`` wraps a negative
+    index and fills NaN past ``N``; and a non-finite entry of ``h`` makes
+    its whole column of the product NaN (``0 * inf``).
+    """
+    n, k = idx.shape[-2], idx.shape[-1]
+    if onehot is None:
+        onehot = neighbor_onehot(idx)
+    if onehot is not None:
+        # HIGHEST: the backend multiplies float32 in bfloat16 parts, and a
+        # row of one 1.0 among zeros then hands h's parts back whole, where
+        # the default's single pass would round h (and, transposed, its
+        # cotangents) to 8 bits.
+        flat = jnp.einsum(
+            "...rn,...ne->...re",
+            onehot.astype(h.dtype),
+            h,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+    else:
+        flat = jnp.take_along_axis(
+            h, idx.reshape(*idx.shape[:-2], n * k, 1), axis=-2
+        )
     return flat.reshape(*idx.shape[:-2], n, k, h.shape[-1])
 
 
@@ -108,9 +157,11 @@ class GNNActorCritic(nn.Module):
                 node
             )
         )
+        with jax.named_scope("neighbor_gather"):
+            onehot = neighbor_onehot(idx)  # once: idx is every round's
         for r in range(self.rounds):
             with jax.named_scope("neighbor_gather"):
-                h_nb = gather_nodes(h, idx)  # (..., N, k, E)
+                h_nb = gather_nodes(h, idx, onehot)  # (..., N, k, E)
             h_self = jnp.broadcast_to(
                 h[..., :, None, :], h_nb.shape
             )
@@ -123,7 +174,7 @@ class GNNActorCritic(nn.Module):
             if mask is not None:
                 with jax.named_scope("neighbor_gather"):
                     nb_valid = gather_nodes(
-                        mask.astype(msg.dtype)[..., None], idx
+                        mask.astype(msg.dtype)[..., None], idx, onehot
                     )  # (..., N, k, 1)
                 msg = msg * nb_valid
                 agg = msg.sum(axis=-2) / jnp.maximum(

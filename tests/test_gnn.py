@@ -13,7 +13,12 @@ from marl_distributedformation_tpu.env.formation import (
     step_batch,
 )
 from marl_distributedformation_tpu.models import GNNActorCritic
-from marl_distributedformation_tpu.models.gnn import gather_nodes, parse_knn_obs
+from marl_distributedformation_tpu.models.gnn import (
+    ONEHOT_MAX_NODES,
+    gather_nodes,
+    neighbor_onehot,
+    parse_knn_obs,
+)
 from marl_distributedformation_tpu.ops import knn
 from marl_distributedformation_tpu.train import TrainConfig, Trainer
 
@@ -159,14 +164,119 @@ def test_gnn_mask_blocks_padded_neighbors():
     )
 
 
-def test_gather_nodes():
-    h = jnp.arange(12, dtype=jnp.float32).reshape(1, 4, 3)
-    idx = jnp.array([[[1, 2], [0, 3], [3, 0], [2, 1]]])
-    out = gather_nodes(h, idx)
-    assert out.shape == (1, 4, 2, 3)
-    np.testing.assert_array_equal(
-        np.asarray(out[0, 0]), np.asarray(h[0, jnp.array([1, 2])])
+def _take_along_axis_nodes(h, idx, xp=jnp):
+    """The gather ``gather_nodes`` was until PR 28, kept as the reference
+    (``xp=np``: on the host, nothing compiled)."""
+    n, k = idx.shape[-2], idx.shape[-1]
+    flat = xp.take_along_axis(
+        h, idx.reshape(*idx.shape[:-2], n * k, 1), axis=-2
     )
+    return flat.reshape(*idx.shape[:-2], n, k, h.shape[-1])
+
+
+def _wide_values(rng, shape):
+    """float32 with all 24 mantissa bits in play, both signs, magnitudes
+    from 1e-30 to 1e30."""
+    mant = 1.0 + (rng.integers(0, 2**23, shape) | 1) * 2.0**-23  # low bit set
+    sign = rng.choice([-1.0, 1.0], shape)
+    return (sign * mant * 2.0 ** rng.integers(-99, 100, shape)).astype(np.float32)
+
+
+def _graph(rng, lead, n, k):
+    """Random neighbours with repeats, every node's first slot a self-loop
+    and node 0 everybody's last."""
+    idx = rng.integers(0, n, (*lead, n, k)).astype(np.int32)
+    idx[..., 0] = np.arange(n)
+    idx[..., 1:, -1] = 0
+    return idx
+
+
+@pytest.mark.parametrize("e", [1, 64])
+@pytest.mark.parametrize("lead", [(), (3,), (2, 3)])
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("n", [5, 100, 128, 129, 640])
+def test_gather_nodes(n, k, lead, e):
+    """Both paths of ``gather_nodes`` (the one-hot product where N <= 128,
+    ``take_along_axis`` past it) against a plain ``take_along_axis``: the
+    forward pass bit for bit, the gradient against ``segment_sum`` to
+    float32 rounding (the product adds in another order), and nothing
+    with respect to ``idx``. Indices are in ``[0, N)`` by construction
+    (``compute_obs_knn``; self-loops for padded agents); outside it the
+    product gives zeros where ``take_along_axis`` wraps a negative index
+    and fills NaN past N, and neither is held to anything here."""
+    rng = np.random.default_rng(1000 * n + 10 * k + e)
+    h = _wide_values(rng, (*lead, n, e))
+    idx = _graph(rng, lead, n, k)
+    assert ONEHOT_MAX_NODES == 128
+    assert (neighbor_onehot(jnp.asarray(idx)) is None) == (n > 128)
+
+    out = jax.jit(gather_nodes)(h, idx)
+    ref = _take_along_axis_nodes(h, idx, xp=np)
+    assert out.shape == ref.shape and out.dtype == h.dtype
+    np.testing.assert_array_equal(np.asarray(out).view(np.int32), ref.view(np.int32))
+
+    # Cotangents of both signs, and of sizes a float32 sum holds together.
+    g = (rng.standard_normal(ref.shape) * 2.0 ** rng.integers(-6, 7, ref.shape))
+    g = g.astype(np.float32)
+    d_h, d_idx = jax.jit(
+        jax.grad(lambda x, i: jnp.sum(gather_nodes(x, i) * g), (0, 1), allow_int=True)
+    )(h, idx)
+    # idx is an integer: the only cotangent it can take is float0's nothing
+    assert d_idx.dtype == jax.dtypes.float0
+    formations = int(np.prod(lead, dtype=int))
+    segments = (idx.reshape(formations, n * k) + n * np.arange(formations)[:, None]).ravel()
+    seg, seg_abs = (
+        np.asarray(jax.ops.segment_sum(x.reshape(-1, e), segments, formations * n))
+        for x in (g, np.abs(g))
+    )
+    # to 1e-6 of what each sum added up
+    gap = np.abs(np.asarray(d_h).reshape(-1, e) - seg) / np.maximum(seg_abs, 1e-30)
+    assert gap.max() < 1e-6
+
+
+def test_gnn_is_its_old_self_at_100_agents(monkeypatch):
+    """gnn100's module (N=100, k=4) through the product against the same
+    module with the old ``take_along_axis`` monkeypatched in: the same
+    parameter tree, names and shapes (an older checkpoint loads), and
+    outputs equal bit for bit."""
+    from marl_distributedformation_tpu.models import gnn as gnn_module
+
+    n, k = 100, 4
+    params = EnvParams(num_agents=n, obs_mode="knn", knn_k=k)
+    state = reset_batch(jax.random.PRNGKey(5), params, 3)
+    obs = jax.vmap(compute_obs, in_axes=(0, 0, None))(
+        state.agents, state.goal, params
+    )
+    model = GNNActorCritic(k=k, rounds=2)
+
+    def init_and_apply():  # a function of its own each time: traced anew
+        variables = model.init(jax.random.PRNGKey(0), obs)
+        return variables, jax.jit(lambda v, o: model.apply(v, o))(variables, obs)
+
+    def shapes(variables):
+        return {
+            "/".join(part.key for part in path): leaf.shape
+            for path, leaf in jax.tree_util.tree_leaves_with_path(variables)
+        }
+
+    variables, new = init_and_apply()
+    old_calls = []
+
+    def old_gather(h, idx, onehot=None):
+        old_calls.append(h.shape)
+        return _take_along_axis_nodes(h, idx)
+
+    monkeypatch.setattr(gnn_module, "gather_nodes", old_gather)
+    old_variables, old = init_and_apply()
+    assert old_calls == [(3, n, 64)] * 4  # two rounds, init and apply
+
+    assert shapes(variables) == shapes(old_variables)
+    assert shapes(variables)["params/msg_0/kernel"] == (2 * 64 + 3, 64)
+    assert set(variables["params"]) == {
+        "embed", "msg_0", "upd_0", "msg_1", "upd_1", "actor", "critic", "log_std"}
+    for a, b in zip(jax.tree_util.tree_leaves((variables, new)),
+                    jax.tree_util.tree_leaves((old_variables, old))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.slow

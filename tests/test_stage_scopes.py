@@ -138,6 +138,78 @@ def test_a_minibatch_is_one_gather_where_rows_pack(compiled_text, policy, gather
     assert len(found) == gathers, found
 
 
+def _opcodes_under(text, scope):
+    """``/``-joined path above ``scope`` -> the opcodes of the instructions
+    whose ``op_name`` has ``scope`` as a path part."""
+    found = {}
+    for line in text.splitlines():
+        name = re.search(r'op_name="([^"]*)"', line)
+        opcode = re.search(r"= \S+ ([\w-]+)\(", line)
+        if name and opcode and scope in name.group(1).split("/"):
+            above = name.group(1).split("/" + scope + "/")[0]
+            found.setdefault(above, set()).add(opcode.group(1))
+    return found
+
+
+def test_neighbor_gather_is_a_product_in_rollout_and_update(compiled_text):
+    """The tiny GNN trainer has 8 agents, within one MXU tile as gnn100's
+    100 are: in the compiled iteration every ``neighbor_gather`` (the
+    rollout's steps, its bootstrap value, the update's forward pass and
+    its transpose) holds a ``dot`` and neither ``gather`` nor ``scatter``,
+    and the program has no scatter at all left to read as no stage."""
+    under = _opcodes_under(compiled_text["gnn"], "neighbor_gather")
+    stages = {
+        stage: [ops for above, ops in under.items() if stage in above.split("/")]
+        for stage in ("rollout", "loss_and_grad")
+    }
+    assert all(stages.values()), under
+    for ops in under.values():
+        assert "dot" in ops and not ops & {"gather", "scatter"}, under
+    assert any("transpose" in above for above in under), under
+    assert not re.search(r"= \S+ scatter\(", compiled_text["gnn"])
+
+
+@pytest.mark.parametrize("agents,product", [(100, True), (128, True), (129, False)])
+def test_neighbor_gather_picks_its_path_by_the_node_axis(agents, product):
+    """``GNNActorCritic.apply`` and its gradient alone, lowered at gnn100's
+    k=4 and compiled: with N <= 128 the four one-hot products (two rounds,
+    forward and transposed; the model's only batched ``dot``s) each
+    carry ``neighbor_gather`` in their ``op_name`` (the transposed ones
+    under ``transpose(...)`` too), and no ``gather`` or ``scatter`` is left
+    anywhere; past 128 the gather and its scatter-add stand as they were."""
+    model = GNNActorCritic(k=4, rounds=2)
+    obs = jax.ShapeDtypeStruct(
+        (2, agents, EnvParams(num_agents=agents, obs_mode="knn", knn_k=4).obs_dim),
+        jnp.float32,
+    )
+    variables = jax.eval_shape(model.init, jax.random.PRNGKey(0), obs)
+
+    def loss(variables, obs):
+        mean, _, value = model.apply(variables, obs)
+        return mean.sum() + value.sum()
+
+    text = jax.jit(jax.grad(loss)).lower(variables, obs).compile().as_text()
+    lines = text.splitlines()
+    under = _opcodes_under(text, "neighbor_gather")
+    forward = [ops for above, ops in under.items() if "transpose" not in above]
+    transposed = [ops for above, ops in under.items() if "transpose" in above]
+    assert forward and transposed, under
+    if not product:
+        assert all("gather" in ops and "dot" not in ops for ops in forward), under
+        assert all("scatter" in ops and "dot" not in ops for ops in transposed), under
+        return
+    assert not [line for line in lines if re.search(r"= \S+ (gather|scatter)\(", line)]
+    # the only batched products of the model: a node table a formation
+    products = [
+        line for line in lines
+        if re.search(r"= \S+ dot\(", line) and "lhs_batch_dims={0}" in line
+    ]
+    assert len(products) == 4, products
+    names = [re.search(r'op_name="([^"]*)"', line).group(1) for line in products]
+    assert all("/neighbor_gather/" in name for name in names), names
+    assert sum("transpose" in name for name in names) == 2, names
+
+
 def test_no_name_is_a_primitive_or_helper_of_jax():
     names = DEVICE_SCOPES + KERNEL_NAMES + HOST_SPANS
     assert len(set(names)) == len(names)
