@@ -53,8 +53,7 @@ def main() -> None:
         jax.vmap(control, in_axes=(0, 0, 0, None)), static_argnums=3
     )
 
-    # Warm up: the first call compiles (the repo bench convention,
-    # bench.py); time steady-state execution only.
+    # Warm up: the first call compiles; time steady-state execution only.
     vel = vctrl(state.agents, state.goal, state.obstacles, params)
     warm_state, _ = step_fn(state, vel / params.max_speed)
     jax.block_until_ready(warm_state.agents)

@@ -30,8 +30,8 @@ _DONATE_KWARGS = frozenset({"donate_argnums", "donate_argnames"})
 
 def _callable_name(node: ast.AST) -> Optional[str]:
     """Last-segment name of the jitted target, peeling wrapping calls
-    (``jax.jit(_burst(iteration, r))`` -> ``_burst`` peels to its first
-    arg ``iteration``)."""
+    (``jax.jit(make_fused_chunk(iteration, k))`` -> ``make_fused_chunk``
+    peels to its first arg ``iteration``)."""
     if isinstance(node, ast.Name):
         return node.id
     if isinstance(node, ast.Attribute):

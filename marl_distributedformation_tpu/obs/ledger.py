@@ -44,9 +44,8 @@ Design constraints, in order — the Tracer/MetricsRegistry discipline:
 Read sides: :meth:`ProgramLedger.snapshot` (flat ``{name: float}``,
 merged into the one Prometheus namespace as ``program{...}``-labeled
 families by ``obs/export.py``), :meth:`ProgramLedger.census` (the
-structured record ``scripts/program_report.py`` renders and
-``scripts/check_bench_record.py --census`` diffs against a committed
-copy), and the RegressionSentinel's ``ledger_watches`` over the
+structured record ``scripts/program_report.py`` renders), and the
+RegressionSentinel's ``ledger_watches`` over the
 aggregate gauges.
 """
 
@@ -60,8 +59,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from marl_distributedformation_tpu.obs.metrics import MetricsRegistry
 
-# Census file schema (scripts/program_report.py and
-# check_bench_record.py --census parse this).
+# Census file schema (scripts/program_report.py parses this).
 CENSUS_SCHEMA = 1
 
 # The cost/memory fact fields a record may carry. Order matters: it is
@@ -363,9 +361,7 @@ class ProgramLedger:
 
     def census(self) -> Dict[str, Any]:
         """The structured program census: every entry's full record plus
-        the dispatch-latency summaries and the ledger totals — the
-        artifact a chip window commits beside BENCH (see
-        ``check_bench_record.py --census``)."""
+        the dispatch-latency summaries and the ledger totals."""
         entries = self.entries()
         hists = self._metrics.snapshot()
         programs = []

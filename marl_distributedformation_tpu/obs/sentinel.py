@@ -42,7 +42,7 @@ from marl_distributedformation_tpu.obs.metrics import (
 )
 from marl_distributedformation_tpu.obs.tracer import Tracer, get_tracer
 
-# bench.py's explicit not-run marker (check_bench_record.py shares it).
+# The bench record's explicit not-run marker.
 SKIPPED = "skipped"
 
 _BENCH_RE = re.compile(r"^BENCH_r(\d+)\.json$")

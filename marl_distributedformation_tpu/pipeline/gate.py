@@ -552,7 +552,7 @@ class PromotionGate:
     def eval_steps_per_sec(self) -> float:
         """Gate throughput in formation-env-steps evaluated per second
         (cells x formations x episode length over cumulative eval
-        wall-clock) — the bench's ``gate_eval_steps_per_sec``."""
+        wall-clock)."""
         if self.eval_seconds_total <= 0:
             return 0.0
         steps = (

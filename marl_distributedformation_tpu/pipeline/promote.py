@@ -28,8 +28,8 @@ from typing import Dict, List
 
 from marl_distributedformation_tpu.utils.checkpoint import checkpoint_step
 
-# Bump when the line shape changes; scripts/check_bench_record.py and the
-# schema unit test pin the current shape.
+# Bump when the line shape changes; the schema unit test pins the
+# current shape.
 #
 # Schema history:
 #   1 — PR 7: event/time/step/checkpoint + gate verdict payload.

@@ -725,8 +725,8 @@ class AlwaysLearningPipeline:
             idx = min(len(latencies) - 1, int(q * len(latencies)))
             return round(latencies[idx], 4)
 
-        # Per-stage p50s over every traced promotion — the bench's
-        # promotion_span_breakdown (where did the promotion seconds go).
+        # Per-stage p50s over every traced promotion: where did the
+        # promotion seconds go.
         by_stage: Dict[str, List[float]] = {}
         for r in self.promotions:
             for stage, seconds in (r.spans or {}).items():

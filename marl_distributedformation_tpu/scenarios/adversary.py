@@ -200,7 +200,7 @@ class AdversarySearch:
     contract): construction jits nothing, the single compile happens on
     the first generation, and every later generation — for THIS
     checkpoint or any later same-architecture one — reuses it.
-    ``guard.count`` is the receipt the gate and the bench record.
+    ``guard.count`` is the receipt the gate records.
     """
 
     def __init__(
@@ -293,7 +293,7 @@ class AdversarySearch:
         origin: str = "<candidate>",
     ) -> List[float]:
         """The config metric at explicit ``(scenario, severity)`` cells —
-        through the SAME compiled program (the bench's worst-case
+        through the SAME compiled program (the worst-case
         comparison hook). ``len(cells)`` must fit the population."""
         self.check_params(params, origin)
         if len(cells) > self.population:
@@ -444,7 +444,7 @@ class AdversarySearch:
 
     def candidates_per_sec(self) -> float:
         """Search throughput in scenario candidates evaluated per second
-        (the bench's ``adversarial_candidates_per_sec``)."""
+        (``scripts/adversarial_search.py`` reports it)."""
         if self.search_seconds_total <= 0:
             return 0.0
         return self.candidates_evaluated / self.search_seconds_total

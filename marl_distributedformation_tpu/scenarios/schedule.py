@@ -6,7 +6,7 @@ each naming the scenario subset to randomize over and a severity ramp.
 Unlike the hetero curriculum — whose stage boundaries rebuild env state —
 a scenario stage transition is pure data (a new probs vector + severity
 scalar into the SAME compiled program), so schedules never recompile and
-compose with ``iters_per_dispatch`` bursts.
+compose with ``fused_chunk`` scans.
 
 Config forms accepted by ``schedule_from_cfg`` (cfg key ``scenarios``):
 

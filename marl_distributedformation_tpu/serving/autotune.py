@@ -26,8 +26,8 @@ distribution and arrival rate of a :class:`~.loadgen.RequestTrace`
   threshold falls out of the same plan.
 
 The DP is pure — one trace in, one plan out — so the SAME plan shape
-serves two callers: offline (the bench harness builds a fleet from a
-plan before traffic) and live (serving/elastic replays the recent
+serves two callers: offline (``serve_policy.py --slo-bench`` builds a
+fleet from a plan before traffic) and live (serving/elastic replays the recent
 recorded window through :func:`replay_recorder` and lands the new plan
 at the fleet batch barrier after prewarming every rung off the serving
 path). A rung change still means new compiles — the elastic controller

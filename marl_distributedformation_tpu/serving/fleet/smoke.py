@@ -9,7 +9,7 @@ and — because every client records ``(completion order, model_step)``
 into one shared log — the global step-monotonicity contract of the
 coordinated hot swap.
 
-The report is bench.py's one-JSON-line shape:
+The report is one flat dict, printed as one JSON line:
 
 - ``requests_per_sec_fleet`` / merged latency percentiles — the fleet
   throughput headline.

@@ -18,7 +18,7 @@ This module provides:
   (:func:`synthetic_trace`) or record/replay real traffic as JSONL
   (:func:`save_trace` / :func:`load_trace`). Traces are deterministic
   given a seed — the ladder autotuner (autotune.py) consumes the same
-  trace the bench drives, so its decisions are reproducible.
+  trace the load replay drives, so its decisions are reproducible.
 - :class:`TraceRecorder` — a bounded ring the schedulers record LIVE
   arrivals into; its window replays through the same autotuner DP
   (serving/elastic) and dumps as the same JSONL
@@ -30,8 +30,8 @@ This module provides:
   exists to see).
 - :func:`max_rate_at_slo` — bisection over offered rate: the highest
   rate whose replay holds ``p95 <= target`` with at most ``max_loss``
-  of requests rejected/timed out. This is bench phase 9's
-  ``serving_req_per_sec_at_p95_slo``.
+  of requests rejected/timed out (``serve_policy.py --slo-bench``'s
+  ``serving_req_per_sec_at_p95_slo``).
 """
 
 from __future__ import annotations

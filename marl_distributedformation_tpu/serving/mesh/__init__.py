@@ -26,7 +26,7 @@ CONTROL plane — not the data plane — doing the cross-host work.
   MetaRouter in-process, hosts as REAL subprocesses (``kill -9`` is a
   real host death). Testable without multi-process jax collectives,
   which this container's jaxlib refuses.
-- :func:`~.smoke.run_mesh_smoke` — bench phase 14's harness: mesh
+- :func:`~.smoke.run_mesh_smoke` — the acceptance storm: mesh
   req/s, global-swap latency, kill-one-host failover accounting, and
   per-host budget-1 compile receipts.
 

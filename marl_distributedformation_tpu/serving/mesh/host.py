@@ -6,7 +6,7 @@ full per-host serving stack — ``FleetRouter`` over the local devices,
 from a promoted-checkpoint directory, registers with the coordinator,
 and serves until killed. This is the unit the loopback mesh
 (``serving/mesh/loopback.py``), the chaos storm's ``--mesh`` campaign,
-and bench phase 14 spawn as real OS processes: ``kill -9`` of one of
+and the mesh smoke spawn as real OS processes: ``kill -9`` of one of
 these is a REAL host death, not a ``SimulatedCrash``.
 
 The process prints exactly ONE JSON line on stdout when ready::

@@ -4,7 +4,7 @@ The container's jaxlib refuses multi-process collectives, but the mesh
 tier never needed them — the control plane coordinates over RPC and
 the data plane over HTTP, both of which loopback exercises for real.
 :func:`spawn_local_mesh` boots the whole topology the tests, the chaos
-storm's ``--mesh`` campaign, and bench phase 14 share:
+storm's ``--mesh`` campaign, and the mesh smoke share:
 
 - a :class:`~.coordinator.MeshCoordinator` RPC service in THIS process,
 - N host SUBPROCESSES (``serving/mesh/host.py`` — each its own

@@ -1,6 +1,6 @@
-"""Mesh smoke: the loopback 2-host acceptance storm (bench phase 14).
+"""Mesh smoke: the loopback 2-host acceptance storm.
 
-One call measures the four headline numbers the bench record commits:
+One call measures four headline numbers:
 
 - ``mesh_req_per_sec`` — client threads hammering the MetaRouter over
   both hosts for ``duration_s``;
@@ -125,7 +125,7 @@ def run_mesh_smoke(
     per_iter: int = 60,
     ready_timeout_s: float = 120.0,
 ) -> Dict[str, Any]:
-    """The whole acceptance storm; returns the bench-field dict."""
+    """The whole acceptance storm; returns the flat field dict."""
     from marl_distributedformation_tpu.env import EnvParams
     from marl_distributedformation_tpu.serving.scheduler import (
         BackpressureError,

@@ -1,6 +1,5 @@
 """Smoke benchmark: drive a live scheduler with a mixed-size request
-stream and report the serving numbers that matter (bench.py's
-one-JSON-line contract, applied to inference).
+stream and report the serving numbers that matter (one JSON line).
 
 Used by ``scripts/serve_policy.py --smoke`` and the tier-1 serving test:
 a handful of client threads submit observation batches whose sizes span

@@ -171,19 +171,18 @@ class HeteroTrainer:
         )
         self.ppo = ppo
         self.config = config
-        if int(config.iters_per_dispatch) > 1 or int(config.fused_chunk) > 0:
+        if int(config.fused_chunk) > 0:
             # Stage boundaries are host-driven (count resampling + env
             # reset between stages); fusing iterations across them would
             # silently blur the curriculum, and fusing within a stage
-            # would need stage-length-aware burst sizing. Reject loudly
-            # instead of silently running at cadence 1. fused_chunk
-            # (Anakin mode) fuses even harder and fails for the same
-            # reason — unlike scenario schedules, curriculum stage data
-            # is not a traced input to one compiled program.
+            # would need stage-length-aware chunk sizing. Reject loudly
+            # instead of silently running at cadence 1 — unlike scenario
+            # schedules, curriculum stage data is not a traced input to
+            # one compiled program.
             raise SystemExit(
-                "iters_per_dispatch > 1 / fused_chunk do not compose with "
-                "curriculum training (stage boundaries are host-driven); "
-                "unset them or drop the curriculum"
+                "fused_chunk does not compose with curriculum training "
+                "(stage boundaries are host-driven); unset it or drop "
+                "the curriculum"
             )
 
         self.model = model or MLPActorCritic(

@@ -34,8 +34,8 @@ Composition of two existing shells, not new machinery:
 
 Deliberate scope (documented restrictions, enforced loudly):
 single-controller only (the config-5 acceptance runs on one chip; use
-``SweepTrainer`` for multi-host populations), no per-member learning
-rates, and no ``iters_per_dispatch`` (retired for sweeps).
+``SweepTrainer`` for multi-host populations) and no per-member learning
+rates.
 ``fused_chunk=K`` (round 6) DOES compose: within a stage, K vmapped
 iterations fuse into one ``lax.scan`` dispatch — chunks clip at the
 host-driven stage boundaries (a stage tail shorter than K compiles its
@@ -137,13 +137,6 @@ class HeteroSweepTrainer:
                 "candidate workflow runs on one chip. Multi-host "
                 "populations are SweepTrainer's domain (drop the "
                 "curriculum), or run one process."
-            )
-        if int(config.iters_per_dispatch) > 1:
-            raise SystemExit(
-                "iters_per_dispatch is retired for population sweeps — "
-                "set fused_chunk=K instead (chunks clip at curriculum "
-                "stage boundaries, so staged training now composes with "
-                "scan fusion)"
             )
         self._fused_chunk = max(0, int(config.fused_chunk))
         self.curriculum = curriculum

@@ -323,12 +323,6 @@ class SebulbaDriver(Trainer):
                 "architecture=anakin. The in-program health word itself "
                 "rides the sebulba learner fine: health=true"
             )
-        if config.iters_per_dispatch > 1:
-            raise SystemExit(
-                "iters_per_dispatch is the Anakin host-loop burst "
-                "spelling; sebulba fuses at the learner — set fused_chunk "
-                "to K, the batches drained per update chunk"
-            )
         super().__init__(
             env_params,
             ppo=ppo,

@@ -52,8 +52,7 @@ per chunk, double-buffered against the next chunk's execution;
 population checkpoints (every member file + the sweep_state anchor)
 write on a background thread off a device-side snapshot, at chunk
 boundaries — chunk boundary == checkpoint boundary == bit-exact resume
-boundary (pinned by ``tests/test_fused_sweep.py``). The old
-``iters_per_dispatch`` reduced-metrics burst is retired for sweeps.
+boundary (pinned by ``tests/test_fused_sweep.py``).
 """
 
 from __future__ import annotations
@@ -130,18 +129,6 @@ class SweepTrainer:
     ) -> None:
         assert num_seeds >= 1
         self._fused_chunk = max(0, int(config.fused_chunk))
-        if int(config.iters_per_dispatch) > 1:
-            # The reduced-metrics burst cadence is RETIRED for sweeps:
-            # fused_chunk subsumes it (same scan fusion, but metrics come
-            # back stacked per iteration and checkpoints go async) and
-            # measured >= it at every chunk size. Reject loudly rather
-            # than silently training at cadence 1.
-            raise SystemExit(
-                "iters_per_dispatch is retired for population sweeps — "
-                "set fused_chunk=K instead (the Anakin mode: K vmapped "
-                "iterations per lax.scan dispatch, per-member metrics "
-                "stacked per iteration, async population checkpoints)"
-            )
         self._multihost = jax.process_count() > 1
         if self._fused_chunk and self._multihost:
             raise SystemExit(
@@ -351,8 +338,8 @@ class SweepTrainer:
             # through the scan untouched, so per-member per-iteration
             # metrics come back stacked (fused_chunk, members, ...).
             iteration_pop = make_fused_chunk(iteration_pop, self._fused_chunk)
-        # Compile-once receipt for the population program (bench records
-        # it; guard_retraces=1 enforces it).
+        # Compile-once receipt for the population program
+        # (guard_retraces=1 enforces it).
         self.retrace_guard = profiling.RetraceGuard(
             "sweep_iteration", max_traces=config.guard_retraces or None
         )

@@ -75,18 +75,13 @@ class TrainConfig:
     #   SummaryWriter into {log_dir}/tensorboard/
     resume: bool = False
     log_interval: int = 1  # emit metrics every k rollouts
-    iters_per_dispatch: int = 1  # rollout+update iterations fused into ONE
-    #   jitted program via lax.scan — one host dispatch
-    #   advances R iterations. Metrics/logging/checkpoint cadence quantize
-    #   to R; metrics are the mean over the burst (dones: sum).
     fused_chunk: int = 0  # Anakin mode (docs/training.md): >0 compiles K
     #   rollout+update iterations into ONE lax.scan program with the full
     #   training state as the donated carry. Per-iteration metrics come
     #   back STACKED (one batched device_get per chunk, double-buffered
     #   against the next chunk's execution) and checkpoints are written by
     #   a background thread off a device-side snapshot. Chunk boundary =
-    #   checkpoint boundary; logging stays per-iteration. Mutually
-    #   exclusive with iters_per_dispatch (the host-loop burst spelling).
+    #   checkpoint boundary; logging stays per-iteration.
     profile: bool = False  # capture a jax.profiler trace of a few
     #   post-warmup dispatches into {log_dir}/profile/ (profile=true CLI).
     #   Composes with fused_chunk: the capture window is DISPATCH-grained
@@ -278,7 +273,7 @@ def make_ppo_iteration(
     return iteration
 
 
-def make_fused_chunk(iteration, k: int, reduce_metrics: bool = False):
+def make_fused_chunk(iteration, k: int):
     """Fuse ``k`` rollout+update iterations into ONE ``lax.scan`` device
     program — the Podracer "Anakin" dispatch shape (PAPERS.md): the carry
     is the full training state ``(train_state, env_state, obs, key)``
@@ -291,14 +286,6 @@ def make_fused_chunk(iteration, k: int, reduce_metrics: bool = False):
     ``(k,)`` axis — every fused iteration trains at its own schedule
     point, exactly like ``k`` host-loop dispatches (bitwise; pinned by
     tests/test_fused_scan.py).
-
-    ``reduce_metrics=True`` keeps the legacy burst contract
-    (``TrainConfig.iters_per_dispatch``: mean over the chunk,
-    ``episode_dones`` sums) for the single-run ``Trainer``'s host-loop
-    burst spelling — its ONLY remaining consumer now that both population
-    sweeps dispatch through the stacked-metrics fused path. This replaces
-    the former ``_burst`` helper — one scan builder serves both cadences,
-    so the two can never drift.
     """
 
     def fused_chunk_iteration(train_state, env_state, obs, key, *scenario_seq):
@@ -314,21 +301,6 @@ def make_fused_chunk(iteration, k: int, reduce_metrics: bool = False):
         (train_state, env_state, obs, key), stacked = jax.lax.scan(
             body, (train_state, env_state, obs, key), xs, length=k
         )
-        if reduce_metrics:
-            # episode_dones sums; the health flags reduce by MIN (the
-            # burst is healthy only if every fused iteration was — a
-            # mean would dilute a single skip below detection); the
-            # rest mean, the legacy burst contract.
-            stacked = {
-                name: (
-                    v.sum(axis=0)
-                    if name == "episode_dones"
-                    else v.min(axis=0)
-                    if name.startswith("health_")
-                    else v.mean(axis=0)
-                )
-                for name, v in stacked.items()
-            }
         return train_state, env_state, obs, key, stacked
 
     return fused_chunk_iteration
@@ -538,8 +510,8 @@ class Trainer:
         self._iteration_core = self._make_iteration()
         # Self-healing train lane (train/recovery.py, docs/recovery.md):
         # the in-program health word + skip-update guard wrap the
-        # functional core BEFORE fusion, so host-loop, burst, and fused
-        # dispatch all carry the same flags in their metrics.
+        # functional core BEFORE fusion, so host-loop and fused
+        # dispatch carry the same flags in their metrics.
         if config.health:
             from marl_distributedformation_tpu.train.recovery import (
                 wrap_health,
@@ -580,16 +552,7 @@ class Trainer:
                 ),
                 config.log_dir or str(repo_root() / "logs" / config.name),
             )
-        self._iters_per_dispatch = max(1, int(config.iters_per_dispatch))
         self._fused_chunk = max(0, int(config.fused_chunk))
-        if self._fused_chunk and self._iters_per_dispatch > 1:
-            raise SystemExit(
-                "fused_chunk and iters_per_dispatch are two spellings of "
-                "dispatch fusion — set exactly one (fused_chunk is the "
-                "Anakin mode: stacked per-iteration metrics, double-"
-                "buffered drain, background checkpoints; "
-                "iters_per_dispatch is the host-loop burst)"
-            )
         if self._fused_chunk and self._multihost:
             raise SystemExit(
                 "fused-scan training is single-host for now (the async "
@@ -599,12 +562,6 @@ class Trainer:
         if self._fused_chunk:
             dispatch_fn = make_fused_chunk(
                 self._iteration_core, self._fused_chunk
-            )
-        elif self._iters_per_dispatch > 1:
-            dispatch_fn = make_fused_chunk(
-                self._iteration_core,
-                self._iters_per_dispatch,
-                reduce_metrics=True,
             )
         else:
             dispatch_fn = self._iteration_core
@@ -862,12 +819,12 @@ class Trainer:
                 stack.enter_context(profiling.nan_guard())
             if self.scenario_params is None:
                 extra = ()
-            elif self._fused_chunk or rollouts > 1:
+            elif self._fused_chunk:
                 # Chunked dispatch (any fused_chunk — a K=1 scan still
-                # takes xs with a leading (1,) axis — or a legacy burst):
-                # each scanned iteration gets the params the host loop
-                # would draw at its rollout index, resampled per
-                # iteration — not one batch frozen across the chunk.
+                # takes xs with a leading (1,) axis): each scanned
+                # iteration gets the params the host loop would draw at
+                # its rollout index, resampled per iteration — not one
+                # batch frozen across the chunk.
                 extra = (self._next_scenario_chunk(rollouts),)
             else:
                 extra = (self.scenario_params,)
@@ -900,8 +857,8 @@ class Trainer:
         if self._scenario_schedule is not None:
             self._scenario_rollouts += rollouts
             self._scenario_draws += rollouts
-            if not self._fused_chunk and rollouts == 1:
-                # Chunked modes draw their params from
+            if not self._fused_chunk:
+                # Fused mode draws its params from
                 # _next_scenario_chunk at dispatch time — resampling the
                 # single-dispatch batch here would be one wasted device
                 # program per chunk on the hot path.
@@ -909,14 +866,13 @@ class Trainer:
         return metrics
 
     def run_iteration(self) -> Dict[str, float]:
-        """One host-loop dispatch — ``iters_per_dispatch`` rollout+update
-        cycles (1 by default); returns device metrics (burst-averaged
-        when fused)."""
+        """One host-loop dispatch — one rollout+update iteration;
+        returns device metrics."""
         assert not self._fused_chunk, (
             "fused_chunk trainers dispatch via run_chunk() (stacked "
             "per-iteration metrics), not run_iteration()"
         )
-        return self._dispatch(self._iters_per_dispatch)
+        return self._dispatch(1)
 
     def run_chunk(self) -> Dict[str, Array]:
         """Anakin mode: dispatch ONE fused-scan chunk (``fused_chunk``
@@ -956,11 +912,7 @@ class Trainer:
                 metrics = self.run_iteration()
                 iteration += 1
                 tracer.after_dispatch(metrics)
-                meter.tick(
-                    self._iters_per_dispatch
-                    * self.ppo.n_steps
-                    * self.config.num_formations
-                )
+                meter.tick(self.ppo.n_steps * self.config.num_formations)
                 # Live gauges every dispatch (three dict writes), not
                 # just at log cadence — GET /metrics must answer "how
                 # fast right now" even when log_interval is long.
@@ -990,11 +942,7 @@ class Trainer:
                         # actually trained at.
                         last_record["scenario_severity"] = float(
                             self._scenario_schedule.severity_at(
-                                max(
-                                    self._scenario_rollouts
-                                    - self._iters_per_dispatch,
-                                    0,
-                                )
+                                max(self._scenario_rollouts - 1, 0)
                             )
                         )
                     logger.log(last_record, self.num_timesteps)

@@ -18,7 +18,7 @@ through to ``train.build_trainer``):
     python scripts/always_learning.py name=always num_formation=64 \\
         total_timesteps=64000 max_steps=100 pipeline_replicas=2
 
-    # what bench.py phase 7 measures (forced 2-device CPU, tiny run):
+    # a tiny run on a forced 2-device CPU:
     JAX_PLATFORMS=cpu python scripts/always_learning.py name=bench_pipeline \\
         num_formation=16 total_timesteps=4800 max_steps=60 \\
         gate_formations=32 pipeline_replicas=2
@@ -128,8 +128,8 @@ PIPELINE_KEYS = (
 TRAIN_EXTRA_KEYS = (
     "save_freq", "policy", "hidden_sizes", "mesh", "num_seeds",
     "curriculum", "learning_rates", "platform", "preset", "fused_chunk",
-    "iters_per_dispatch", "guard_retraces", "guard_transfers",
-    "guard_nans", "profile", "profile_iterations",
+    "guard_retraces", "guard_transfers", "guard_nans", "profile",
+    "profile_iterations",
     # sebulba lane (train/sebulba/, docs/sebulba.md): the split
     # acting/learning architecture; the gate then runs on its OWN
     # device slice instead of time-sharing the trainer's.

@@ -24,8 +24,7 @@ Result: formation-steps/s for the full loop =
     (n_steps * M) / (n_steps * (t_env_vecstep + t_infer) + t_update)
 
 Run: python scripts/estimate_reference_train.py
-The output feeds bench.py's REFERENCE_TRAIN_FORMATION_STEPS_PER_SEC and
-docs/reference_train_estimate.md.
+The output feeds docs/reference_train_estimate.md.
 """
 
 from __future__ import annotations

@@ -12,9 +12,6 @@ build timings, dispatch-latency summaries); entry points dump it to
 questions directly: which programs dominate flops, bytes, compile wall,
 and dispatch tail latency — text tables by default, one JSON object
 with ``--json`` (stable keys: ``totals``, ``top``, ``programs``).
-
-``scripts/check_bench_record.py --census`` is the companion GATE (diff
-a committed census against a live one); this script is the human view.
 """
 
 from __future__ import annotations

@@ -124,7 +124,7 @@ def _build_init_policy(args):
 
 
 def _run_slo_bench(args) -> int:
-    """bench.py phase 9: the SLO-driven serving bench, one JSON line.
+    """The SLO-driven serving bench (--slo-bench), one JSON line.
 
     Three fleets on the same forced multi-device CPU (or real mesh),
     driven by the SAME open-loop request trace (serving/loadgen.py):
@@ -372,7 +372,7 @@ def _run_slo_bench(args) -> int:
 
 
 def _run_elastic_bench(args) -> int:
-    """The --elastic-bench comparison (bench.py phase "elastic"): a
+    """The --elastic-bench comparison: a
     shifting-mix day — interactive-heavy first half, big-rung storm
     second half — against two fleets on the same forced multi-device
     CPU mesh:
@@ -988,15 +988,15 @@ def main(argv=None) -> int:
     parser.add_argument(
         "--elastic-bench",
         action="store_true",
-        help="run the elastic-vs-static capacity bench (bench.py phase "
-        "'elastic'): a shifting-mix trace against a frozen "
+        help="run the elastic-vs-static capacity bench: "
+        "a shifting-mix trace against a frozen "
         "first-half-tuned fleet and a CapacityController-managed one, "
         "both measured on the storm half; one JSON line",
     )
     parser.add_argument(
         "--slo-bench",
         action="store_true",
-        help="run the SLO-driven serving bench (bench.py phase 9): "
+        help="run the SLO-driven serving bench: "
         "replicated vs sharded vs bf16 under the same open-loop load "
         "trace, then bisect for req/s at the p95 target; one JSON line",
     )
@@ -1037,8 +1037,7 @@ def main(argv=None) -> int:
         choices=("on", "off"),
         default="on",
         help="obs/ tracing spine: batch spans + trace-ID propagation "
-        "(default on; bench phase 8 runs the smoke both ways to measure "
-        "the overhead)",
+        "(default on)",
     )
     args = parser.parse_args(argv)
 

@@ -137,7 +137,8 @@ def test_fused_program_compiles_exactly_once_across_chunks_and_stages(
 ):
     """Three chunks crossing a scenario stage change + severity ramp =
     ONE compile of the fused program (guard_retraces=1 would raise on
-    the retrace; the count is the receipt bench.py records)."""
+    the retrace; the count is the receipt the benchmark's
+    ``compiles_in_window`` reads)."""
     trainer = make_trainer(
         tmp_path, scenario=two_stage_schedule(), fused_chunk=2,
         guard_retraces=1,
@@ -318,8 +319,6 @@ def test_fused_chunk_fail_fasts(tmp_path):
     test_profile_composes_with_fused_trainer below)."""
     from marl_distributedformation_tpu.train import HeteroTrainer
 
-    with pytest.raises(SystemExit, match="exactly one"):
-        make_trainer(tmp_path, fused_chunk=2, iters_per_dispatch=2)
     with pytest.raises(SystemExit, match="fused_chunk"):
         # The single-run curriculum trainer keeps its host-driven stage
         # loop (the POPULATION curriculum shell is the one that fuses).

@@ -132,8 +132,7 @@ def test_fused_lr_sweep_bitwise_matches_host_loop(tmp_path):
 
 def test_fused_sweep_compiles_exactly_once_across_chunks(tmp_path):
     """Three chunks = ONE compile of the fused population program
-    (guard_retraces=1 would raise on a retrace; the count is the receipt
-    bench.py records per rung)."""
+    (guard_retraces=1 would raise on a retrace)."""
     fused = make_sweep(tmp_path, fused_chunk=2, guard_retraces=1)
     for _ in range(3):
         fused.run_chunk()
@@ -329,15 +328,3 @@ def test_profile_composes_with_fused_sweep(tmp_path):
     assert sweep.retrace_guard.count == 1, (
         "tracing must not retrace the fused program"
     )
-
-
-# ---------------------------------------------------------------------------
-# The burst cadence is retired for sweeps; fail-fasts stay loud
-# ---------------------------------------------------------------------------
-
-
-def test_sweep_burst_cadence_retired(tmp_path):
-    with pytest.raises(SystemExit, match="fused_chunk"):
-        make_sweep(tmp_path, iters_per_dispatch=2)
-    with pytest.raises(SystemExit, match="fused_chunk"):
-        make_hetero(tmp_path, iters_per_dispatch=2)

@@ -245,12 +245,6 @@ def test_resume_rejects_identity_mismatch(tmp_path):
 
 
 def test_rejections(tmp_path):
-    with pytest.raises(SystemExit, match="iters_per_dispatch"):
-        HeteroSweepTrainer(
-            curriculum=CURR,
-            config=_cfg(tmp_path, iters_per_dispatch=2),
-            num_seeds=2,
-        )
     with pytest.raises(AssertionError, match="divisible"):
         HeteroSweepTrainer(
             curriculum=CURR,

@@ -497,66 +497,6 @@ def test_census_write_load_and_report_round_trip(
         load_census(bad)
 
 
-def test_census_diff_gate(private_ledger, tmp_path):
-    _census_with(private_ledger)
-    committed = private_ledger.census()
-    sys.path.insert(0, str(REPO / "scripts"))
-    try:
-        from check_bench_record import census_diff
-    finally:
-        sys.path.pop(0)
-    # Identical census: clean.
-    assert census_diff(committed, committed) == []
-    # Drifted flops past tolerance: named rejection.
-    live = json.loads(json.dumps(committed))
-    live["programs"][0]["flops"] = 2e9
-    problems = census_diff(committed, live, tolerance=0.25)
-    assert len(problems) == 1 and "flops drifted 100%" in problems[0]
-    assert census_diff(committed, live, tolerance=1.5) == []
-    # A vanished and a new program are both rejections.
-    live = json.loads(json.dumps(committed))
-    live["programs"][1]["dispatch_key"] = "serve_other"
-    live["programs"][1]["key"] = "serve_other"
-    problems = census_diff(committed, live)
-    assert any("vanished" in p and "serve_small" in p for p in problems)
-    assert any("new program" in p and "serve_other" in p for p in problems)
-    # A replica's entry disappearing under a shared dispatch key is a
-    # count change, not a vanished key — still a rejection.
-    live = json.loads(json.dumps(committed))
-    live["programs"].append(dict(live["programs"][0]))
-    problems = census_diff(committed, live)
-    assert any(
-        "count changed (1 committed -> 2 live)" in p for p in problems
-    )
-
-
-def test_ledger_bench_validator(private_ledger):
-    sys.path.insert(0, str(REPO / "scripts"))
-    try:
-        from check_bench_record import check
-    finally:
-        sys.path.pop(0)
-    base = {"platform": "tpu"}
-    ok = {
-        **base,
-        "ledger_overhead_pct": 1.2,
-        "ledger_program_count": 9,
-        "ledger_compile_seconds_total": 31.5,
-    }
-    assert check(ok, [], []) == []
-    assert check({**ok, "ledger_overhead_pct": 7.0}, [], [])
-    assert check({**ok, "ledger_overhead_pct": float("nan")}, [], [])
-    assert check({**ok, "ledger_program_count": 0}, [], [])
-    assert check({**ok, "ledger_compile_seconds_total": -1.0}, [], [])
-    skipped = {
-        **base,
-        "ledger_overhead_pct": "skipped",
-        "ledger_program_count": "skipped",
-        "ledger_compile_seconds_total": "skipped",
-    }
-    assert check(skipped, [], []) == []
-
-
 # ---------------------------------------------------------------------------
 # Sentinel: ledger watches trip the same machinery
 # ---------------------------------------------------------------------------

@@ -369,7 +369,7 @@ def test_robustness_matrix_cli_emits_json(tmp_path, monkeypatch, capsys):
     assert set(on_disk["matrix"]) == set(report["checkpoints"])
     cell = next(iter(next(iter(on_disk["matrix"].values())).values()))
     assert "episode_return_per_agent" in next(iter(cell.values()))
-    # The stdout JSON line parses (bench.py contract style).
+    # The stdout JSON line parses.
     last = capsys.readouterr().out.strip().splitlines()[-1]
     assert json.loads(last)["eval_compiles"] == 1
 

@@ -239,8 +239,6 @@ def test_anakin_dispatch_surfaces_and_options_are_fenced(tmp_path):
         sebulba.run_chunk()
     with pytest.raises(SystemExit, match="recovery"):
         make_sebulba(tmp_path / "rec", recovery=True)
-    with pytest.raises(SystemExit, match="iters_per_dispatch"):
-        make_sebulba(tmp_path / "ipd", iters_per_dispatch=2)
 
 
 # ---------------------------------------------------------------------------

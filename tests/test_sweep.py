@@ -411,25 +411,17 @@ def test_sweep_composes_with_ctde_and_gnn(tmp_path):
     assert np.isfinite(np.asarray(m["loss"])).all()
 
 
-def test_sweep_burst_retired_and_hetero_rejects_dispatch_fusion(tmp_path):
-    """iters_per_dispatch (the reduced-metrics burst) is RETIRED for
-    sweeps — fused_chunk is the population fusion spelling
+def test_hetero_rejects_fused_chunk(tmp_path):
+    """fused_chunk is the population fusion spelling
     (tests/test_fused_sweep.py pins its bitwise parity); the single-run
-    curriculum trainer still rejects both knobs (host-driven stages)."""
-    params = EnvParams(num_agents=3)
-    with pytest.raises(SystemExit, match="fused_chunk"):
-        SweepTrainer(
-            params, ppo=PPO, config=_cfg(tmp_path, iters_per_dispatch=2),
-            num_seeds=2,
-        )
-
+    curriculum trainer rejects it (host-driven stages)."""
     from marl_distributedformation_tpu.train import HeteroTrainer
 
-    with pytest.raises(SystemExit, match="iters_per_dispatch"):
+    with pytest.raises(SystemExit, match="fused_chunk"):
         HeteroTrainer(
-            env_params=params,
+            env_params=EnvParams(num_agents=3),
             ppo=PPO,
-            config=_cfg(tmp_path, iters_per_dispatch=2),
+            config=_cfg(tmp_path, fused_chunk=2),
         )
 
 

@@ -59,46 +59,6 @@ def test_trainer_runs_and_logs(tmp_path):
     assert records[-1]["step"] == trainer.total_timesteps
 
 
-def test_iters_per_dispatch_matches_single_dispatch(tmp_path):
-    """iters_per_dispatch=2 runs the same math as two single-iteration
-    dispatches: params match tightly, timestep accounting and metric
-    aggregation (mean; dones sum) hold, and train() end-to-end works."""
-    single = tiny_trainer(tmp_path, name="single")
-    burst = tiny_trainer(
-        tmp_path, name="burst", iters_per_dispatch=2,
-        log_dir=str(tmp_path / "logs_burst"),
-    )
-    m0 = single.run_iteration()
-    m1 = single.run_iteration()
-    mb = burst.run_iteration()
-    assert single.num_timesteps == burst.num_timesteps == 2 * 4 * 4 * 3
-    leaves_s = jax.tree_util.tree_leaves(single.train_state.params)
-    leaves_b = jax.tree_util.tree_leaves(burst.train_state.params)
-    for a, b in zip(leaves_s, leaves_b):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-7
-        )
-    np.testing.assert_allclose(
-        float(mb["reward"]),
-        (float(m0["reward"]) + float(m1["reward"])) / 2,
-        rtol=1e-5,
-    )
-    np.testing.assert_allclose(
-        float(mb["episode_dones"]),
-        float(m0["episode_dones"]) + float(m1["episode_dones"]),
-    )
-    # End-to-end: 4 iterations in 2 dispatches, checkpoints + logs land.
-    full = tiny_trainer(
-        tmp_path, name="burst_train", iters_per_dispatch=2,
-        log_dir=str(tmp_path / "logs_bt"),
-        total_timesteps=4 * 4 * 4 * 3,
-    )
-    final = full.train()
-    assert full.num_timesteps == full.total_timesteps
-    assert np.isfinite(final["loss"])
-    assert latest_checkpoint(tmp_path / "logs_bt") is not None
-
-
 def test_checkpoint_write_discovery_resume(tmp_path):
     trainer = tiny_trainer(tmp_path)
     trainer.train()

@@ -12,8 +12,8 @@ holds the single copy of the compiled-parity assertion:
   ``MDF_TPU_TESTS=1 pytest tests/`` (conftest leaves the real backend on and
   ``test_ops_pallas.py::test_compiled_pallas_parity_on_tpu`` runs all
   three legs);
-- bench.py's knn phase also exercises the compiled kernel on TPU
-  (``impl="auto"`` selects it inside the jitted scan).
+- the benchmark's ``gnn100-train-m8k`` cell also exercises the compiled
+  kernel on TPU (``impl="auto"`` selects it inside the jitted scan).
 """
 
 import sys

@@ -67,7 +67,6 @@ def train_config_from_config(cfg) -> TrainConfig:
         # Dispatches to trace under profile=true — whole fused chunks in
         # Anakin mode (chunk-granular capture, docs/profiling.md).
         profile_iterations=int(cfg.get("profile_iterations", 3)),
-        iters_per_dispatch=int(cfg.get("iters_per_dispatch", 1)),
         # Anakin mode (docs/training.md): K iterations per lax.scan
         # dispatch, stacked metrics drained double-buffered, checkpoints
         # on a background writer. fused_chunk=32 is a good TPU default.
