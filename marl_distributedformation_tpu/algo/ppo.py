@@ -22,6 +22,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 from flax import struct
 from flax.training.train_state import TrainState
@@ -144,40 +145,82 @@ def _wmean(x: Array, weights: Array) -> Array:
     return (x * w).sum() / jnp.maximum(w.sum(), 1e-8)
 
 
-# One vreg's lanes: rows at most this wide are gathered as one packed row.
+# One (8,128) tile's lanes: the packed table's physical row. Rows at most
+# this wide pack, as many to a physical row as their power-of-two width fits.
 _PACK_MAX_WIDTH = 128
 
 
 def _pack_rows(
     data: MinibatchData,
-) -> Optional[Tuple[Array, Callable[[Array], MinibatchData]]]:
-    """Concatenate ``data``'s leaves into one float32 ``(total, width)``
-    table, so a minibatch's rows are looked up once instead of once a leaf
-    (the TPU pays a narrow-row gather per index, not per byte).
+) -> Optional[Tuple[Callable[[Array], Array], Callable[[Array], MinibatchData]]]:
+    """Pack ``data``'s leaves into one float32 table 128 lanes wide, so a
+    minibatch's rows are looked up once instead of once a leaf, and an index
+    touches one (8,128) tile (the TPU pays a narrow-row gather per index and
+    per tile the row touches, not per byte).
 
-    Returns ``(table, unpack)`` with ``unpack(table[idx])`` bitwise equal to
-    ``tree_map(lambda x: x[idx], data)``, or ``None`` where the rows do not
-    pack: a leaf that is not float32, or rows wider than one vreg's lanes
-    (per-formation rows are already wide contiguous blocks)."""
+    A packed row of ``width`` floats takes ``sub`` lanes, the next power of
+    two; ``g = 128 // sub`` of them share a physical row, ``P = ceil(total /
+    g)`` physical rows in all. Logical row ``j`` lies in physical row ``j %
+    P``, lanes ``(j // P) * sub`` onwards: strided groups, so the table is
+    the plain transpose of a ``(128, P)`` array and ``total`` stays on the
+    lanes while it is built (eight consecutive rows a physical row would go
+    through a ``(total, sub)`` array lane-padded to 128).
+
+    Returns ``(lookup, unpack)`` with ``unpack(lookup(idx))`` equal to
+    ``tree_map(lambda x: x[idx], data)`` for ``idx`` in ``[0, total)``, or
+    ``None`` where the rows do not pack: a leaf that is not float32, or rows
+    wider than 128 floats (per-formation rows are already wide contiguous
+    blocks). Where ``g > 1``, ``lookup`` picks the sub-row with a lane mask
+    and a product against a constant 0/1 matrix at ``Precision.HIGHEST``
+    (scope ``subrow_pick``): bit-equal for finite values, except that
+    ``-0.0`` comes back ``+0.0``; a non-finite entry makes every float of
+    its own logical row NaN or infinite, and of no other."""
     leaves, treedef = jax.tree_util.tree_flatten(data)
     shapes = [x.shape[1:] for x in leaves]
     widths = [math.prod(shape) for shape in shapes]
-    if sum(widths) > _PACK_MAX_WIDTH or any(
-        x.dtype != jnp.float32 for x in leaves
-    ):
+    width = sum(widths)
+    if width > _PACK_MAX_WIDTH or any(x.dtype != jnp.float32 for x in leaves):
         return None
-    table = jnp.concatenate(
-        [x.reshape(-1, w) for x, w in zip(leaves, widths)], axis=1
+    total = leaves[0].shape[0]
+    sub = 1 << (width - 1).bit_length()
+    groups = _PACK_MAX_WIDTH // sub
+    phys = -(-total // groups)
+    columns = jnp.concatenate(
+        [x.reshape(total, w).T for x, w in zip(leaves, widths)], axis=0
     )
-    splits = list(itertools.accumulate(widths))[:-1]
+    columns = jnp.pad(columns, ((0, sub - width), (0, groups * phys - total)))
+    table = (
+        columns.reshape(sub, groups, phys)
+        .transpose(1, 0, 2)
+        .reshape(_PACK_MAX_WIDTH, phys)
+        .T
+    )
+    lane = np.arange(_PACK_MAX_WIDTH)
+    lane_group = lane // sub
+    fold = (lane[:, None] % sub == np.arange(sub)).astype(np.float32)
+
+    def lookup(idx: Array) -> Array:
+        rows = table[idx % phys]
+        if groups == 1:
+            return rows
+        with jax.named_scope("subrow_pick"):
+            mine = (idx // phys)[:, None] == lane_group
+            return jnp.dot(
+                jnp.where(mine, rows, 0.0),
+                fold,
+                precision=jax.lax.Precision.HIGHEST,
+            )
+
+    splits = list(itertools.accumulate(widths))
 
     def unpack(rows: Array) -> MinibatchData:
+        # the last split is the pad up to ``sub`` lanes
         return treedef.unflatten(
             col.reshape(-1, *shape)
             for col, shape in zip(jnp.split(rows, splits, axis=1), shapes)
         )
 
-    return table, unpack
+    return lookup, unpack
 
 
 def ppo_loss(
@@ -335,8 +378,8 @@ def ppo_update(
             if packed is None:
                 mb = jax.tree_util.tree_map(lambda x: x[idx], data)
             else:
-                table, unpack = packed
-                mb = unpack(table[idx])
+                lookup, unpack = packed
+                mb = unpack(lookup(idx))
         ent_coef = None
         if decay:
             # Two-limb float split of the integer step: a straight

@@ -55,6 +55,7 @@ DEVICE_SCOPES = (
     "epoch_shuffle",  # algo/ppo.py, under ppo_update
     "minibatch_gather",  # algo/ppo.py, under ppo_update
     "row_pack",  # algo/ppo.py, under minibatch_gather, where rows pack
+    "subrow_pick",  # algo/ppo.py, under minibatch_gather: >1 row a table row
     "loss_and_grad",  # algo/ppo.py, under ppo_update
     "optimizer_step",  # algo/ppo.py, under ppo_update
     "neighbor_gather",  # models/gnn.py: under policy and loss_and_grad

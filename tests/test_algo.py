@@ -418,3 +418,145 @@ def test_per_formation_rows_do_not_pack(monkeypatch, with_mask):
     _assert_same_update(got, _unpacked(monkeypatch, ppo_update, *args))
     assert np.isfinite(float(got[1]["loss"]))
     assert float(got[1]["grad_norm"]) > 0
+
+
+# ----------------------------------------------------------------------
+# The packed table 128 lanes wide: one physical row an index, the sub-row
+# picked out of it
+# ----------------------------------------------------------------------
+
+_LANES = ppo_module._PACK_MAX_WIDTH
+
+
+def _groups(width):
+    """Logical rows a physical row of the packed table holds."""
+    return _LANES // (1 << (width - 1).bit_length())
+
+
+def _rows_of_width(key, total, width, weighted=False):
+    """``total`` rows whose packed width is ``width`` floats: the five
+    rollout leaves (and ``weights``) where the width leaves ``obs`` a float,
+    ``obs`` alone (two axes a row, so the reshape is held too) below it."""
+    ks = jax.random.split(key, 6)
+    if width < 7:
+        shape = (total, width // 2, 2) if width % 2 == 0 else (total, width)
+        return MinibatchData(
+            obs=jax.random.normal(ks[0], shape), actions=None,
+            old_log_probs=None, advantages=None, returns=None,
+        )
+    return MinibatchData(
+        obs=jax.random.normal(ks[0], (total, width - 5 - weighted)),
+        actions=jax.random.normal(ks[1], (total, 2)),
+        old_log_probs=-jnp.abs(jax.random.normal(ks[2], (total,))),
+        advantages=jax.random.normal(ks[3], (total,)),
+        returns=jax.random.normal(ks[4], (total,)),
+        weights=jax.random.uniform(ks[5], (total,)) if weighted else None,
+    )
+
+
+def _lookup(data, idx):
+    lookup, unpack = ppo_module._pack_rows(data)
+    return unpack(lookup(idx))
+
+
+def _assert_same_bits(got, want):
+    chex.assert_trees_all_equal_shapes_and_dtypes(got, want)
+    bits = lambda x: np.asarray(x).view(np.uint32)  # noqa: E731
+    chex.assert_trees_all_equal(
+        jax.tree_util.tree_map(bits, got), jax.tree_util.tree_map(bits, want)
+    )
+
+
+_TOTALS = {
+    "ragged": lambda g: 7 * g + 3,  # g does not divide it (where g > 1)
+    "under_g": lambda g: max(g - 1, 1),  # one physical row, not full
+    "whole": lambda g: 8 * g,
+}
+
+
+@pytest.mark.parametrize(
+    "width,total_kind,weighted",
+    [
+        (width, kind, weighted)
+        for width in (1, 2, 13, 16, 17, 64, 65, 128)
+        for kind in _TOTALS
+        for weighted in ((False, True) if width >= 7 else (False,))
+    ],
+)
+def test_lookup_equals_a_gather_a_leaf_bitwise(width, total_kind, weighted):
+    """``unpack(lookup(idx))`` against ``x[idx]`` on every leaf, at every
+    index: among them 0, ``P - 1``, ``P`` (the first row of the second
+    group of lanes) and ``total - 1``, and each more than once."""
+    groups = _groups(width)
+    total = _TOTALS[total_kind](groups)
+    data = _rows_of_width(jax.random.PRNGKey(width), total, width, weighted)
+    assert sum(
+        x[0].size for x in jax.tree_util.tree_leaves(data)
+    ) == width
+    phys = -(-total // groups)
+    edges = jnp.asarray([0, phys - 1, min(phys, total - 1), total - 1])
+    idx = jnp.concatenate(
+        [edges, jax.random.permutation(jax.random.PRNGKey(1), total), edges]
+    )
+    want = jax.tree_util.tree_map(lambda x: x[idx], data)
+    _assert_same_bits(_lookup(data, idx), want)
+    _assert_same_bits(jax.jit(_lookup)(data, idx), want)
+
+
+@pytest.mark.parametrize("width", [13, 65])
+def test_lookup_under_vmap_over_two_members(width):
+    """The table gains a member axis and the gather a batch dimension; each
+    member gets its own rows at its own indices."""
+    total = 7 * _groups(width) + 3
+    datas = [
+        _rows_of_width(jax.random.PRNGKey(i), total, width) for i in range(2)
+    ]
+    idx = jnp.stack(
+        [jax.random.permutation(jax.random.PRNGKey(7 + i), total) for i in range(2)]
+    )
+    stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *datas)
+    got = jax.vmap(_lookup)(stacked, idx)
+    want = jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs),
+        *[jax.tree_util.tree_map(lambda x: x[i], d) for d, i in zip(datas, idx)],
+    )
+    _assert_same_bits(got, want)
+
+
+@pytest.mark.parametrize("width", [13, 16, 65], ids=["pick", "pick_full", "no_pick"])
+def test_lookup_special_values_as_the_docstring_states(width):
+    """Where a sub-row is picked (``g > 1``) ``-0.0`` comes back ``+0.0``
+    and a non-finite float makes its own logical row non-finite, and no
+    row beside it in the same physical row; where the gathered row is the
+    row (``g == 1``) every bit comes back."""
+    groups = _groups(width)
+    total = 7 * groups + 3
+    phys = -(-total // groups)
+    data = _rows_of_width(jax.random.PRNGKey(3), total, width)
+    sick = {2: jnp.inf, 3: -jnp.inf, 4: jnp.nan}
+    obs = data.obs.at[1, 0].set(-0.0)
+    for row, value in sick.items():
+        obs = obs.at[row, 1].set(value)
+    data = data.replace(obs=obs)
+    idx = jnp.arange(total)
+    got = _lookup(data, idx)
+    want = jax.tree_util.tree_map(lambda x: x[idx], data)
+    healthy = np.setdiff1d(np.arange(total), [1, *sick])
+    if groups > 1:
+        # rows 2 + phys, ... share physical rows with the sick ones
+        assert set(np.asarray(list(sick)) + phys) <= set(healthy)
+    _assert_same_bits(
+        jax.tree_util.tree_map(lambda x: x[healthy], got),
+        jax.tree_util.tree_map(lambda x: x[healthy], want),
+    )
+    if groups == 1:
+        _assert_same_bits(got, want)
+        return
+    zero = np.asarray(got.obs)[1, 0]
+    assert zero == 0.0 and not np.signbit(zero)
+    np.testing.assert_array_equal(
+        np.asarray(got.obs)[1, 1:], np.asarray(want.obs)[1, 1:]
+    )
+    for row in sick:
+        for leaf in jax.tree_util.tree_leaves(got):
+            assert not np.isfinite(np.asarray(leaf)[row]).any(), (row, leaf[row])

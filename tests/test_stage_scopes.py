@@ -38,6 +38,7 @@ PARENTS = {
     "epoch_shuffle": ("ppo_update",),
     "minibatch_gather": ("ppo_update",),
     "row_pack": ("minibatch_gather",),
+    "subrow_pick": ("minibatch_gather",),
     "loss_and_grad": ("ppo_update",),
     "optimizer_step": ("ppo_update",),
     "neighbor_gather": ("policy", "loss_and_grad"),
@@ -50,9 +51,10 @@ PARENTS = {
 }
 GNN_ONLY = ("neighbor_gather",)
 TRUNK_ONLY = ("trunk_attention", "trunk_indexer", "trunk_moe")
-# Rows of 13 floats pack; a formation's rows (8 agents x 21 floats) are
-# over one vreg's lanes and keep the gather a leaf.
-MLP_ONLY = ("row_pack",)
+# Rows of 13 floats pack, eight to a 128-lane row of the table, so the
+# sub-row is picked; a formation's rows (8 agents x 21 floats) are over one
+# vreg's lanes and keep the gather a leaf.
+MLP_ONLY = ("row_pack", "subrow_pick")
 
 
 def _tiny_trainer(policy, tmp_path, **config):
@@ -126,16 +128,35 @@ def test_scope_is_an_exact_path_part_under_its_parent(op_paths, scope, policy):
     assert found == set(PARENTS[scope]), (scope, policy, found)
 
 
+def _minibatch_gathers(text):
+    """The ``gather`` instructions under ``minibatch_gather``."""
+    return [
+        line for line in text.splitlines()
+        if re.search(r"= \S+ gather\(", line)
+        and re.search(r'op_name="[^"]*/minibatch_gather/', line)
+    ]
+
+
 @pytest.mark.parametrize("policy,gathers", [("mlp", 1), ("gnn", 5), ("trunk", 5)])
 def test_a_minibatch_is_one_gather_where_rows_pack(compiled_text, policy, gathers):
     """Packed rows are looked up once a minibatch; a leaf at a time (five
     leaves) where they are not."""
-    found = [
-        line for line in compiled_text[policy].splitlines()
-        if re.search(r"= \S+ gather\(", line)
-        and re.search(r'op_name="[^"]*/minibatch_gather/', line)
-    ]
+    found = _minibatch_gathers(compiled_text[policy])
     assert len(found) == gathers, found
+
+
+def test_the_one_gather_fetches_a_128_lane_row_an_index(compiled_text):
+    """The packed table's physical row is one (8,128) tile's lanes: the mlp
+    iteration's only gather under ``minibatch_gather`` fetches a ``(1, 128)``
+    slice an index, and the fold to the sub-row's 16 lanes is a ``dot``
+    under ``subrow_pick``."""
+    (gather,) = _minibatch_gathers(compiled_text["mlp"])
+    # 24 rows a minibatch; the CPU's compiler keeps the slice's unit axis
+    assert re.search(r"= f32\[24,(1,)?128\]\S* gather\(", gather), gather
+    assert "slice_sizes={1,128}" in gather, gather
+    under = _opcodes_under(compiled_text["mlp"], "subrow_pick")
+    assert under and any("dot" in ops for ops in under.values()), under
+    assert all(above.endswith("/minibatch_gather") for above in under), under
 
 
 def _opcodes_under(text, scope):
