@@ -3,23 +3,44 @@
 ``policy=trunk trunk=<name>`` (train.py) reads the architecture from
 ``cfg/trunk/<name>.yaml``: a published decoder block under its published
 key names, plus what of it this chip holds (``layers_held``,
-``experts_held``, ``expert_share``). A sequence is one swarm at one time
-step in ring-slot order, the mask is causal over the agent index, and a
-minibatch row is a whole swarm-step (``per_formation``).
+``experts_held``, ``expert_share``, ``head_share``). A sequence is one swarm
+at one time step in ring-slot order, the mask is causal over the agent
+index, and a minibatch row is a whole swarm-step (``per_formation``).
 
-The block (equations in ``benchmarks/reference/policy_trunk.py``, which
-the tests hold this module to): RMSNorm, grouped-query attention with q/k
-head norms and RoPE over the keys a learned indexer selects (its ``topk``
-largest index scores among the keys a query can see), RMSNorm, a routed
-expert layer that is told which experts it holds, routes over all of them
-and computes the part its own give, with no token dropped.
+A layer is a token mixer, then RMSNorm and a routed expert layer that is
+told which experts it holds, routes over all of them and computes the part
+its own give, with no token dropped (plus the shared expert, where the
+model has one: whole on every chip). The mixers (``MIXERS``; equations in
+``benchmarks/reference/policy_trunk.py`` and ``policy_trunk_hybrid.py``,
+which the tests hold this module to):
+
+- ``sparse_gqa`` (Keye-VL-2.0's): grouped-query attention with q/k head
+  norms and RoPE over the keys a learned indexer selects (its ``topk``
+  largest index scores among the keys a query can see);
+- ``gated_gqa`` (Solar-Open2's layers ``gqa_layers``): dense causal
+  grouped-query attention, no RoPE, no q/k norm, a sigmoid output gate;
+- ``kda`` (its other layers): Kimi Delta Attention, short causal
+  convolutions, a per-channel log-decay gate, the delta rule with beta in
+  (0, 2) run in chunks over the agent axis (``models/kda.py``), a gated
+  head norm. The state runs over the agents of one swarm-step inside one
+  forward pass; nothing is carried over time.
+
+Of a mixer's heads the chip may hold a share (``head_share``: share i of n
+holds ``heads / n`` query heads with their key heads): the projections'
+widths follow the heads held and ``o @ wo`` is the partial sum it is.
 
 How it is computed here:
 
-- layers run under one ``lax.scan``, a swarm at a time with
-  ``jax.checkpoint`` a layer; attention works by blocks of ``q_chunk_size`` queries against the keys
-  the block can see, each block recomputed in the backward pass, so the
-  ``heads x S x S`` scores never exist whole;
+- layers run under one ``lax.scan`` over the periods of the layer pattern
+  (a trunk of one kind: over its layers), a swarm at a time with
+  ``jax.checkpoint`` a layer; attention works by blocks of ``q_chunk_size``
+  queries against the keys the block can see, each block recomputed in the
+  backward pass, so the ``heads x S x S`` scores never exist whole
+  (``gated_gqa``: by pairs of a query block and a tile of keys under the
+  diagonal, all of one shape, a block's tiles put together afterwards);
+- a mixer's projections that read the normalised input are one product
+  with one parameter leaf, the matrices side by side (``w_in`` of
+  ``gated_gqa`` and ``kda``, ``s_in`` of the shared expert);
 - the selection needs no sort: a query's ``topk``-th largest index score
   is found by bisection on the float's bits (32 counting passes), ties go
   to the lower index by a running count, and the result is a mask on the
@@ -27,10 +48,11 @@ How it is computed here:
   the recomputation selects nothing twice. The group whose queries see at
   most ``topk`` keys selects everything and skips it;
 - precision: float32 state and activations; products in three bf16 passes
-  (jax's ``high``); both selections (the indexer's ``qI . kI`` and the
-  router's logits) and the observation embedding at float32 ``highest``:
-  fewer passes there re-rank keys around rank ``topk`` and experts around
-  rank ``num_experts_per_tok``;
+  (jax's ``high``), the delta rule's state update among them; both
+  selections (the indexer's ``qI . kI`` and the router's logits) and the
+  observation embedding at float32 ``highest``: fewer passes there re-rank
+  keys around rank ``topk`` and experts around rank
+  ``num_experts_per_tok``;
 - the expert layer runs each held expert over the swarm and masks in the
   tokens routed to it: dense under the routing's mask, not dispatched
   (``expert_layer``). The indexer gets no gradient (the selection is
@@ -41,8 +63,9 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import math
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,18 +77,27 @@ from marl_distributedformation_tpu.models.common import (
     PolicyHead,
     PooledValueHead,
 )
+from marl_distributedformation_tpu.models.kda import chunked_delta_rule, short_conv
 
 Array = jax.Array
 
 HIGHEST = jax.lax.Precision.HIGHEST
 # What a layer's checkpoint keeps besides its input (see select_keys).
 _KEPT = ("trunk_select_threshold", "trunk_select_ties")
-COUNTERS = ("moe_held_share", "moe_load_max_over_mean", "indexer_selected_mean")
+# Query blocks in one key tile of the gated GQA mixer (``_causal_tiles``).
+_GQA_TILE_BLOCKS = 4
+# Every counter a layer can sow; a trunk sows those its layers have.
+COUNTERS = (
+    "moe_held_share", "moe_load_max_over_mean", "indexer_selected_mean",
+    "kda_log_decay_mean", "kda_beta_mean",
+)
 
 
 @dataclasses.dataclass(frozen=True)
 class TrunkArch:
-    """The architecture file's content (hashable: a flax attribute)."""
+    """The architecture file's content (hashable: a flax attribute). Head
+    and expert counts are the published ones; ``*_held`` and the shares say
+    what of them this chip holds."""
 
     name: str
     hidden_size: int
@@ -73,10 +105,6 @@ class TrunkArch:
     num_key_value_heads: int
     head_dim: int
     rms_norm_eps: float
-    rope_theta: float
-    indexer_num_heads: int
-    indexer_head_dim: int
-    topk: int
     q_chunk_size: int
     num_experts: int
     num_experts_per_tok: int
@@ -85,19 +113,76 @@ class TrunkArch:
     layers_held: int
     experts_held: int
     expert_share: Tuple[int, int]  # (this chip's share, of how many)
+    head_share: Tuple[int, int] = (0, 1)
+    # the held layers' mixers, in order (keys of MIXERS)
+    layer_kinds: Tuple[str, ...] = ()
+    shared_expert_size: int = 0  # 0: the model has no shared expert
+    # sparse_gqa
+    rope_theta: float = 0.0
+    indexer_num_heads: int = 0
+    indexer_head_dim: int = 0
+    topk: int = 0
+    # kda
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_size: int = 0
+    kda_chunk_size: int = 0
+
+    @property
+    def period(self) -> Tuple[str, ...]:
+        """The shortest run of kinds that the held layers repeat."""
+        kinds = self.layer_kinds
+        return next(
+            kinds[:p] for p in range(1, len(kinds) + 1)
+            if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p)
+        )
+
+    def held(self, heads: int) -> int:
+        """How many of ``heads`` published heads this chip holds."""
+        return heads // self.head_share[1]
 
     @classmethod
     def from_dict(cls, name: str, data: dict) -> "TrunkArch":
-        sa = data["sa_config"]
+        """Both shapes of file: Keye-VL-2.0's (``sa_config``, ``num_experts``;
+        every layer ``sparse_gqa``) and Solar-Open2's (``gqa_layers``,
+        ``linear_attn_config``, ``n_routed_experts``, ``n_shared_experts``)."""
+        sa = data.get("sa_config") or {}
+        kda = data.get("linear_attn_config") or {}
+        hybrid = "gqa_layers" in data
+        layers = int(data["layers_held"])
         share, count = (int(v) for v in data["expert_share"])
+        head, head_count = (int(v) for v in data.get("head_share", (0, 1)))
         arch = cls(
             name=name,
+            hidden_size=data["hidden_size"],
+            num_attention_heads=data["num_attention_heads"],
+            num_key_value_heads=data["num_key_value_heads"],
+            head_dim=data["head_dim"],
+            rms_norm_eps=data["rms_norm_eps"],
+            q_chunk_size=(sa if "q_chunk_size" in sa else data)["q_chunk_size"],
+            num_experts=data["n_routed_experts" if hybrid else "num_experts"],
+            num_experts_per_tok=data["num_experts_per_tok"],
+            moe_intermediate_size=data["moe_intermediate_size"],
+            norm_topk_prob=data["norm_topk_prob"],
+            layers_held=layers,
+            experts_held=data["experts_held"],
             expert_share=(share, count),
-            **{
-                field.name: (sa if field.name in sa else data)[field.name]
-                for field in dataclasses.fields(cls)
-                if field.name not in ("name", "expert_share")
-            },
+            head_share=(head, head_count),
+            layer_kinds=tuple(
+                ("gated_gqa" if i in data["gqa_layers"] else "kda") if hybrid
+                else "sparse_gqa"
+                for i in range(layers)
+            ),
+            shared_expert_size=data["moe_intermediate_size"]
+            * int(data.get("n_shared_experts", 0)),
+            rope_theta=0.0 if hybrid else data["rope_theta"],
+            indexer_num_heads=sa.get("indexer_num_heads", 0),
+            indexer_head_dim=sa.get("indexer_head_dim", 0),
+            topk=sa.get("topk", 0),
+            kda_num_heads=kda.get("num_heads", 0),
+            kda_head_dim=kda.get("head_dim", 0),
+            kda_conv_size=kda.get("short_conv_kernel_size", 0),
+            kda_chunk_size=data.get("kda_chunk_size", 0),
         )
         unsupported = {
             "hidden_act": data.get("hidden_act", "silu") != "silu",
@@ -108,7 +193,25 @@ class TrunkArch:
             "expert_share": not 0 <= share < count
             or arch.experts_held * count != arch.num_experts,
             "layers_held": not 1 <= arch.layers_held <= int(data["num_hidden_layers"]),
+            "head_share": not 0 <= head < head_count
+            or arch.num_key_value_heads % head_count != 0
+            or arch.kda_num_heads % head_count != 0,
+            "sa_config": not hybrid and not sa,
         }
+        if hybrid:  # what the two new mixers do not compute
+            unsupported.update({
+                "use_rope": bool(data.get("use_rope", False)),
+                "use_gqa_gate": not data.get("use_gqa_gate", False),
+                "kda_use_full_proj": bool(data.get("kda_use_full_proj", False)),
+                "kda_allow_neg_eigval": not data.get("kda_allow_neg_eigval", False),
+                "first_k_dense_replace": int(data.get("first_k_dense_replace", 0)) != 0,
+                "routed_scaling_factor": data.get("routed_scaling_factor", 1) != 1,
+                "linear_attn_config": kda.get("num_kv_heads") is not None
+                or (
+                    "kda" in arch.layer_kinds
+                    and not (arch.kda_num_heads and arch.kda_chunk_size > 0)
+                ),
+            })
         bad = [key for key, wrong in unsupported.items() if wrong]
         if bad:
             raise ValueError(
@@ -286,22 +389,68 @@ def expert_layer(
     return out, counters
 
 
-# ----------------------------------------------------------------------
-# The layer and the module
-# ----------------------------------------------------------------------
+def shared_expert(h2: Array, w_in: Array, down: Array) -> Array:
+    """The shared expert's part for one swarm: every token, ungated; every
+    chip that shares the layer computes it alike. ``w_in`` is ``[gate |
+    up]`` side by side."""
+    gate, up = jnp.split(h2 @ w_in, 2, axis=1)
+    return (jax.nn.silu(gate) * up) @ down
 
 
-def trunk_layer(
-    x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool = False
-):
-    """One decoder layer on one swarm ``x (S, hidden)``; also its
-    counters. ``collect`` adds what was selected to them, the key mask
-    ``(S, S)`` and the experts ``(S, top)``: what a small swarm's test
-    compares."""
+# ----------------------------------------------------------------------
+# The mixers
+# ----------------------------------------------------------------------
+# A mixer's ``shapes(arch)`` names its parameters (initialiser, shape of one
+# layer); its ``mix(x, lp, arch, collect)`` takes one swarm ``x (S, hidden)``
+# and returns ``x`` with its part added, its counters summed over the
+# swarm's tokens (the layer divides by S) and, with ``collect``, what it
+# selected.
+
+
+def _sliced_normal(key, shape, dtype=jnp.float32):
+    # a layer's (and an expert's) slice at a time: the TPU compiles
+    # one draw of 10^8 numbers in 17 s, 64 of 10^6 in under one
+    lead = len(shape) - 2
+    draw = lambda k: nn.initializers.normal(0.02)(k, shape[lead:], dtype)  # noqa: E731
+    slices = jax.random.split(key, math.prod(shape[:lead]))
+    return jax.lax.map(draw, slices).reshape(shape)
+
+
+def _rbg_normal(key, shape, dtype=jnp.float32):
+    # XLA's own generator (``impl="rbg"``), one draw whatever the shape:
+    # ``_sliced_normal`` is a loop to compile a matrix, and a hybrid trunk has
+    # three times the matrices (``sparse_gqa`` keeps the draw it has had)
+    if jnp.issubdtype(key.dtype, jax.dtypes.prng_key):
+        key = jax.random.key_data(key)
+    rbg = jax.random.wrap_key_data(jnp.resize(key, (4,)), impl="rbg")
+    return nn.initializers.normal(0.02)(rbg, shape, dtype)
+
+
+def _sparse_gqa_shapes(a: TrunkArch):
+    h, hd = a.hidden_size, a.head_dim
+    nq, nkv = a.held(a.num_attention_heads), a.held(a.num_key_value_heads)
+    ni, di = a.indexer_num_heads, a.indexer_head_dim
+    return {
+        "attn_norm": (nn.initializers.ones, (h,)),
+        "wq": (_sliced_normal, (h, nq * hd)),
+        "wk": (_sliced_normal, (h, nkv * hd)),
+        "wv": (_sliced_normal, (h, nkv * hd)),
+        "wo": (_sliced_normal, (nq * hd, h)),
+        "q_norm": (nn.initializers.ones, (hd,)),
+        "k_norm": (nn.initializers.ones, (hd,)),
+        "idx_wq": (_sliced_normal, (h, ni * di)),
+        "idx_wk": (_sliced_normal, (h, di)),
+        "idx_w": (_sliced_normal, (h, ni)),
+        "idx_k_scale": (nn.initializers.ones, (di,)),
+        "idx_k_bias": (nn.initializers.zeros, (di,)),
+    }
+
+
+def _sparse_gqa(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
     s = x.shape[0]
     eps, theta = arch.rms_norm_eps, arch.rope_theta
-    nq, nkv, hd = arch.num_attention_heads, arch.num_key_value_heads, arch.head_dim
-    ni, di = arch.indexer_num_heads, arch.indexer_head_dim
+    nq, nkv = arch.held(arch.num_attention_heads), arch.held(arch.num_key_value_heads)
+    hd, ni, di = arch.head_dim, arch.indexer_num_heads, arch.indexer_head_dim
 
     with jax.named_scope("trunk_attention"):
         h = _rms(x, lp["attn_norm"], eps)
@@ -341,78 +490,331 @@ def trunk_layer(
         selected = selected + found.sum()
     with jax.named_scope("trunk_attention"):
         x = x + jnp.concatenate(outs) @ lp["wo"]
+    collected = {"selected_keys": jnp.concatenate(masks)} if collect else {}
+    return x, {"indexer_selected_mean": selected}, collected
+
+
+def _gated_gqa_shapes(a: TrunkArch):
+    h, hd = a.hidden_size, a.head_dim
+    nq, nkv = a.held(a.num_attention_heads), a.held(a.num_key_value_heads)
+    return {
+        "attn_norm": (nn.initializers.ones, (h,)),
+        # everything that reads the normalised input, side by side:
+        # [wq | wk | wv | wg], widths nq, nkv, nkv, nq heads
+        "w_in": (_rbg_normal, (h, 2 * (nq + nkv) * hd)),
+        "wo": (_rbg_normal, (nq * hd, h)),
+    }
+
+
+@jax.checkpoint
+def _attend_tile(q: Array, k: Array, v: Array, mask: Array):
+    """One tile of a softmax over more keys than it sees: ``q (T, kv, group,
+    d)``, ``k, v (L, kv, d)``, ``mask (T, L)`` with a key in every row ->
+    the tile's largest score ``m (kv, group, T)``, its weights' sum ``l`` and
+    their product with ``v``, ``(T, kv, group, d)``, both relative to ``m``.
+    Recomputed in the backward pass."""
+    scores = jnp.einsum("tgad,sgd->gats", q, k) * q.shape[-1] ** -0.5
+    scores = jnp.where(mask, scores, -jnp.inf)
+    m = scores.max(-1)
+    p = jnp.exp(scores - m[..., None])
+    return jnp.einsum("gats,sgd->tgad", p, v), m, p.sum(-1)
+
+
+def _causal_tiles(s: int, chunk: int):
+    """``(chunk, tile, pairs)``: the (query block, key tile) pairs a causal
+    mask leaves something of, for blocks of ``chunk`` queries and tiles of
+    ``tile`` keys (``_GQA_TILE_BLOCKS`` blocks long where that divides the
+    swarm). A swarm the chunk does not divide is one pair."""
+    if s % chunk:
+        return s, s, [(0, 0)]
+    tile = chunk * _GQA_TILE_BLOCKS
+    tile = tile if s % tile == 0 else chunk
+    return chunk, tile, [
+        (i, j) for i in range(s // chunk) for j in range(i * chunk // tile + 1)
+    ]
+
+
+def _gated_gqa(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
+    """Dense causal attention without the keys the mask hides: one loop over
+    the (query block, key tile) pairs under the diagonal, every pair of one
+    shape (so one loop body in each pass), and a block's tiles put together
+    by their largest scores and weight sums, as a softmax over all of them."""
+    s, hd = x.shape[0], arch.head_dim
+    nq, nkv = arch.held(arch.num_attention_heads), arch.held(arch.num_key_value_heads)
+    with jax.named_scope("trunk_gated_attention"):
+        h = _rms(x, lp["attn_norm"], arch.rms_norm_eps)
+        q, k, v, gate = jnp.split(
+            h @ lp["w_in"], (nq * hd, (nq + nkv) * hd, (nq + 2 * nkv) * hd), 1
+        )
+        q = q.reshape(s, nkv, nq // nkv, hd)
+        k, v = k.reshape(s, nkv, hd), v.reshape(s, nkv, hd)
+        chunk, tile, pairs = _causal_tiles(s, arch.q_chunk_size)
+        block_of = jnp.array([i for i, _ in pairs])
+
+        def pair(ij):
+            first, start = ij[0] * chunk, ij[1] * tile
+            visible = (
+                (start + jnp.arange(tile))[None, :] <= (first + jnp.arange(chunk))[:, None]
+            )
+            return _attend_tile(
+                jax.lax.dynamic_slice_in_dim(q, first, chunk),
+                jax.lax.dynamic_slice_in_dim(k, start, tile),
+                jax.lax.dynamic_slice_in_dim(v, start, tile),
+                visible,
+            )
+
+        o, m, l = jax.lax.map(pair, jnp.array(pairs))  # (pairs, ...)
+        blocks = s // chunk
+        largest = jax.ops.segment_max(m, block_of, blocks, indices_are_sorted=True)
+        weight = jnp.exp(m - largest[block_of])  # (pairs, kv, group, T)
+        total = jax.ops.segment_sum(l * weight, block_of, blocks, indices_are_sorted=True)
+        summed = jax.ops.segment_sum(
+            o * jnp.moveaxis(weight, -1, 1)[..., None], block_of, blocks,
+            indices_are_sorted=True,
+        )  # (blocks, T, kv, group, d)
+        attended = (summed / jnp.moveaxis(total, -1, 1)[..., None]).reshape(s, -1)
+        x = x + (attended * jax.nn.sigmoid(gate)) @ lp["wo"]
+    return x, {}, {}
+
+
+def _log_uniform_decay(key, shape, dtype=jnp.float32):
+    """``A_log``: log of U(1, 16), a value a head."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias(key, shape, dtype=jnp.float32):
+    """A bias whose softplus is U(0.001, 0.1)."""
+    dt = jax.random.uniform(key, shape, dtype, 0.001, 0.1)
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def _kda_shapes(a: TrunkArch):
+    h, d, heads = a.hidden_size, a.kda_head_dim, a.held(a.kda_num_heads)
+    width = heads * d
+    return {
+        "attn_norm": (nn.initializers.ones, (h,)),
+        # what reads the normalised input, side by side: [wq | wk | wv | f_a |
+        # g_a] (the two gates' low-rank first halves). ``w_beta`` stays a leaf
+        # of its own: with its ``heads`` columns the width is no multiple of
+        # 128 lanes, and the compiled update then copies the leaf, its moments
+        # and its gradient into another layout every step
+        "w_in": (_rbg_normal, (h, 3 * width + 2 * d)),
+        "w_beta": (_rbg_normal, (h, heads)),
+        "conv": (_rbg_normal, (a.kda_conv_size, 3 * width)),  # of q, k, v
+        "f_b": (_rbg_normal, (d, width)),  # the decay gate's second half
+        "dt_bias": (_dt_bias, (width,)),
+        "A_log": (_log_uniform_decay, (heads,)),
+        "g_b": (_rbg_normal, (d, width)),  # the output gate's second half
+        "o_norm": (nn.initializers.ones, (d,)),
+        "wo": (_rbg_normal, (width, h)),
+    }
+
+
+def _l2norm(x: Array) -> Array:
+    return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + 1e-6)
+
+
+def _kda(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
+    s, d = x.shape[0], arch.kda_head_dim
+    width = lp["wo"].shape[0]
+    with jax.named_scope("trunk_kda"):
+        h = _rms(x, lp["attn_norm"], arch.rms_norm_eps)
+        qkv, f, g = jnp.split(h @ lp["w_in"], (3 * width, 3 * width + d), 1)
+        q, k, v = jnp.moveaxis(
+            jax.nn.silu(short_conv(qkv, lp["conv"])).reshape(s, 3, -1, d), 1, 0
+        )
+        q = _l2norm(q) * d**-0.5
+        k = _l2norm(k)
+        log_decay = -jnp.exp(lp["A_log"])[:, None] * jax.nn.softplus(
+            (f @ lp["f_b"] + lp["dt_bias"]).reshape(s, -1, d)
+        )
+        beta = 2.0 * jax.nn.sigmoid(h @ lp["w_beta"])
+        with jax.named_scope("kda_recurrence"):
+            o = chunked_delta_rule(q, k, v, log_decay, beta, arch.kda_chunk_size)
+        gate = jax.nn.sigmoid(g @ lp["g_b"]).reshape(s, -1, d)
+        o = _rms(o, lp["o_norm"], arch.rms_norm_eps) * gate
+        x = x + o.reshape(s, -1) @ lp["wo"]
+    sums = {
+        "kda_log_decay_mean": log_decay.mean((1, 2)).sum(),
+        "kda_beta_mean": beta.mean(1).sum(),
+    }
+    return x, sums, {}
+
+
+class Mixer(NamedTuple):
+    shapes: Callable[[TrunkArch], Dict[str, tuple]]
+    mix: Callable
+    normal: Callable  # the draw of the layer's matrices
+
+
+MIXERS = {
+    "sparse_gqa": Mixer(_sparse_gqa_shapes, _sparse_gqa, _sliced_normal),
+    "gated_gqa": Mixer(_gated_gqa_shapes, _gated_gqa, _rbg_normal),
+    "kda": Mixer(_kda_shapes, _kda, _rbg_normal),
+}
+
+
+# ----------------------------------------------------------------------
+# The layer and the module
+# ----------------------------------------------------------------------
+
+
+def _moe_shapes(a: TrunkArch, normal: Callable):
+    h, f = a.hidden_size, a.moe_intermediate_size
+    shapes = {
+        "moe_norm": (nn.initializers.ones, (h,)),
+        "router": (normal, (h, a.num_experts)),
+        "w_gate": (normal, (a.experts_held, h, f)),
+        "w_up": (normal, (a.experts_held, h, f)),
+        "w_down": (normal, (a.experts_held, f, h)),
+    }
+    if a.shared_expert_size:
+        shapes.update({
+            "s_in": (normal, (h, 2 * a.shared_expert_size)),  # [gate | up]
+            "s_down": (normal, (a.shared_expert_size, h)),
+        })
+    return shapes
+
+
+def trunk_layer(
+    x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool = False,
+    kind: str = "sparse_gqa",
+):
+    """One decoder layer of mixer ``kind`` on one swarm ``x (S, hidden)``;
+    also its counters. ``collect`` adds what was selected to them, the key
+    mask ``(S, S)`` of a ``sparse_gqa`` layer and the experts ``(S, top)``:
+    what a small swarm's test compares."""
+    s = x.shape[0]
+    x, sums, collected = MIXERS[kind].mix(x, lp, arch, collect)
 
     with jax.named_scope("trunk_moe"):
-        h2 = _rms(x, lp["moe_norm"], eps)
-        e_top, c = route(
-            h2, lp["router"], arch.num_experts_per_tok, arch.norm_topk_prob
-        )
-        added, counters = expert_layer(
-            h2, e_top, c, lp["w_gate"], lp["w_up"], lp["w_down"], arch.expert_share
-        )
+        with jax.named_scope("router"):
+            h2 = _rms(x, lp["moe_norm"], arch.rms_norm_eps)
+            e_top, c = route(
+                h2, lp["router"], arch.num_experts_per_tok, arch.norm_topk_prob
+            )
+        with jax.named_scope("routed_experts"):
+            added, counters = expert_layer(
+                h2, e_top, c, lp["w_gate"], lp["w_up"], lp["w_down"],
+                arch.expert_share,
+            )
         x = x + added
-    counters["indexer_selected_mean"] = selected / s
+        if "s_in" in lp:  # the model has a shared expert
+            with jax.named_scope("shared_expert"):
+                x = x + shared_expert(h2, lp["s_in"], lp["s_down"])
+    for name, total in sums.items():
+        counters[name] = total / s
     if collect:
-        counters["selected_keys"] = jnp.concatenate(masks)
+        counters.update(collected)
         counters["selected_experts"] = e_top
     return x, counters
 
 
+def period_names(period: Tuple[str, ...]) -> Tuple[str, ...]:
+    """The parameter groups of a period's layers: ``0_gated_gqa``, ``1_kda``..."""
+    return tuple(f"{i}_{kind}" for i, kind in enumerate(period))
+
+
+def _one_draw(key, shapes: Dict[str, tuple]) -> Dict[str, tuple]:
+    """``shapes`` (name -> (initialiser, shape)) with every ``_rbg_normal``
+    matrix carved, in order, out of one draw: a draw is a program for the
+    TPU to compile, a quarter of a second each and forty of them a hybrid
+    trunk, and what is drawn is the same distribution."""
+    drawn = {name: shape for name, (init, shape) in shapes.items() if init is _rbg_normal}
+    sizes = [math.prod(shape) for shape in drawn.values()]
+    parts = jnp.split(
+        _rbg_normal(key, (sum(sizes),)), list(itertools.accumulate(sizes))[:-1]
+    )
+    carved = {
+        name: part.reshape(shape) for (name, shape), part in zip(drawn.items(), parts)
+    }
+    return {
+        name: ((lambda *_, name=name: carved[name]) if name in carved else init, shape)
+        for name, (init, shape) in shapes.items()
+    }
+
+
+def _stack(module: nn.Module, arch: TrunkArch, kind: str, count: int):
+    """The parameters of ``count`` layers of ``kind`` (mixer, then expert
+    layer) as ``module``'s own, stacked on a leading axis."""
+    mixer = MIXERS[kind]
+    shapes = {
+        name: (init, (count, *shape))
+        for name, (init, shape) in
+        {**mixer.shapes(arch), **_moe_shapes(arch, mixer.normal)}.items()
+    }
+    # (not for ``sparse_gqa``: taking a key here would move its draws)
+    if mixer.normal is _rbg_normal and module.is_initializing():
+        shapes = _one_draw(module.make_rng("params"), shapes)
+    return {name: module.param(name, init, shape) for name, (init, shape) in shapes.items()}
+
+
+class _LayerStack(nn.Module):
+    """One layer of the pattern's period, stacked over the periods."""
+
+    arch: TrunkArch
+    kind: str
+    periods: int
+
+    @nn.compact
+    def __call__(self) -> Dict[str, Array]:
+        return _stack(self, self.arch, self.kind, self.periods)
+
+
+# A hybrid trunk's layer is traced and lowered once a kind, not once a layer
+# and a pass: the rollout's, the bootstrap's and the update's forward passes
+# over the three ``kda`` layers are one jitted function's one trace.
+_traced_once = jax.jit(trunk_layer, static_argnames=("arch", "collect", "kind"))
+
+
 class TrunkLayers(nn.Module):
-    """The held layers' parameters, stacked on a leading layer axis, and
-    the scan over them."""
+    """The held layers' parameters and the scan over them. A trunk of one
+    kind holds ``<name> (layers, ...)`` and scans over its layers; one whose
+    layers differ holds ``<i>_<kind>/<name> (periods, ...)`` for the ``i``-th
+    layer of its pattern's period and scans over the periods, the body
+    running a period's layers in order. Stacked by position and not by
+    kind: a scan over a run of one kind would compile the kind once a pass
+    (20 s of a cold run), but a loop's body copies its layer out of the
+    stack, and the stack's gradient in, in every pass: 3.8% of the rate and
+    1.27 GiB at Solar-Open2's widths (PERF.md section 6, PR 33)."""
 
     arch: TrunkArch
 
     @nn.compact
     def __call__(self, x: Array) -> Tuple[Array, Dict[str, Array]]:
         a = self.arch
-        h, hd, f = a.hidden_size, a.head_dim, a.moe_intermediate_size
-        nq, nkv = a.num_attention_heads, a.num_key_value_heads
-        ni, di = a.indexer_num_heads, a.indexer_head_dim
+        period = a.period
+        periods = a.layers_held // len(period)
+        uniform = len(period) == 1
+        if uniform:
+            stacked = _stack(self, a, period[0], periods)
+        else:
+            stacked = {
+                name: _LayerStack(a, kind, periods, name=name)()
+                for name, kind in zip(period_names(period), period)
+            }
+        layer = trunk_layer if uniform else _traced_once
 
-        def normal(key, shape, dtype=jnp.float32):
-            # a layer's (and an expert's) slice at a time: the TPU compiles
-            # one draw of 10^8 numbers in 17 s, 64 of 10^6 in under one
-            lead = len(shape) - 2
-            draw = lambda k: nn.initializers.normal(0.02)(k, shape[lead:], dtype)  # noqa: E731
-            slices = jax.random.split(key, math.prod(shape[:lead]))
-            return jax.lax.map(draw, slices).reshape(shape)
-
-        shapes = {
-            "attn_norm": (nn.initializers.ones, (h,)),
-            "wq": (normal, (h, nq * hd)),
-            "wk": (normal, (h, nkv * hd)),
-            "wv": (normal, (h, nkv * hd)),
-            "wo": (normal, (nq * hd, h)),
-            "q_norm": (nn.initializers.ones, (hd,)),
-            "k_norm": (nn.initializers.ones, (hd,)),
-            "idx_wq": (normal, (h, ni * di)),
-            "idx_wk": (normal, (h, di)),
-            "idx_w": (normal, (h, ni)),
-            "idx_k_scale": (nn.initializers.ones, (di,)),
-            "idx_k_bias": (nn.initializers.zeros, (di,)),
-            "moe_norm": (nn.initializers.ones, (h,)),
-            "router": (normal, (h, a.num_experts)),
-            "w_gate": (normal, (a.experts_held, h, f)),
-            "w_up": (normal, (a.experts_held, h, f)),
-            "w_down": (normal, (a.experts_held, f, h)),
-        }
-        stacked = {
-            name: self.param(name, init, (a.layers_held, *shape))
-            for name, (init, shape) in shapes.items()
-        }
-
-        def layer(x, lp):
+        def run_layer(x, lp, kind=period[0]):
             # a swarm at a time, so that what a layer holds at once does
             # not grow with the batch
             swarm = jax.checkpoint(
-                functools.partial(trunk_layer, lp=lp, arch=a),
+                functools.partial(layer, lp=lp, arch=a, kind=kind),
                 policy=jax.checkpoint_policies.save_only_these_names(*_KEPT),
             )
             return jax.lax.map(swarm, x)
 
-        x, counters = jax.lax.scan(layer, x, stacked)
+        def run_period(x, pp):
+            found = {}
+            for name, kind in zip(period_names(period), period):
+                x, counters = run_layer(x, pp[name], kind)
+                for counter, value in counters.items():
+                    found.setdefault(counter, []).append(value)
+            return x, {  # the mean over the period's layers that have it
+                name: jnp.stack(values).mean(0) for name, values in found.items()
+            }
+
+        x, counters = jax.lax.scan(run_layer if uniform else run_period, x, stacked)
         return x, {name: value.mean() for name, value in counters.items()}
 
 
@@ -474,10 +876,16 @@ class TrunkActorCritic(nn.Module):
         )
 
     def forward_counters(self, params, obs: Array) -> Dict[str, Array]:
-        """The counters one forward pass sows (``COUNTERS``), as scalars:
-        the share of assignments that fall on held experts, the held
-        experts' largest load over their mean, the mean number of keys a
-        query selects. Read on demand: it is a forward pass of its own, so
-        the training iteration does not make it."""
+        """The counters one forward pass sows (those of ``COUNTERS`` this
+        trunk's layers have), as scalars: the share of assignments that fall
+        on held experts, the held experts' largest load over their mean;
+        the mean number of keys a query selects (``sparse_gqa``); the mean
+        log-decay a step and the mean beta (``kda``: how far the state
+        reaches and how hard it is overwritten). Read on demand: it is a
+        forward pass of its own, so the training iteration does not make
+        it."""
         _, sown = self.apply(params, obs, mutable=["counters"])
-        return {name: sown["counters"][name][0] for name in COUNTERS}
+        return {
+            name: sown["counters"][name][0]
+            for name in COUNTERS if name in sown["counters"]
+        }

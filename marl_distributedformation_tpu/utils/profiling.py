@@ -63,6 +63,12 @@ DEVICE_SCOPES = (
     "trunk_attention",  # q/k/v/o products, norms, RoPE, blocked softmax
     "trunk_indexer",  # its three products, the index scores, the selection
     "trunk_moe",  # router, the held experts' products, mask and combine
+    "router",  # under trunk_moe: its norm, logits at highest, softmax, top-k
+    "routed_experts",  # under trunk_moe: the held experts under the mask
+    "shared_expert",  # under trunk_moe, where the model has one
+    "trunk_gated_attention",  # gated NoPE GQA: products, blocked softmax, gate
+    "trunk_kda",  # a Kimi-Delta layer's mixer whole
+    "kda_recurrence",  # under trunk_kda: the chunked delta rule (models/kda.py)
 )
 # ``pl.pallas_call(name=...)`` of the two k-NN kernels (ops/knn_pallas.py):
 # N <= 512 fused, larger N streaming. Both keep the substring ``knn``.

@@ -43,18 +43,37 @@ PARENTS = {
     "optimizer_step": ("ppo_update",),
     "neighbor_gather": ("policy", "loss_and_grad"),
     "trunk_attention": ("policy", "loss_and_grad"),
-    # what of them has no tangent (the selection's counting passes, the
-    # assignment sort) jax hoists out of the differentiated function, and
-    # the hoisted layer loop loses ``loss_and_grad`` from its path
-    "trunk_indexer": ("policy", "loss_and_grad", "ppo_update"),
-    "trunk_moe": ("policy", "loss_and_grad", "ppo_update"),
+    "trunk_indexer": ("policy", "loss_and_grad"),
+    "trunk_moe": ("policy", "loss_and_grad"),
+    "router": ("trunk_moe",),
+    "routed_experts": ("trunk_moe",),
+    "shared_expert": ("trunk_moe",),
+    "trunk_gated_attention": ("policy", "loss_and_grad"),
+    "trunk_kda": ("policy", "loss_and_grad"),
+    "kda_recurrence": ("trunk_kda",),
+}
+# In a sparse_gqa trunk, what of these has no tangent (the selection's
+# counting passes, the routing) jax hoists out of the differentiated function,
+# and the hoisted layer loop loses ``loss_and_grad`` from its path; of the
+# hybrid's layers (each kind one jitted function, traced once) the softmax
+# pairs' causal masks, the routing and the KDA chunks' masks are.
+HOISTED = {
+    "trunk": ("trunk_indexer", "trunk_moe", "routed_experts"),
+    "trunk_hybrid": ("trunk_gated_attention", "trunk_moe", "trunk_kda"),
 }
 GNN_ONLY = ("neighbor_gather",)
-TRUNK_ONLY = ("trunk_attention", "trunk_indexer", "trunk_moe")
+# what only a sparse_gqa layer opens, what every trunk layer opens, and what
+# only the hybrid's layers open (models/trunk.py MIXERS)
+SPARSE_ONLY = ("trunk_attention", "trunk_indexer")
+HYBRID_ONLY = ("shared_expert", "trunk_gated_attention", "trunk_kda", "kda_recurrence")
+TRUNK_ONLY = SPARSE_ONLY + ("trunk_moe", "router", "routed_experts") + HYBRID_ONLY
 # Rows of 13 floats pack, eight to a 128-lane row of the table, so the
 # sub-row is picked; a formation's rows (8 agents x 21 floats) are over one
 # vreg's lanes and keep the gather a leaf.
 MLP_ONLY = ("row_pack", "subrow_pick")
+
+
+POLICIES = ("mlp", "gnn", "trunk", "trunk_hybrid")
 
 
 def _tiny_trainer(policy, tmp_path, **config):
@@ -71,7 +90,8 @@ def _tiny_trainer(policy, tmp_path, **config):
     if policy == "gnn":
         agents, model = 8, GNNActorCritic(k=3, rounds=2)
     else:  # 16 agents: the second block of 8 queries sees more than topk 8
-        agents, model = 16, TrunkActorCritic(arch=load_trunk_arch("tiny"), k=3)
+        name = {"trunk": "tiny", "trunk_hybrid": "tiny-hybrid"}[policy]
+        agents, model = 16, TrunkActorCritic(arch=load_trunk_arch(name), k=3)
     return Trainer(
         EnvParams(num_agents=agents, obs_mode="knn", knn_k=3),
         ppo=PPOConfig(n_steps=4, batch_size=4 * agents, n_epochs=2),
@@ -84,7 +104,7 @@ def _tiny_trainer(policy, tmp_path, **config):
 def compiled_text(tmp_path_factory):
     """policy -> the compiled tiny training iteration's HLO text."""
     texts = {}
-    for policy in ("mlp", "gnn", "trunk"):
+    for policy in POLICIES:
         trainer = _tiny_trainer(policy, tmp_path_factory.mktemp(policy))
         texts[policy] = trainer._iteration.lower(
             trainer.train_state, trainer.env_state, trainer.obs, trainer.key
@@ -108,7 +128,7 @@ def op_paths(compiled_text):
     return paths
 
 
-@pytest.mark.parametrize("policy", ["mlp", "gnn", "trunk"])
+@pytest.mark.parametrize("policy", POLICIES)
 @pytest.mark.parametrize("scope", DEVICE_SCOPES)
 def test_scope_is_an_exact_path_part_under_its_parent(op_paths, scope, policy):
     assert set(PARENTS) == set(DEVICE_SCOPES)
@@ -120,12 +140,16 @@ def test_scope_is_an_exact_path_part_under_its_parent(op_paths, scope, policy):
     absent = {
         "mlp": GNN_ONLY + TRUNK_ONLY,
         "gnn": MLP_ONLY + TRUNK_ONLY,
-        "trunk": MLP_ONLY + GNN_ONLY,
+        "trunk": MLP_ONLY + GNN_ONLY + HYBRID_ONLY,
+        "trunk_hybrid": MLP_ONLY + GNN_ONLY + SPARSE_ONLY,
     }
     if scope in absent[policy]:
         assert not found
         return
-    assert found == set(PARENTS[scope]), (scope, policy, found)
+    expected = set(PARENTS[scope])
+    if scope in HOISTED.get(policy, ()):
+        expected.add("ppo_update" if scope != "routed_experts" else "trunk_moe")
+    assert found == expected, (scope, policy, found)
 
 
 def _minibatch_gathers(text):
@@ -137,7 +161,9 @@ def _minibatch_gathers(text):
     ]
 
 
-@pytest.mark.parametrize("policy,gathers", [("mlp", 1), ("gnn", 5), ("trunk", 5)])
+@pytest.mark.parametrize(
+    "policy,gathers", [("mlp", 1), ("gnn", 5), ("trunk", 5), ("trunk_hybrid", 5)]
+)
 def test_a_minibatch_is_one_gather_where_rows_pack(compiled_text, policy, gathers):
     """Packed rows are looked up once a minibatch; a leaf at a time (five
     leaves) where they are not."""
@@ -325,6 +351,12 @@ SCOPE_READERS = {
     "trunk_attention_ms": "trunk_attention",
     "trunk_indexer_ms": "trunk_indexer",
     "trunk_moe_ms": "trunk_moe",
+    "trunk_kda_ms": "trunk_kda",
+    "trunk_kda_recurrence_ms": "kda_recurrence",
+    "trunk_gated_attention_ms": "trunk_gated_attention",
+    "trunk_routed_experts_ms": "routed_experts",
+    "trunk_router_ms": "router",
+    "trunk_shared_expert_ms": "shared_expert",
 }
 
 

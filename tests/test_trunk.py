@@ -341,7 +341,10 @@ def test_forward_counters_account_for_one_pass(params, obs):
     """Read on demand, off a pass of their own: the training iteration
     does not pay for them."""
     counters = jax.device_get(_model().forward_counters(params, obs[:1]))
-    assert set(counters) == set(trunk.COUNTERS)
+    assert set(counters) == {  # a sparse_gqa trunk's own
+        "moe_held_share", "moe_load_max_over_mean", "indexer_selected_mean"
+    }
+    assert set(counters) < set(trunk.COUNTERS)
     assert counters["indexer_selected_mean"] == pytest.approx(7.125)
     assert 0.0 < counters["moe_held_share"] < 1.0
     assert 0.0 <= counters["moe_load_max_over_mean"] <= 2.0  # of 2 held; 0: none loaded
