@@ -128,6 +128,13 @@ class MinibatchData:
     #   the pooled critic; None for agent-factored models or homogeneous
     #   batches. Distinct from ``weights``: the mask shapes the MODEL's
     #   forward pass, weights shape the LOSS reduction.
+    rows_sharding: Any = struct.field(pytree_node=False, default=None)
+    #   Static, set on the flat rollout data ``ppo_update`` is handed: the
+    #   layout of an epoch's (num_minibatches, batch_size) row indices over
+    #   a mesh (``parallel.minibatch_sharding``), or None: every device
+    #   takes each minibatch whole. It rides on the data, not in
+    #   ``ppo_update``'s signature, so whatever stands in for
+    #   ``ppo_update(train_state, data, key, config)`` keeps the layout.
 
 
 def _leaf_name(entry) -> Optional[str]:
@@ -302,6 +309,14 @@ def ppo_loss(
     return loss, metrics
 
 
+def minibatch_shape(config: PPOConfig, total: int) -> Tuple[int, int]:
+    """``(num_minibatches, batch_size)`` of an update over ``total`` rows."""
+    # Clamp for rollouts smaller than batch_size (e.g. num_formation=1):
+    # train on one full-rollout minibatch instead of crashing.
+    batch_size = min(config.batch_size, total)
+    return total // batch_size, batch_size
+
+
 def ppo_update(
     train_state: TrainState,
     data: MinibatchData,
@@ -314,12 +329,16 @@ def ppo_update(
     agent-transitions — each agent is its own "environment", the reference's
     parameter-sharing trick (vectorized_env.py:32). Narrow float32 rows are
     packed into one table so a minibatch is one gather (``_pack_rows``).
+
+    ``data.rows_sharding`` lays an epoch's ``(num_minibatches,
+    batch_size)`` index array out over a mesh: each device then looks up
+    and differentiates its share of every minibatch's rows, and the
+    partitioner all-reduces the gradient, the advantage moments and the
+    metrics. The permutation and the table stay whole on every device, so
+    the minibatches are the same sets of rows as without it.
     """
     total = data.obs.shape[0]
-    # Clamp for rollouts smaller than batch_size (e.g. num_formation=1):
-    # train on one full-rollout minibatch instead of crashing.
-    batch_size = min(config.batch_size, total)
-    num_minibatches = total // batch_size
+    num_minibatches, batch_size = minibatch_shape(config, total)
     used = num_minibatches * batch_size
 
     ent_decay = config.ent_coef_final is not None
@@ -445,6 +464,10 @@ def ppo_update(
         with jax.named_scope("epoch_shuffle"):
             perm = jax.random.permutation(epoch_key, total)[:used]
             idx = perm.reshape(num_minibatches, batch_size)
+            if data.rows_sharding is not None:
+                idx = jax.lax.with_sharding_constraint(
+                    idx, data.rows_sharding
+                )
         ts, metrics = jax.lax.scan(minibatch_step, ts, idx)
         return ts, jax.tree_util.tree_map(lambda m: m.mean(), metrics)
 

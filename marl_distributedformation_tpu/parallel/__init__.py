@@ -15,6 +15,7 @@ from marl_distributedformation_tpu.parallel.mesh import (  # noqa: F401
     make_dp_step,
     make_mesh,
     make_shard_fn,
+    minibatch_sharding,
     replicate,
     replicated,
     shard_batch,

@@ -4,10 +4,16 @@ The reference has no distributed machinery at all (SURVEY.md §2.1: no
 NCCL/MPI/multi-process anything — "distributed" in its name means
 *decentralized control*). The TPU-native scaling story is therefore designed
 fresh: formations are the data axis, sharded over a ``jax.sharding.Mesh``
-('dp'); parameters are replicated; XLA inserts the gradient ``psum`` over ICI
-because the jitted update consumes dp-sharded minibatches with replicated
-params. An optional 'sp' axis shards the *agent* ring dimension for very
-large swarms (see ``parallel/ring.py``).
+('dp'); parameters are replicated. What the one jitted iteration then does
+on each device: the rollout steps the device's own formations; the rollout
+buffer is all-gathered, and every device builds the update's packed row
+table and draws each epoch's permutation whole (the same random stream as
+on one device); of every minibatch a device looks up and differentiates
+``batch_size / dp`` rows (:func:`minibatch_sharding`), and XLA all-reduces
+the gradient, the advantage moments and the metrics over ICI. Where 'dp'
+does not divide a minibatch's rows every device takes it whole. An optional
+'sp' axis shards the *agent* ring dimension for very large swarms (see
+``parallel/ring.py``).
 
 Works identically on real TPU meshes and on CPU test meshes created with
 ``--xla_force_host_platform_device_count``.
@@ -68,6 +74,18 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
+def minibatch_sharding(
+    mesh: Mesh, batch_size: int
+) -> Optional[NamedSharding]:
+    """The layout of ``ppo_update``'s ``(num_minibatches, batch_size)`` row
+    indices that divides every minibatch over 'dp' ('sp', where the mesh
+    has it, repeats the share), or ``None`` where 'dp' does not divide
+    ``batch_size``: the minibatch then stays whole on every device."""
+    if batch_size % mesh.shape["dp"] != 0:
+        return None
+    return NamedSharding(mesh, P(None, "dp"))
+
+
 def shard_batch(tree: Any, mesh: Mesh) -> Any:
     """Place a pytree whose leaves all carry a leading formation axis."""
     return jax.device_put(tree, formation_sharding(mesh))
@@ -116,9 +134,10 @@ def make_shard_fn(
     """Build the ``shard_fn`` hook ``Trainer`` applies after initialization:
     replicate the train state, shard env state + obs over 'dp'.
 
-    The jitted train iteration then runs SPMD: rollouts and minibatch grads
-    are computed on local formation shards and XLA all-reduces gradients
-    (replicated params + sharded batch => psum over 'dp' on ICI).
+    The jitted train iteration then runs SPMD: each device rolls out its
+    own formations; the update's row table and shuffle are whole on every
+    device, and ``Trainer`` divides each minibatch's rows over 'dp'
+    (:func:`minibatch_sharding`), so XLA all-reduces the gradients over ICI.
     """
     the_mesh = mesh or make_mesh(axis_sizes or {"dp": len(jax.devices())})
     extra_axes = set(the_mesh.shape) - {"dp", "sp"}

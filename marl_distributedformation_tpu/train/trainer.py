@@ -16,6 +16,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 import threading
 import time
 from pathlib import Path
@@ -30,6 +31,7 @@ from marl_distributedformation_tpu.algo import (
     PPOConfig,
     collect_rollout,
     compute_gae,
+    minibatch_shape,
     ppo_update,
 )
 from marl_distributedformation_tpu.chaos.plane import (
@@ -177,12 +179,29 @@ def fill_ent_schedule(
     )
 
 
+def _update_rows(
+    ppo: PPOConfig, env_params: EnvParams, per_formation: bool
+) -> Tuple[PPOConfig, Tuple[int, ...]]:
+    """The update's config and the leading shape of one of its rows."""
+    if not per_formation:
+        return ppo, ()
+    # Minibatch whole formations: rows are (N, ...) blocks so the
+    # centralized critic sees every agent. batch_size stays denominated
+    # in agent-transitions for comparable SGD noise across policies.
+    n = env_params.num_agents
+    return (
+        dataclasses.replace(ppo, batch_size=max(1, ppo.batch_size // n)),
+        (n,),
+    )
+
+
 def make_ppo_iteration(
     env_params: EnvParams,
     ppo: PPOConfig,
     per_formation: bool = False,
     env_step_fn: Any = None,
     scenario_step_fn: Any = None,
+    rows_sharding: Any = None,
 ):
     """Build the functional training iteration: rollout + GAE + all
     minibatch epochs as one pure function
@@ -199,19 +218,12 @@ def make_ppo_iteration(
     schedules and per-formation scenario mixes are pure data, so the
     compiled program never changes (tests/test_scenarios.py pins the
     compile-once contract).
+
+    ``rows_sharding`` goes to ``ppo_update`` on the flat rollout data: how
+    a minibatch's rows are laid out over the trainer's mesh
+    (``Trainer._minibatch_sharding``).
     """
-    if per_formation:
-        # Minibatch whole formations: rows are (N, ...) blocks so the
-        # centralized critic sees every agent. batch_size stays denominated
-        # in agent-transitions for comparable SGD noise across policies.
-        n = env_params.num_agents
-        update_ppo = dataclasses.replace(
-            ppo, batch_size=max(1, ppo.batch_size // n)
-        )
-        row_shape = (n,)
-    else:
-        update_ppo = ppo
-        row_shape = ()
+    update_ppo, row_shape = _update_rows(ppo, env_params, per_formation)
 
     def iteration(
         train_state: TrainState,
@@ -254,6 +266,7 @@ def make_ppo_iteration(
             old_log_probs=batch.log_probs.reshape(-1, *row_shape),
             advantages=advantages.reshape(-1, *row_shape),
             returns=returns.reshape(-1, *row_shape),
+            rows_sharding=rows_sharding,
         )
         with jax.named_scope("ppo_update"):
             train_state, update_metrics = ppo_update(
@@ -619,7 +632,37 @@ class Trainer:
             self.per_formation,
             self._env_step_fn,
             self._scenario_step_fn,
+            self._minibatch_sharding(),
         )
+
+    def _minibatch_sharding(self):
+        """How ``ppo_update`` lays a minibatch's rows out: divided over the
+        mesh's 'dp' axis where there is one and it divides their count,
+        else ``None`` (every device takes the minibatch whole)."""
+        mesh = getattr(self._shard_fn, "mesh", None)
+        if mesh is None or mesh.shape["dp"] == 1:
+            return None
+        from marl_distributedformation_tpu.parallel import (
+            minibatch_sharding,
+        )
+
+        update_ppo, row_shape = _update_rows(
+            self.ppo, self.env_params, self.per_formation
+        )
+        total = self.ppo.n_steps * self.num_envs // math.prod(row_shape)
+        _, batch_size = minibatch_shape(update_ppo, total)
+        sharding = minibatch_sharding(mesh, batch_size)
+        dp = mesh.shape["dp"]
+        print(
+            f"[trainer] minibatches of {batch_size} rows: "
+            + (
+                f"{batch_size // dp} a device over dp={dp}, gradients "
+                "all-reduced"
+                if sharding is not None
+                else f"whole on every device (dp={dp} does not divide them)"
+            )
+        )
+        return sharding
 
     def _build_scenario_samplers(self) -> None:
         """(Re)build the jitted domain-randomization samplers over the

@@ -1,13 +1,30 @@
 """Mesh-sharding tests on the 8-virtual-device CPU mesh (conftest.py)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from flax.training.train_state import TrainState
+from jax.sharding import PartitionSpec as P
 
-from marl_distributedformation_tpu.algo import PPOConfig
+from marl_distributedformation_tpu.algo import (
+    MinibatchData,
+    PPOConfig,
+    ppo_update,
+)
 from marl_distributedformation_tpu.env import EnvParams
-from marl_distributedformation_tpu.parallel import make_mesh, make_shard_fn
+from marl_distributedformation_tpu.models import (
+    MLPActorCritic,
+    distributions,
+)
+from marl_distributedformation_tpu.parallel import (
+    make_mesh,
+    make_shard_fn,
+    minibatch_sharding,
+    replicate,
+)
 from marl_distributedformation_tpu.train import TrainConfig, Trainer
 
 
@@ -26,10 +43,10 @@ def test_make_mesh_shapes():
         make_mesh({"dp": 16})
 
 
-def _trainer(tmp_path, shard_fn=None, num_formations=8):
+def _trainer(tmp_path, shard_fn=None, num_formations=8, batch_size=24):
     return Trainer(
         EnvParams(num_agents=3),
-        ppo=PPOConfig(n_steps=4, batch_size=24, n_epochs=2),
+        ppo=PPOConfig(n_steps=4, batch_size=batch_size, n_epochs=2),
         config=TrainConfig(
             num_formations=num_formations,
             seed=0,
@@ -66,6 +83,24 @@ def test_sharded_training_matches_single_device(tmp_path):
         )
 
 
+def test_sharded_training_matches_single_device_quick(tmp_path):
+    """The slow test's twin at tier-1's price: on dp=4 the dispatch divides
+    every minibatch (6 of 24 rows a device) and still trains the program
+    the single device trains."""
+    t_single = _trainer(tmp_path / "single")
+    t_sharded = _trainer(tmp_path / "sharded", shard_fn=make_shard_fn({"dp": 4}))
+    assert t_single._minibatch_sharding() is None
+    assert t_sharded._minibatch_sharding().spec == P(None, "dp")
+    for _ in range(2):
+        m_single = t_single.run_iteration()
+        m_sharded = t_sharded.run_iteration()
+        for name, rtol in (("reward", 1e-5), ("loss", 1e-3), ("grad_norm", 1e-3)):
+            np.testing.assert_allclose(
+                float(m_single[name]), float(m_sharded[name]), rtol=rtol
+            )
+    _assert_same_train_state(t_sharded.train_state, t_single.train_state)
+
+
 def test_sharded_env_state_placement(tmp_path):
     shard_fn = make_shard_fn({"dp": 8})
     trainer = _trainer(tmp_path, shard_fn=shard_fn, num_formations=16)
@@ -84,6 +119,145 @@ def test_sharded_env_state_placement(tmp_path):
 def test_indivisible_formations_rejected(tmp_path):
     with pytest.raises(ValueError, match="not divisible"):
         _trainer(tmp_path, shard_fn=make_shard_fn({"dp": 8}), num_formations=12)
+
+
+# ---------------------------------------------------------------------------
+# The minibatch divided over 'dp' (parallel.minibatch_sharding, ppo_update)
+# ---------------------------------------------------------------------------
+
+
+def _assert_same_train_state(got, want):
+    """Parameters and Adam's moments agree to float32's tolerance: the
+    divided minibatch changes the order of one sum, nothing else."""
+    assert int(got.step) == int(want.step)
+    for a, b in zip(
+        jax.tree_util.tree_leaves((got.params, got.opt_state)),
+        jax.tree_util.tree_leaves((want.params, want.opt_state)),
+    ):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-6
+        )
+
+
+def _update_case(obs_dim):
+    """A train state and 480 rows of rollout data: 5 minibatches of 96."""
+    config = PPOConfig(batch_size=96, n_epochs=2)
+    model = MLPActorCritic(act_dim=2)
+    k_init, k_obs, k_act, k_adv = jax.random.split(jax.random.PRNGKey(0), 4)
+    obs = jax.random.normal(k_obs, (480, obs_dim))
+    ts = TrainState.create(
+        apply_fn=model.apply,
+        params=model.init(k_init, obs[:1]),
+        tx=config.make_optimizer(),
+    )
+    mean, log_std, values = ts.apply_fn(ts.params, obs)
+    actions = distributions.sample(k_act, mean, log_std)
+    advantages = jax.random.normal(k_adv, (480,))
+    data = MinibatchData(
+        obs=obs,
+        actions=actions,
+        old_log_probs=distributions.log_prob(actions, mean, log_std),
+        advantages=advantages,
+        returns=values + advantages,
+    )
+    return ts, data, config
+
+
+@pytest.mark.parametrize("obs_dim", [8, 160], ids=["packed", "a-leaf"])
+@pytest.mark.parametrize("dp", [2, 4, 8])
+def test_divided_minibatch_equals_whole(dp, obs_dim):
+    """``ppo_update`` with its rows laid out over 'dp' is the update without
+    the layout: same permutation, same rows a minibatch, the sums taken in
+    ``dp`` parts (rows packed into one table, and too wide to pack)."""
+    ts, data, config = _update_case(obs_dim)
+    key = jax.random.PRNGKey(5)
+    mesh = make_mesh({"dp": dp})
+    rows = minibatch_sharding(mesh, config.batch_size)
+    update = jax.jit(lambda *a: ppo_update(*a, config))
+    whole = update(ts, data, key)
+    divided = update(
+        *replicate((ts, data.replace(rows_sharding=rows), key), mesh)
+    )
+    _assert_same_train_state(divided[0], whole[0])
+    assert divided[1].keys() == whole[1].keys()
+    for name, value in whole[1].items():
+        np.testing.assert_allclose(
+            float(divided[1][name]), float(value), rtol=1e-4, atol=1e-6
+        )
+
+
+def _collectives(text, op):
+    """(result shape, op_name) of each ``op`` in a compiled module's text."""
+    return re.findall(
+        rf"= (\S+) {op}(?:-start)?\(.*op_name=\"([^\"]*)\"", text
+    )
+
+
+def test_divided_minibatch_in_the_compiled_dispatch(tmp_path):
+    """On dp=4 the trainer's program looks up 24 / 4 rows a device, meets
+    the gradient in an all-reduce under ``loss_and_grad``, and gathers
+    across devices only what it gathered before: the rollout buffer, for
+    the table every device builds whole (``row_pack``)."""
+    t = _trainer(tmp_path, shard_fn=make_shard_fn({"dp": 4}))
+    text = (
+        t._iteration.lower(t.train_state, t.env_state, t.obs, t.key)
+        .compile()
+        .as_text()
+    )
+    lookups = [
+        shape
+        for shape, name in _collectives(text, "gather")
+        if "minibatch_gather" in name
+    ]
+    assert lookups and all(s.startswith("f32[6,") for s in lookups), lookups
+    assert any(
+        "loss_and_grad" in name for _, name in _collectives(text, "all-reduce")
+    )
+    for _, name in _collectives(text, "all-gather"):
+        assert "ppo_update" not in name or "row_pack" in name, name
+        assert "loss_and_grad" not in name and "subrow_pick" not in name, name
+
+
+@pytest.mark.parametrize(
+    "axes,batch_size,says",
+    [
+        (None, 24, ""),
+        ({"dp": 1}, 24, ""),
+        ({"dp": 8}, 20, "20 rows: whole on every device (dp=8 does not"),
+        ({"dp": 8}, 24, "24 rows: 3 a device over dp=8"),
+    ],
+    ids=["no-mesh", "dp1", "indivisible", "divisible"],
+)
+def test_layout_is_chosen_from_the_mesh_and_the_row_count(
+    tmp_path, monkeypatch, capsys, axes, batch_size, says
+):
+    """No mesh, one device along 'dp', or a minibatch 'dp' does not divide
+    lower to the program without the layout, letter for letter; a minibatch
+    it divides does not. The build log says once which it took."""
+
+    def lowered(tag):
+        t = _trainer(
+            tmp_path / tag,
+            shard_fn=make_shard_fn(axes) if axes else None,
+            batch_size=batch_size,
+        )
+        return t._iteration.lower(
+            t.train_state, t.env_state, t.obs, t.key
+        ).as_text()
+
+    chosen = lowered("chosen")
+    said = capsys.readouterr().out
+    assert said.count("[trainer] minibatches of") == bool(says)
+    assert says in said
+    monkeypatch.setattr(Trainer, "_minibatch_sharding", lambda self: None)
+    without = lowered("without")
+    if "a device" in says:
+        assert chosen != without
+        assert chosen.count("sharding_constraint") > without.count(
+            "sharding_constraint"
+        )
+    else:
+        assert chosen == without
 
 
 # ---------------------------------------------------------------------------
