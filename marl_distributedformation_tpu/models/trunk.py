@@ -10,9 +10,13 @@ index, and a minibatch row is a whole swarm-step (``per_formation``).
 A layer is a token mixer, then RMSNorm and a routed expert layer that is
 told which experts it holds, routes over all of them and computes the part
 its own give, with no token dropped (plus the shared expert, where the
-model has one: whole on every chip). The mixers (``MIXERS``; equations in
-``benchmarks/reference/policy_trunk.py`` and ``policy_trunk_hybrid.py``,
-which the tests hold this module to):
+model has one: whole on every chip); the leading ``first_k_dense_replace``
+layers of a model that has them end in a dense SwiGLU instead. How a
+sublayer reads what the layers carry and writes its part back is the
+residual path's (``RESIDUALS``): a plain sum, or hyper-connections over
+``hc_mult`` streams. The mixers (``MIXERS``; equations in
+``benchmarks/reference/policy_trunk.py``, ``policy_trunk_hybrid.py`` and
+``policy_trunk_mla_hc.py``, which the tests hold this module to):
 
 - ``sparse_gqa`` (Keye-VL-2.0's): grouped-query attention with q/k head
   norms and RoPE over the keys a learned indexer selects (its ``topk``
@@ -23,7 +27,12 @@ which the tests hold this module to):
   convolutions, a per-channel log-decay gate, the delta rule with beta in
   (0, 2) run in chunks over the agent axis (``models/kda.py``), a gated
   head norm. The state runs over the agents of one swarm-step inside one
-  forward pass; nothing is carried over time.
+  forward pass; nothing is carried over time;
+- ``mla`` (Xing4.0's): multi-head latent attention as training computes
+  it, queries and keys and values through low-rank halves with a norm
+  between, a rotary part under YaRN that all heads' keys share,
+  uncompressed keys of ``qk_nope_head_dim + qk_rope_head_dim`` and values of
+  ``v_head_dim`` in ``gated_gqa``'s softmax.
 
 Of a mixer's heads the chip may hold a share (``head_share``: share i of n
 holds ``heads / n`` query heads with their key heads): the projections'
@@ -32,7 +41,8 @@ widths follow the heads held and ``o @ wo`` is the partial sum it is.
 How it is computed here:
 
 - layers run under one ``lax.scan`` over the periods of the layer pattern
-  (a trunk of one kind: over its layers), a swarm at a time with
+  (a trunk of one kind: over its layers; leading dense layers ahead of it,
+  each on its own), a swarm at a time with
   ``jax.checkpoint`` a layer; attention works by blocks of ``q_chunk_size``
   queries against the keys the block can see, each block recomputed in the
   backward pass, so the ``heads x S x S`` scores never exist whole
@@ -86,11 +96,17 @@ HIGHEST = jax.lax.Precision.HIGHEST
 _KEPT = ("trunk_select_threshold", "trunk_select_ties")
 # Query blocks in one key tile of the gated GQA mixer (``_causal_tiles``).
 _GQA_TILE_BLOCKS = 4
+# Tokens of a leading dense layer's SwiGLU at a time (``dense_ffn``).
+_DENSE_TOKENS = 1024
 # Every counter a layer can sow; a trunk sows those its layers have.
 COUNTERS = (
     "moe_held_share", "moe_load_max_over_mean", "indexer_selected_mean",
     "kda_log_decay_mean", "kda_beta_mean",
+    "hc_res_offdiag_mean", "hc_sinkhorn_row_err",
 )
+# How a counter's values over tokens, sublayers and layers become one: a
+# largest value for these, a mean for the others.
+_OVER = {"hc_sinkhorn_row_err": jnp.max}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -127,11 +143,34 @@ class TrunkArch:
     kda_head_dim: int = 0
     kda_conv_size: int = 0
     kda_chunk_size: int = 0
+    # mla
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Tuple[Tuple[str, float], ...] = ()  # YaRN's numbers, by key
+    # the first ``dense_layers`` held layers end in a dense SwiGLU of
+    # ``intermediate_size``, the others in the expert layer
+    dense_layers: int = 0
+    intermediate_size: int = 0
+    # the router: its scores, a selection bias that does not enter the
+    # weights (``noaux_tc``), the weights' scale
+    scoring_func: str = "softmax"
+    router_bias: bool = False
+    routed_scaling_factor: float = 1.0
+    # the residual path (a key of RESIDUALS) and the hyper-connections' numbers
+    residual: str = "plain"
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 0
+    hc_eps: float = 0.0
+    hc_res_clamp: Tuple[float, float] = (0.0, 0.0)
 
     @property
     def period(self) -> Tuple[str, ...]:
-        """The shortest run of kinds that the held layers repeat."""
-        kinds = self.layer_kinds
+        """The shortest run of kinds that the held layers after the leading
+        dense ones repeat."""
+        kinds = self.layer_kinds[self.dense_layers :]
         return next(
             kinds[:p] for p in range(1, len(kinds) + 1)
             if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p)
@@ -143,24 +182,54 @@ class TrunkArch:
 
     @classmethod
     def from_dict(cls, name: str, data: dict) -> "TrunkArch":
-        """Both shapes of file: Keye-VL-2.0's (``sa_config``, ``num_experts``;
-        every layer ``sparse_gqa``) and Solar-Open2's (``gqa_layers``,
-        ``linear_attn_config``, ``n_routed_experts``, ``n_shared_experts``)."""
+        """Three shapes of file: Keye-VL-2.0's (``sa_config``, ``num_experts``;
+        every layer ``sparse_gqa``), Solar-Open2's (``gqa_layers``,
+        ``linear_attn_config``, ``n_routed_experts``, ``n_shared_experts``) and
+        Xing4.0's (``kv_lora_rank``, ``hc_mult``, ``first_k_dense_replace``,
+        ``scoring_func``, ``routed_scaling_factor``; every layer ``mla``, the
+        residual path ``hyper``, held from layer ``first_layer_held`` on)."""
         sa = data.get("sa_config") or {}
         kda = data.get("linear_attn_config") or {}
+        yarn = data.get("rope_scaling") or {}
         hybrid = "gqa_layers" in data
+        mla = "kv_lora_rank" in data
         layers = int(data["layers_held"])
+        first = int(data.get("first_layer_held", 0))
         share, count = (int(v) for v in data["expert_share"])
         head, head_count = (int(v) for v in data.get("head_share", (0, 1)))
+        # what only Xing4.0's shape of file states, and its reference computes
+        own = dict(
+            q_lora_rank=data["q_lora_rank"],
+            kv_lora_rank=data["kv_lora_rank"],
+            qk_nope_head_dim=data["qk_nope_head_dim"],
+            qk_rope_head_dim=data["qk_rope_head_dim"],
+            v_head_dim=data["v_head_dim"],
+            rope_scaling=tuple(sorted(
+                (key, value) for key, value in yarn.items() if key != "type"
+            )),
+            dense_layers=min(max(int(data["first_k_dense_replace"]) - first, 0), layers),
+            intermediate_size=data["intermediate_size"],
+            scoring_func=data.get("scoring_func", "softmax"),
+            router_bias=data.get("topk_method") == "noaux_tc",
+            routed_scaling_factor=float(data.get("routed_scaling_factor", 1)),
+            residual="hyper",
+            hc_mult=int(data["hc_mult"]),
+            hc_sinkhorn_iters=int(data["hc_sinkhorn_iters"]),
+            hc_eps=float(data["hc_eps"]),
+            hc_res_clamp=(
+                float(data["mhc_h_res_clamp_min"]), float(data["mhc_h_res_clamp_max"])
+            ),
+        ) if mla else {}
         arch = cls(
             name=name,
             hidden_size=data["hidden_size"],
             num_attention_heads=data["num_attention_heads"],
             num_key_value_heads=data["num_key_value_heads"],
-            head_dim=data["head_dim"],
+            head_dim=data["qk_nope_head_dim"] + data["qk_rope_head_dim"]
+            if mla else data["head_dim"],
             rms_norm_eps=data["rms_norm_eps"],
             q_chunk_size=(sa if "q_chunk_size" in sa else data)["q_chunk_size"],
-            num_experts=data["n_routed_experts" if hybrid else "num_experts"],
+            num_experts=data["n_routed_experts" if hybrid or mla else "num_experts"],
             num_experts_per_tok=data["num_experts_per_tok"],
             moe_intermediate_size=data["moe_intermediate_size"],
             norm_topk_prob=data["norm_topk_prob"],
@@ -169,7 +238,8 @@ class TrunkArch:
             expert_share=(share, count),
             head_share=(head, head_count),
             layer_kinds=tuple(
-                ("gated_gqa" if i in data["gqa_layers"] else "kda") if hybrid
+                "mla" if mla
+                else ("gated_gqa" if i in data["gqa_layers"] else "kda") if hybrid
                 else "sparse_gqa"
                 for i in range(layers)
             ),
@@ -183,6 +253,7 @@ class TrunkArch:
             kda_head_dim=kda.get("head_dim", 0),
             kda_conv_size=kda.get("short_conv_kernel_size", 0),
             kda_chunk_size=data.get("kda_chunk_size", 0),
+            **own,
         )
         unsupported = {
             "hidden_act": data.get("hidden_act", "silu") != "silu",
@@ -192,13 +263,14 @@ class TrunkArch:
             % arch.num_key_value_heads != 0,
             "expert_share": not 0 <= share < count
             or arch.experts_held * count != arch.num_experts,
-            "layers_held": not 1 <= arch.layers_held <= int(data["num_hidden_layers"]),
+            "layers_held": not 1 <= arch.layers_held
+            <= int(data["num_hidden_layers"]) - first or first < 0,
             "head_share": not 0 <= head < head_count
             or arch.num_key_value_heads % head_count != 0
             or arch.kda_num_heads % head_count != 0,
-            "sa_config": not hybrid and not sa,
+            "sa_config": not hybrid and not mla and not sa,
         }
-        if hybrid:  # what the two new mixers do not compute
+        if hybrid:  # what the two mixers and this shape's reference do not compute
             unsupported.update({
                 "use_rope": bool(data.get("use_rope", False)),
                 "use_gqa_gate": not data.get("use_gqa_gate", False),
@@ -211,6 +283,20 @@ class TrunkArch:
                     "kda" in arch.layer_kinds
                     and not (arch.kda_num_heads and arch.kda_chunk_size > 0)
                 ),
+            })
+        if mla:  # what the latent attention, the router and the streams do not compute
+            unsupported.update({
+                "n_group": int(data.get("n_group", 1)) != 1,
+                "topk_group": int(data.get("topk_group", 1)) != 1,
+                "rope_scaling": yarn.get("type") != "yarn"
+                or yarn.get("mscale") != yarn.get("mscale_all_dim"),
+                "num_nextn_predict_layers": int(data.get("num_nextn_predict_layers", 0)) != 0,
+                "scoring_func": arch.scoring_func not in ("softmax", "sigmoid"),
+                "topk_method": data.get("topk_method", "greedy") not in ("greedy", "noaux_tc"),
+                "moe_layer_freq": int(data.get("moe_layer_freq", 1)) != 1,
+                "num_attention_heads": arch.num_attention_heads != arch.num_key_value_heads,
+                "hc_mult": arch.hc_mult < 1,
+                "hc_sinkhorn_iters": arch.hc_sinkhorn_iters < 1,
             })
         bad = [key for key, wrong in unsupported.items() if wrong]
         if bad:
@@ -243,8 +329,13 @@ def _layer_norm(x: Array, scale: Array, bias: Array, eps: float) -> Array:
 def _rope(x: Array, theta: float) -> Array:
     """Rotate-half RoPE on ``x (S, ..., d)`` at positions 0..S-1 (the
     published ``mrope_section`` with its three position axes equal)."""
+    d = x.shape[-1]
+    return _rotate(x, theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+
+
+def _rotate(x: Array, inv_freq: Array) -> Array:
+    """``x (S, ..., d)`` turned by ``inv_freq (d / 2)`` times its position."""
     s, d = x.shape[0], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     angle = jnp.concatenate([angle, angle], -1).reshape(
         s, *([1] * (x.ndim - 2)), d
@@ -344,13 +435,27 @@ def _attend_block(q: Array, k: Array, v: Array, mask: Array) -> Array:
 # ----------------------------------------------------------------------
 
 
-def route(h2: Array, router: Array, top: int, normalise: bool):
+def route(
+    h2: Array, router: Array, top: int, normalise: bool, scoring: str = "softmax",
+    bias: Optional[Array] = None, scale: float = 1.0,
+):
     """Each token's ``top`` experts of all the router's and the weights
-    they combine with; the logits at float32 ``highest``."""
+    they combine with; the logits at float32 ``highest``. ``scoring`` makes
+    the scores of the logits (``softmax`` over the experts, or ``sigmoid``
+    an expert); ``bias`` is added to them for the selection alone (it gets no
+    gradient: the selection is indices); ``scale`` multiplies the weights."""
     logits = jnp.einsum("sh,he->se", h2, router, precision=HIGHEST)
-    r_top, e_top = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        r_top, e_top = jax.lax.top_k(scores, top)
+    else:
+        _, e_top = jax.lax.top_k(scores + bias, top)
+        r_top = jnp.take_along_axis(scores, e_top, axis=-1)
     c = r_top / r_top.sum(-1, keepdims=True) if normalise else r_top
-    return e_top, c
+    return e_top, c if scale == 1.0 else scale * c
 
 
 def expert_layer(
@@ -397,14 +502,29 @@ def shared_expert(h2: Array, w_in: Array, down: Array) -> Array:
     return (jax.nn.silu(gate) * up) @ down
 
 
+def dense_ffn(h2: Array, w_in: Array, down: Array) -> Array:
+    """A leading dense layer's SwiGLU for one swarm, ``_DENSE_TOKENS`` tokens
+    at a time and each block again in the backward pass: a swarm's ``(S, 2
+    intermediate_size)`` products at once are the largest buffers of the
+    layer (1.1 GiB at 8,192 x 9,216) at the point where its memory peaks."""
+    s = h2.shape[0]
+    block = _DENSE_TOKENS if s % _DENSE_TOKENS == 0 else s
+    out = jax.lax.map(
+        jax.checkpoint(lambda rows: shared_expert(rows, w_in, down)),
+        h2.reshape(s // block, block, -1),
+    )
+    return out.reshape(s, -1)
+
+
 # ----------------------------------------------------------------------
 # The mixers
 # ----------------------------------------------------------------------
 # A mixer's ``shapes(arch)`` names its parameters (initialiser, shape of one
-# layer); its ``mix(x, lp, arch, collect)`` takes one swarm ``x (S, hidden)``
-# and returns ``x`` with its part added, its counters summed over the
-# swarm's tokens (the layer divides by S) and, with ``collect``, what it
-# selected.
+# layer); its ``mix(x, lp, arch, collect)`` takes what the residual path
+# hands one swarm's sublayer, ``x (S, hidden)`` before the norm, and returns
+# its part ``(S, hidden)`` (the layer adds it: ``RESIDUALS``), its counters
+# summed over the swarm's tokens (the layer divides by S) and, with
+# ``collect``, what it selected.
 
 
 def _sliced_normal(key, shape, dtype=jnp.float32):
@@ -489,9 +609,9 @@ def _sparse_gqa(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
             )
         selected = selected + found.sum()
     with jax.named_scope("trunk_attention"):
-        x = x + jnp.concatenate(outs) @ lp["wo"]
+        part = jnp.concatenate(outs) @ lp["wo"]
     collected = {"selected_keys": jnp.concatenate(masks)} if collect else {}
-    return x, {"indexer_selected_mean": selected}, collected
+    return part, {"indexer_selected_mean": selected}, collected
 
 
 def _gated_gqa_shapes(a: TrunkArch):
@@ -534,11 +654,47 @@ def _causal_tiles(s: int, chunk: int):
     ]
 
 
+def _causal_softmax(q: Array, k: Array, v: Array, chunk_size: int, whole_pairs: bool):
+    """Dense causal attention without the keys the mask hides, for ``q (S, kv,
+    group, d)``, ``k (S, kv, d)`` and ``v (S, kv, dv)`` -> ``(S, kv * group *
+    dv)``; the keys' width and the values' may differ. One loop over the
+    (query block, key tile) pairs under the diagonal, every pair of one shape
+    (so one loop body in each pass), and a block's tiles put together by their
+    largest scores and weight sums, as a softmax over all of them. With
+    ``whole_pairs`` a pair takes its slices again in the backward pass: where
+    every query head has a key head of its own a tile of keys and values is
+    too large to keep for each pair."""
+    s = q.shape[0]
+    chunk, tile, pairs = _causal_tiles(s, chunk_size)
+    block_of = jnp.array([i for i, _ in pairs])
+
+    def pair(ij):
+        first, start = ij[0] * chunk, ij[1] * tile
+        visible = (
+            (start + jnp.arange(tile))[None, :] <= (first + jnp.arange(chunk))[:, None]
+        )
+        return _attend_tile(
+            jax.lax.dynamic_slice_in_dim(q, first, chunk),
+            jax.lax.dynamic_slice_in_dim(k, start, tile),
+            jax.lax.dynamic_slice_in_dim(v, start, tile),
+            visible,
+        )
+
+    o, m, l = jax.lax.map(  # (pairs, ...)
+        jax.checkpoint(pair) if whole_pairs else pair, jnp.array(pairs)
+    )
+    blocks = s // chunk
+    largest = jax.ops.segment_max(m, block_of, blocks, indices_are_sorted=True)
+    weight = jnp.exp(m - largest[block_of])  # (pairs, kv, group, T)
+    total = jax.ops.segment_sum(l * weight, block_of, blocks, indices_are_sorted=True)
+    summed = jax.ops.segment_sum(
+        o * jnp.moveaxis(weight, -1, 1)[..., None], block_of, blocks,
+        indices_are_sorted=True,
+    )  # (blocks, T, kv, group, dv)
+    return (summed / jnp.moveaxis(total, -1, 1)[..., None]).reshape(s, -1)
+
+
 def _gated_gqa(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
-    """Dense causal attention without the keys the mask hides: one loop over
-    the (query block, key tile) pairs under the diagonal, every pair of one
-    shape (so one loop body in each pass), and a block's tiles put together
-    by their largest scores and weight sums, as a softmax over all of them."""
     s, hd = x.shape[0], arch.head_dim
     nq, nkv = arch.held(arch.num_attention_heads), arch.held(arch.num_key_value_heads)
     with jax.named_scope("trunk_gated_attention"):
@@ -548,33 +704,9 @@ def _gated_gqa(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
         )
         q = q.reshape(s, nkv, nq // nkv, hd)
         k, v = k.reshape(s, nkv, hd), v.reshape(s, nkv, hd)
-        chunk, tile, pairs = _causal_tiles(s, arch.q_chunk_size)
-        block_of = jnp.array([i for i, _ in pairs])
-
-        def pair(ij):
-            first, start = ij[0] * chunk, ij[1] * tile
-            visible = (
-                (start + jnp.arange(tile))[None, :] <= (first + jnp.arange(chunk))[:, None]
-            )
-            return _attend_tile(
-                jax.lax.dynamic_slice_in_dim(q, first, chunk),
-                jax.lax.dynamic_slice_in_dim(k, start, tile),
-                jax.lax.dynamic_slice_in_dim(v, start, tile),
-                visible,
-            )
-
-        o, m, l = jax.lax.map(pair, jnp.array(pairs))  # (pairs, ...)
-        blocks = s // chunk
-        largest = jax.ops.segment_max(m, block_of, blocks, indices_are_sorted=True)
-        weight = jnp.exp(m - largest[block_of])  # (pairs, kv, group, T)
-        total = jax.ops.segment_sum(l * weight, block_of, blocks, indices_are_sorted=True)
-        summed = jax.ops.segment_sum(
-            o * jnp.moveaxis(weight, -1, 1)[..., None], block_of, blocks,
-            indices_are_sorted=True,
-        )  # (blocks, T, kv, group, d)
-        attended = (summed / jnp.moveaxis(total, -1, 1)[..., None]).reshape(s, -1)
-        x = x + (attended * jax.nn.sigmoid(gate)) @ lp["wo"]
-    return x, {}, {}
+        attended = _causal_softmax(q, k, v, arch.q_chunk_size, whole_pairs=False)
+        part = (attended * jax.nn.sigmoid(gate)) @ lp["wo"]
+    return part, {}, {}
 
 
 def _log_uniform_decay(key, shape, dtype=jnp.float32):
@@ -633,24 +765,212 @@ def _kda(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
             o = chunked_delta_rule(q, k, v, log_decay, beta, arch.kda_chunk_size)
         gate = jax.nn.sigmoid(g @ lp["g_b"]).reshape(s, -1, d)
         o = _rms(o, lp["o_norm"], arch.rms_norm_eps) * gate
-        x = x + o.reshape(s, -1) @ lp["wo"]
+        part = o.reshape(s, -1) @ lp["wo"]
     sums = {
         "kda_log_decay_mean": log_decay.mean((1, 2)).sum(),
         "kda_beta_mean": beta.mean(1).sum(),
     }
-    return x, sums, {}
+    return part, sums, {}
+
+
+def _mla_shapes(a: TrunkArch):
+    h, heads = a.hidden_size, a.held(a.num_attention_heads)
+    return {
+        "attn_norm": (nn.initializers.ones, (h,)),
+        # the low-rank first halves are whole on every chip, the second
+        # halves' widths follow the heads held
+        "wq_a": (_rbg_normal, (h, a.q_lora_rank)),
+        "q_a_norm": (nn.initializers.ones, (a.q_lora_rank,)),
+        # a head [q_nope | q_rope]
+        "wq_b": (_rbg_normal, (a.q_lora_rank, heads * a.head_dim)),
+        "wkv_a": (_rbg_normal, (h, a.kv_lora_rank + a.qk_rope_head_dim)),  # [ckv | k_rope]
+        "kv_a_norm": (nn.initializers.ones, (a.kv_lora_rank,)),
+        # a head [k_nope | v]
+        "wkv_b": (
+            _rbg_normal, (a.kv_lora_rank, heads * (a.qk_nope_head_dim + a.v_head_dim))
+        ),
+        "wo": (_rbg_normal, (heads * a.v_head_dim, h)),
+    }
+
+
+def _yarn(a: TrunkArch) -> Tuple[Array, float]:
+    """The rotary part's ``qk_rope_head_dim / 2`` frequencies under YaRN
+    (pairs that turn more than ``beta_fast`` times over the original context
+    keep theirs, those that turn fewer than ``beta_slow`` times are slowed by
+    ``factor``, a ramp between), and what YaRN multiplies the softmax's scale
+    by: ``mscale`` squared (cos and sin stay unscaled where ``mscale`` equals
+    ``mscale_all_dim``, which ``from_dict`` holds the file to)."""
+    d, yarn = a.qk_rope_head_dim, dict(a.rope_scaling)
+
+    def pair_of(turns):
+        span = yarn["original_max_position_embeddings"] / (2 * math.pi * turns)
+        return d * math.log(span) / (2 * math.log(a.rope_theta))
+
+    low = max(math.floor(pair_of(yarn["beta_fast"])), 0)
+    high = min(math.ceil(pair_of(yarn["beta_slow"])), d - 1)
+    pairs = jnp.arange(d // 2, dtype=jnp.float32)
+    inv_freq = a.rope_theta ** (-2.0 * pairs / d)
+    kept = 1.0 - jnp.clip((pairs - low) / max(high - low, 1e-3), 0.0, 1.0)
+    mscale = 0.1 * yarn["mscale_all_dim"] * math.log(yarn["factor"]) + 1.0
+    return inv_freq * kept + inv_freq / yarn["factor"] * (1.0 - kept), mscale * mscale
+
+
+def _mla(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
+    """Multi-head latent attention as training computes it: queries, keys
+    and values through their low-rank halves, a rotary part that all heads'
+    keys share, uncompressed keys and values in ``gated_gqa``'s softmax (no
+    absorbed products, no cache)."""
+    s, eps = x.shape[0], arch.rms_norm_eps
+    heads, d_nope = arch.held(arch.num_attention_heads), arch.qk_nope_head_dim
+    inv_freq, mscale2 = _yarn(arch)
+    with jax.named_scope("trunk_mla"):
+        h = _rms(x, lp["attn_norm"], eps)
+        q = _rms(h @ lp["wq_a"], lp["q_a_norm"], eps) @ lp["wq_b"]
+        ckv, k_rope = jnp.split(h @ lp["wkv_a"], (arch.kv_lora_rank,), 1)
+        kv = _rms(ckv, lp["kv_a_norm"], eps) @ lp["wkv_b"]
+        q_nope, q_rope = jnp.split(q.reshape(s, heads, -1), (d_nope,), 2)
+        k_nope, v = jnp.split(kv.reshape(s, heads, -1), (d_nope,), 2)
+        # the tile scales its scores by d^-0.5; YaRN's part rides on the queries
+        q = mscale2 * jnp.concatenate([q_nope, _rotate(q_rope, inv_freq)], -1)
+        k_rope = jnp.broadcast_to(
+            _rotate(k_rope, inv_freq)[:, None, :], (s, heads, arch.qk_rope_head_dim)
+        )
+        k = jnp.concatenate([k_nope, k_rope], -1)
+        with jax.named_scope("mla_softmax"):
+            attended = _causal_softmax(
+                q[:, :, None, :], k, v, arch.q_chunk_size, whole_pairs=True
+            )
+        part = attended @ lp["wo"]
+    return part, {}, {}
 
 
 class Mixer(NamedTuple):
     shapes: Callable[[TrunkArch], Dict[str, tuple]]
     mix: Callable
     normal: Callable  # the draw of the layer's matrices
+    scope: str  # the stage its part is added under
 
 
 MIXERS = {
-    "sparse_gqa": Mixer(_sparse_gqa_shapes, _sparse_gqa, _sliced_normal),
-    "gated_gqa": Mixer(_gated_gqa_shapes, _gated_gqa, _rbg_normal),
-    "kda": Mixer(_kda_shapes, _kda, _rbg_normal),
+    "sparse_gqa": Mixer(_sparse_gqa_shapes, _sparse_gqa, _sliced_normal, "trunk_attention"),
+    "gated_gqa": Mixer(_gated_gqa_shapes, _gated_gqa, _rbg_normal, "trunk_gated_attention"),
+    "kda": Mixer(_kda_shapes, _kda, _rbg_normal, "trunk_kda"),
+    "mla": Mixer(_mla_shapes, _mla, _rbg_normal, "trunk_mla"),
+}
+
+
+# ----------------------------------------------------------------------
+# The residual path
+# ----------------------------------------------------------------------
+# What a layer carries from sublayer to sublayer, and how a sublayer reads
+# it and writes to it. ``shapes(arch, normal)`` names the leaves a layer
+# holds for it; ``spread(x, arch)`` makes what the layers carry of the
+# embedding ``(..., S, hidden)`` and ``gather`` what the heads read of it;
+# ``read(x, lp, sublayer, arch)`` gives a swarm's sublayer (``attn`` or
+# ``ffn``) its input ``(S, hidden)``, what its parts are added to, how, and
+# counters; ``add(base, part, how)`` adds a part ``(S, hidden)``.
+#
+# ``plain``: one stream and a sum, ``x = x + F(norm(x))``.
+# ``hyper``: ``n = hc_mult`` streams ``X``, a tuple of ``(S, hidden)``, under
+# manifold-constrained hyper-connections (equations in
+# ``benchmarks/reference/policy_trunk_mla_hc.py``): a sublayer reads the
+# mixture ``h_pre . X`` and writes ``X' = H_res X + h_post (outer) y``, with
+# ``h_pre``, ``h_post`` and the doubly stochastic ``H_res`` made per token
+# from the streams themselves. A stream is an array of its own: stacked
+# ``(n, S, hidden)`` they are one buffer four times the size of every other,
+# which the compiler cannot place in what is left beside the parameters, and
+# ``(S, n, hidden)`` pads a token's ``n`` rows to a tile of 8. The
+# coefficients have the tokens on the lanes, ``(n, S)`` and ``(n, n, S)``.
+
+
+def _hc_bias(key, shape, dtype=jnp.float32):
+    """``[b_pre | b_post | b_res]``: N(0, 1), and 2 more on ``b_res``'s
+    diagonal: ``H_res`` lies near the identity and visibly off it."""
+    n = math.isqrt(shape[-1] + 1) - 1  # the width is n (n + 2)
+    diagonal = jnp.concatenate([jnp.zeros(2 * n, dtype), jnp.eye(n, dtype=dtype).reshape(-1)])
+    return jax.random.normal(key, shape, dtype) + 2.0 * diagonal
+
+
+def _hyper_shapes(a: TrunkArch, normal: Callable):
+    n = a.hc_mult
+    shapes = {}
+    for sublayer in ("attn", "ffn"):
+        shapes.update({
+            # [phi_pre | phi_post | phi_res], a row a number of vec(X[t])
+            f"hc_{sublayer}_phi": (normal, (n * a.hidden_size, n * (n + 2))),
+            f"hc_{sublayer}_alpha": (nn.initializers.constant(0.01), (3,)),
+            f"hc_{sublayer}_b": (_hc_bias, (n * (n + 2),)),
+        })
+    return shapes
+
+
+def _hyper_read(xs: Tuple[Array, ...], lp: Dict[str, Array], sublayer: str, arch: TrunkArch):
+    n, hidden = arch.hc_mult, arch.hidden_size
+    with jax.named_scope("trunk_residual"):
+        phi = lp[f"hc_{sublayer}_phi"].reshape(n, hidden, -1)
+        alpha, b = lp[f"hc_{sublayer}_alpha"], lp[f"hc_{sublayer}_b"][:, None]
+        # u phi for u = rms(vec(X[t])): the product on the streams as they
+        # are, then the token's scale
+        square = sum((x * x).sum(-1) for x in xs) / (n * hidden)
+        scale = jax.lax.rsqrt(square + arch.rms_norm_eps)[:, None]
+        pre, post, res = jnp.split(
+            (sum(x @ phi[i] for i, x in enumerate(xs)) * scale).T, (n, 2 * n), 0
+        )
+        b_pre, b_post, b_res = jnp.split(b, (n, 2 * n), 0)
+        h_pre = jax.nn.sigmoid(alpha[0] * pre + b_pre)  # (n, S)
+        h_post = 2.0 * jax.nn.sigmoid(alpha[1] * post + b_post)
+        with jax.named_scope("hc_sinkhorn"):
+            h_res = jnp.exp(
+                jnp.clip(alpha[2] * res + b_res, *arch.hc_res_clamp)
+            ).reshape(n, n, -1)  # [row, column, token]
+            def step(_, h_res):  # columns, then rows
+                h_res = h_res / (h_res.sum(0, keepdims=True) + arch.hc_eps)
+                return h_res / (h_res.sum(1, keepdims=True) + arch.hc_eps)
+
+            # a loop and not its steps written out: 14,000 of the compiled
+            # program's 39,000 instructions and 16 s of a cold run otherwise
+            # (PERF.md section 6, PR 35); the gradient goes through all of them
+            h_res = jax.lax.fori_loop(0, arch.hc_sinkhorn_iters, step, h_res)
+        mixed = sum(h_pre[i][:, None] * x for i, x in enumerate(xs))
+        base = tuple(
+            sum(h_res[i, j][:, None] * x for j, x in enumerate(xs)) for i in range(n)
+        )
+        diagonal = sum(h_res[i, i] for i in range(n))
+        counters = {
+            "hc_res_offdiag_mean": 1.0 - diagonal.mean() / n,
+            "hc_sinkhorn_row_err": jnp.abs(h_res.sum(1) - 1.0).max(),
+        }
+    return mixed, base, h_post, counters
+
+
+def _hyper_add(base: Tuple[Array, ...], part: Array, h_post: Array):
+    with jax.named_scope("trunk_residual"):
+        return tuple(x + h_post[i][:, None] * part for i, x in enumerate(base))
+
+
+class Residual(NamedTuple):
+    shapes: Callable
+    spread: Callable
+    read: Callable
+    add: Callable
+    gather: Callable
+
+
+RESIDUALS = {
+    "plain": Residual(
+        shapes=lambda arch, normal: {},
+        spread=lambda x, arch: x,
+        read=lambda x, lp, sublayer, arch: (x, x, None, {}),
+        add=lambda base, part, how: base + part,
+        gather=lambda x: x,
+    ),
+    "hyper": Residual(
+        shapes=_hyper_shapes,
+        spread=lambda x, arch: (x,) * arch.hc_mult,  # every stream starts as e_t
+        read=_hyper_read,
+        add=_hyper_add,
+        gather=sum,
+    ),
 }
 
 
@@ -664,6 +984,7 @@ def _moe_shapes(a: TrunkArch, normal: Callable):
     shapes = {
         "moe_norm": (nn.initializers.ones, (h,)),
         "router": (normal, (h, a.num_experts)),
+        **({"router_bias": (nn.initializers.zeros, (a.num_experts,))} if a.router_bias else {}),
         "w_gate": (normal, (a.experts_held, h, f)),
         "w_up": (normal, (a.experts_held, h, f)),
         "w_down": (normal, (a.experts_held, f, h)),
@@ -676,37 +997,63 @@ def _moe_shapes(a: TrunkArch, normal: Callable):
     return shapes
 
 
+def _dense_shapes(a: TrunkArch, normal: Callable):
+    h, f = a.hidden_size, a.intermediate_size
+    return {
+        "dense_norm": (nn.initializers.ones, (h,)),
+        "d_in": (normal, (h, 2 * f)),  # [gate | up]
+        "d_down": (normal, (f, h)),
+    }
+
+
 def trunk_layer(
     x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool = False,
     kind: str = "sparse_gqa",
 ):
-    """One decoder layer of mixer ``kind`` on one swarm ``x (S, hidden)``;
-    also its counters. ``collect`` adds what was selected to them, the key
-    mask ``(S, S)`` of a ``sparse_gqa`` layer and the experts ``(S, top)``:
-    what a small swarm's test compares."""
-    s = x.shape[0]
-    x, sums, collected = MIXERS[kind].mix(x, lp, arch, collect)
+    """One decoder layer of mixer ``kind`` on one swarm, ``x (S, hidden)`` or
+    what the residual path carries in its place; also its counters. The
+    feed-forward sublayer is the dense SwiGLU where ``lp`` holds one and the
+    expert layer otherwise. ``collect`` adds what was selected to the
+    counters, the key mask ``(S, S)`` of a ``sparse_gqa`` layer and the
+    experts ``(S, top)``: what a small swarm's test compares."""
+    s = jax.tree_util.tree_leaves(x)[0].shape[-2]
+    mixer, residual = MIXERS[kind], RESIDUALS[arch.residual]
+    h, x, how, seen = residual.read(x, lp, "attn", arch)
+    part, sums, collected = mixer.mix(h, lp, arch, collect)
+    with jax.named_scope(mixer.scope):
+        x = residual.add(x, part, how)
 
-    with jax.named_scope("trunk_moe"):
-        with jax.named_scope("router"):
-            h2 = _rms(x, lp["moe_norm"], arch.rms_norm_eps)
-            e_top, c = route(
-                h2, lp["router"], arch.num_experts_per_tok, arch.norm_topk_prob
-            )
-        with jax.named_scope("routed_experts"):
-            added, counters = expert_layer(
-                h2, e_top, c, lp["w_gate"], lp["w_up"], lp["w_down"],
-                arch.expert_share,
-            )
-        x = x + added
-        if "s_in" in lp:  # the model has a shared expert
-            with jax.named_scope("shared_expert"):
-                x = x + shared_expert(h2, lp["s_in"], lp["s_down"])
+    h, x, how, seen_ffn = residual.read(x, lp, "ffn", arch)
+    counters, e_top = {}, None
+    if "d_in" in lp:  # a leading dense layer
+        with jax.named_scope("dense_ffn"):
+            h2 = _rms(h, lp["dense_norm"], arch.rms_norm_eps)
+            x = residual.add(x, dense_ffn(h2, lp["d_in"], lp["d_down"]), how)
+    else:
+        with jax.named_scope("trunk_moe"):
+            with jax.named_scope("router"):
+                h2 = _rms(h, lp["moe_norm"], arch.rms_norm_eps)
+                e_top, c = route(
+                    h2, lp["router"], arch.num_experts_per_tok, arch.norm_topk_prob,
+                    arch.scoring_func, lp.get("router_bias"), arch.routed_scaling_factor,
+                )
+            with jax.named_scope("routed_experts"):
+                added, counters = expert_layer(
+                    h2, e_top, c, lp["w_gate"], lp["w_up"], lp["w_down"],
+                    arch.expert_share,
+                )
+            x = residual.add(x, added, how)
+            if "s_in" in lp:  # the model has a shared expert
+                with jax.named_scope("shared_expert"):
+                    x = residual.add(x, shared_expert(h2, lp["s_in"], lp["s_down"]), how)
     for name, total in sums.items():
         counters[name] = total / s
+    for name, value in seen.items():  # of the layer's two sublayers
+        counters[name] = _OVER.get(name, jnp.mean)(jnp.stack([value, seen_ffn[name]]))
     if collect:
         counters.update(collected)
-        counters["selected_experts"] = e_top
+        if e_top is not None:
+            counters["selected_experts"] = e_top
     return x, counters
 
 
@@ -734,14 +1081,20 @@ def _one_draw(key, shapes: Dict[str, tuple]) -> Dict[str, tuple]:
     }
 
 
-def _stack(module: nn.Module, arch: TrunkArch, kind: str, count: int):
-    """The parameters of ``count`` layers of ``kind`` (mixer, then expert
-    layer) as ``module``'s own, stacked on a leading axis."""
+def _stack(module: nn.Module, arch: TrunkArch, kind: str, count: Optional[int]):
+    """The parameters of ``count`` layers of ``kind`` (mixer, what the
+    residual path holds, then the expert layer) as ``module``'s own, stacked
+    on a leading axis; without a ``count``, of one leading dense layer as it
+    is."""
     mixer = MIXERS[kind]
+    feed_forward = _dense_shapes if count is None else _moe_shapes
     shapes = {
-        name: (init, (count, *shape))
-        for name, (init, shape) in
-        {**mixer.shapes(arch), **_moe_shapes(arch, mixer.normal)}.items()
+        name: (init, (*(() if count is None else (count,)), *shape))
+        for name, (init, shape) in {
+            **mixer.shapes(arch),
+            **RESIDUALS[arch.residual].shapes(arch, mixer.normal),
+            **feed_forward(arch, mixer.normal),
+        }.items()
     }
     # (not for ``sparse_gqa``: taking a key here would move its draws)
     if mixer.normal is _rbg_normal and module.is_initializing():
@@ -750,11 +1103,12 @@ def _stack(module: nn.Module, arch: TrunkArch, kind: str, count: int):
 
 
 class _LayerStack(nn.Module):
-    """One layer of the pattern's period, stacked over the periods."""
+    """One layer of the pattern's period, stacked over the periods; or,
+    without ``periods``, one leading dense layer."""
 
     arch: TrunkArch
     kind: str
-    periods: int
+    periods: Optional[int]
 
     @nn.compact
     def __call__(self) -> Dict[str, Array]:
@@ -768,8 +1122,10 @@ _traced_once = jax.jit(trunk_layer, static_argnames=("arch", "collect", "kind"))
 
 
 class TrunkLayers(nn.Module):
-    """The held layers' parameters and the scan over them. A trunk of one
-    kind holds ``<name> (layers, ...)`` and scans over its layers; one whose
+    """The held layers' parameters and the scan over them: first the leading
+    dense layers, each a group ``dense<i>_<kind>/<name>`` run on its own, then
+    the periods of the layer pattern. A trunk of one kind holds ``<name>
+    (layers, ...)`` and scans over its layers; one whose
     layers differ holds ``<i>_<kind>/<name> (periods, ...)`` for the ``i``-th
     layer of its pattern's period and scans over the periods, the body
     running a period's layers in order. Stacked by position and not by
@@ -783,17 +1139,16 @@ class TrunkLayers(nn.Module):
     @nn.compact
     def __call__(self, x: Array) -> Tuple[Array, Dict[str, Array]]:
         a = self.arch
-        period = a.period
-        periods = a.layers_held // len(period)
+        leading, period = a.layer_kinds[: a.dense_layers], a.period
+        periods = (a.layers_held - len(leading)) // len(period)
         uniform = len(period) == 1
-        if uniform:
-            stacked = _stack(self, a, period[0], periods)
-        else:
-            stacked = {
-                name: _LayerStack(a, kind, periods, name=name)()
-                for name, kind in zip(period_names(period), period)
-            }
-        layer = trunk_layer if uniform else _traced_once
+        residual = RESIDUALS[a.residual]
+        layer = trunk_layer if uniform and not leading else _traced_once
+        by_layer = {}  # counter -> its values, a held layer (or the scan's) each
+
+        def note(counters):
+            for counter, value in counters.items():
+                by_layer.setdefault(counter, []).append(value)
 
         def run_layer(x, lp, kind=period[0]):
             # a swarm at a time, so that what a layer holds at once does
@@ -814,8 +1169,32 @@ class TrunkLayers(nn.Module):
                 name: jnp.stack(values).mean(0) for name, values in found.items()
             }
 
+        x = residual.spread(x, a)
+        for i, kind in enumerate(leading):
+            x, counters = run_layer(
+                x, _LayerStack(a, kind, None, name=f"dense{i}_{kind}")(), kind
+            )
+            note(counters)
+        if uniform:
+            stacked = _stack(self, a, period[0], periods)
+        else:
+            stacked = {
+                name: _LayerStack(a, kind, periods, name=name)()
+                for name, kind in zip(period_names(period), period)
+            }
         x, counters = jax.lax.scan(run_layer if uniform else run_period, x, stacked)
-        return x, {name: value.mean() for name, value in counters.items()}
+        note(counters)
+        return residual.gather(x), {
+            name: _over_layers(name, values) for name, values in by_layer.items()
+        }
+
+
+def _over_layers(name: str, values) -> Array:
+    """A counter's values by layer and swarm as one number."""
+    flat = values[0] if len(values) == 1 else jnp.concatenate(
+        [value.reshape(-1) for value in values]
+    )
+    return _OVER.get(name, jnp.mean)(flat)
 
 
 class TrunkActorCritic(nn.Module):

@@ -69,6 +69,11 @@ DEVICE_SCOPES = (
     "trunk_gated_attention",  # gated NoPE GQA: products, blocked softmax, gate
     "trunk_kda",  # a Kimi-Delta layer's mixer whole
     "kda_recurrence",  # under trunk_kda: the chunked delta rule (models/kda.py)
+    "trunk_mla",  # a latent-attention layer's mixer whole
+    "mla_softmax",  # under trunk_mla: the tiles' scores, softmax and o
+    "trunk_residual",  # hyper-connections: coefficients, pre-mix, write-back
+    "hc_sinkhorn",  # under trunk_residual: the 20 normalisations of H_res
+    "dense_ffn",  # a leading dense layer's SwiGLU
 )
 # ``pl.pallas_call(name=...)`` of the two k-NN kernels (ops/knn_pallas.py):
 # N <= 512 fused, larger N streaming. Both keep the substring ``knn``.

@@ -51,29 +51,46 @@ PARENTS = {
     "trunk_gated_attention": ("policy", "loss_and_grad"),
     "trunk_kda": ("policy", "loss_and_grad"),
     "kda_recurrence": ("trunk_kda",),
+    "trunk_mla": ("policy", "loss_and_grad"),
+    "mla_softmax": ("trunk_mla",),
+    # read before each sublayer; written where the sublayer's part is added:
+    # under the mixer's stage, the dense layer's, the expert layer's two
+    "trunk_residual": (
+        "policy", "loss_and_grad", "trunk_mla", "dense_ffn", "trunk_moe", "shared_expert",
+    ),
+    "hc_sinkhorn": ("trunk_residual",),
+    "dense_ffn": ("policy", "loss_and_grad"),
 }
 # In a sparse_gqa trunk, what of these has no tangent (the selection's
 # counting passes, the routing) jax hoists out of the differentiated function,
 # and the hoisted layer loop loses ``loss_and_grad`` from its path; of the
 # hybrid's layers (each kind one jitted function, traced once) the softmax
-# pairs' causal masks, the routing and the KDA chunks' masks are.
+# pairs' causal masks, the routing and the KDA chunks' masks are; of a latent
+# attention layer's, the pairs' masks, the routing, and the loops of the dense
+# layer's blocks and of the residual path's reads.
 HOISTED = {
     "trunk": ("trunk_indexer", "trunk_moe", "routed_experts"),
     "trunk_hybrid": ("trunk_gated_attention", "trunk_moe", "trunk_kda"),
+    "trunk_mla_hc": ("trunk_mla", "trunk_moe", "trunk_residual", "dense_ffn"),
 }
 GNN_ONLY = ("neighbor_gather",)
 # what only a sparse_gqa layer opens, what every trunk layer opens, and what
 # only the hybrid's layers open (models/trunk.py MIXERS)
 SPARSE_ONLY = ("trunk_attention", "trunk_indexer")
-HYBRID_ONLY = ("shared_expert", "trunk_gated_attention", "trunk_kda", "kda_recurrence")
-TRUNK_ONLY = SPARSE_ONLY + ("trunk_moe", "router", "routed_experts") + HYBRID_ONLY
+HYBRID_ONLY = ("trunk_gated_attention", "trunk_kda", "kda_recurrence")
+# what only a trunk of latent attention under the hyper residual opens
+MLA_ONLY = ("trunk_mla", "mla_softmax", "trunk_residual", "hc_sinkhorn", "dense_ffn")
+TRUNK_ONLY = (
+    SPARSE_ONLY + ("trunk_moe", "router", "routed_experts", "shared_expert")
+    + HYBRID_ONLY + MLA_ONLY
+)
 # Rows of 13 floats pack, eight to a 128-lane row of the table, so the
 # sub-row is picked; a formation's rows (8 agents x 21 floats) are over one
 # vreg's lanes and keep the gather a leaf.
 MLP_ONLY = ("row_pack", "subrow_pick")
 
 
-POLICIES = ("mlp", "gnn", "trunk", "trunk_hybrid")
+POLICIES = ("mlp", "gnn", "trunk", "trunk_hybrid", "trunk_mla_hc")
 
 
 def _tiny_trainer(policy, tmp_path, **config):
@@ -90,7 +107,9 @@ def _tiny_trainer(policy, tmp_path, **config):
     if policy == "gnn":
         agents, model = 8, GNNActorCritic(k=3, rounds=2)
     else:  # 16 agents: the second block of 8 queries sees more than topk 8
-        name = {"trunk": "tiny", "trunk_hybrid": "tiny-hybrid"}[policy]
+        name = {
+            "trunk": "tiny", "trunk_hybrid": "tiny-hybrid", "trunk_mla_hc": "tiny-mla-hc",
+        }[policy]
         agents, model = 16, TrunkActorCritic(arch=load_trunk_arch(name), k=3)
     return Trainer(
         EnvParams(num_agents=agents, obs_mode="knn", knn_k=3),
@@ -140,8 +159,9 @@ def test_scope_is_an_exact_path_part_under_its_parent(op_paths, scope, policy):
     absent = {
         "mlp": GNN_ONLY + TRUNK_ONLY,
         "gnn": MLP_ONLY + TRUNK_ONLY,
-        "trunk": MLP_ONLY + GNN_ONLY + HYBRID_ONLY,
-        "trunk_hybrid": MLP_ONLY + GNN_ONLY + SPARSE_ONLY,
+        "trunk": MLP_ONLY + GNN_ONLY + ("shared_expert",) + HYBRID_ONLY + MLA_ONLY,
+        "trunk_hybrid": MLP_ONLY + GNN_ONLY + SPARSE_ONLY + MLA_ONLY,
+        "trunk_mla_hc": MLP_ONLY + GNN_ONLY + SPARSE_ONLY + HYBRID_ONLY,
     }
     if scope in absent[policy]:
         assert not found
@@ -162,7 +182,8 @@ def _minibatch_gathers(text):
 
 
 @pytest.mark.parametrize(
-    "policy,gathers", [("mlp", 1), ("gnn", 5), ("trunk", 5), ("trunk_hybrid", 5)]
+    "policy,gathers",
+    [("mlp", 1), ("gnn", 5), ("trunk", 5), ("trunk_hybrid", 5), ("trunk_mla_hc", 5)],
 )
 def test_a_minibatch_is_one_gather_where_rows_pack(compiled_text, policy, gathers):
     """Packed rows are looked up once a minibatch; a leaf at a time (five
@@ -357,6 +378,11 @@ SCOPE_READERS = {
     "trunk_routed_experts_ms": "routed_experts",
     "trunk_router_ms": "router",
     "trunk_shared_expert_ms": "shared_expert",
+    "trunk_mla_ms": "trunk_mla",
+    "trunk_mla_softmax_ms": "mla_softmax",
+    "trunk_hyper_residual_ms": "trunk_residual",
+    "trunk_hyper_sinkhorn_ms": "hc_sinkhorn",
+    "trunk_dense_ffn_ms": "dense_ffn",
 }
 
 

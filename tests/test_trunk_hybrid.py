@@ -107,7 +107,7 @@ def test_a_mixer_alone_matches_the_reference_forward_and_gradient(params, kind):
     lp = _layer(params, {"kda": "2_kda", "gated_gqa": "0_gated_gqa"}[kind], 1)
     x = jax.random.normal(jax.random.PRNGKey(3), (S, 64))
     weight = jax.random.normal(jax.random.PRNGKey(4), (S, 64))
-    ours = lambda x, lp: trunk.MIXERS[kind].mix(x, lp, _arch(), False)[0] - x  # noqa: E731
+    ours = lambda x, lp: trunk.MIXERS[kind].mix(x, lp, _arch(), False)[0]  # noqa: E731
     theirs = lambda x, lp: reference.MIXERS[kind](x, lp, POLICY)  # noqa: E731
     _close(jax.jit(ours)(x, lp), theirs(x, lp), OUT)
     scalar = lambda f: (lambda x, lp: (weight * f(x, lp)).sum())  # noqa: E731
@@ -137,7 +137,7 @@ def test_gated_attention_puts_a_blocks_key_tiles_together(params, tile_blocks, m
     x = jax.random.normal(jax.random.PRNGKey(21), (32, 64))
     weight = jax.random.normal(jax.random.PRNGKey(22), (32, 64))
     ours = lambda x, lp: (  # noqa: E731
-        weight * (trunk.MIXERS["gated_gqa"].mix(x, lp, _arch(), False)[0] - x)
+        weight * trunk.MIXERS["gated_gqa"].mix(x, lp, _arch(), False)[0]
     ).sum()
     theirs = lambda x, lp: (weight * reference.MIXERS["gated_gqa"](x, lp, POLICY)).sum()  # noqa: E731
     mixer = set(trunk.MIXERS["gated_gqa"].shapes(_arch()))
@@ -311,7 +311,7 @@ def test_head_shares_add_up_to_the_uncut_mixer(kind):
         held = _head_slice(lp, kind, share, 2, arch)
         for name, (_, shape) in trunk.MIXERS[kind].shapes(arch).items():
             assert held[name].shape == shape, name  # the widths follow the heads held
-        part = trunk.MIXERS[kind].mix(x, held, arch, False)[0] - x
+        part = trunk.MIXERS[kind].mix(x, held, arch, False)[0]
         _close(part, reference.MIXERS[kind](x, held, {**POLICY, "head_share": [share, 2]}), OUT)
         assert float(jnp.abs(part - whole).max()) > 0.1 * float(jnp.abs(whole).max())
         total = total + part
