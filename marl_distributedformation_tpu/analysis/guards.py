@@ -40,6 +40,7 @@ from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 import jax
 
 from marl_distributedformation_tpu.obs.ledger import get_ledger, sanitize_key
+from marl_distributedformation_tpu.obs.tracer import get_tracer
 
 
 class RetraceError(RuntimeError):
@@ -161,33 +162,71 @@ _INTROSPECT = threading.local()
 # all happen on the dispatching thread between our call entry and exit,
 # so the innermost active dispatch owns any event that fires.
 _MONITOR = threading.local()
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+# Wraps jax's compile_or_get_cached: a build, or a load from the
+# persistent cache, which the retrieval event and a cache hit then tell.
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
 _MONITOR_EVENTS = {
-    "/jax/core/compile/jaxpr_trace_duration": "trace_seconds",
-    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_seconds",
-    "/jax/core/compile/backend_compile_duration": "compile_seconds",
+    _TRACE: "trace_seconds",
+    _LOWER: "lower_seconds",
+    _BACKEND: "compile_seconds",
+    _CACHE_LOAD: "cache_load_seconds",
 }
+# The same events as counters of the innermost span open on the thread
+# (obs/tracer.py): each lands on one span, so sums over spans count it
+# once. Traces nest in traces and in lowerings (a lowering traces its
+# inner jits), so those two count each instant once, for the outermost
+# event (``Tracer.tally_interval``). ``compile_s`` holds a cache load as
+# jax's backend event does; ``programs`` counts backend events, compiled
+# or loaded, and ``cache_hits`` the loads among them.
+_SPAN_INTERVALS = {_TRACE: "trace_s", _LOWER: "lower_s"}
 _monitor_installed = False
 
 
-def _on_compile_event(event: str, duration: float, **_: Any) -> None:
-    stack = getattr(_MONITOR, "stack", None)
-    if not stack:
-        return
+def _on_compile_duration(event: str, duration: float, **_: Any) -> None:
     field = _MONITOR_EVENTS.get(event)
-    if field is not None:
+    if field is None:
+        return
+    if event == _CACHE_LOAD:
+        get_tracer().tally("cache_load_s", float(duration))
+    stack = getattr(_MONITOR, "stack", None)
+    if stack:
         sink = stack[-1]
         sink[field] = sink.get(field, 0.0) + float(duration)
 
 
-def _install_monitor() -> None:
+def _on_compile_span(event: str, start: float, end: float, **_: Any) -> None:
+    if event == _BACKEND:
+        tracer = get_tracer()
+        tracer.tally("compile_s", end - start)
+        tracer.tally("programs")
+    elif event in _SPAN_INTERVALS:
+        get_tracer().tally_interval(_SPAN_INTERVALS[event], start, end)
+
+
+def _on_cache_event(event: str, **_: Any) -> None:
+    if event == _CACHE_HIT:
+        get_tracer().tally("cache_hits")
+
+
+def install_compile_listener() -> None:
+    """Register the process's one listener for ``jax.monitoring``'s
+    compile events (idempotent), in its three forms (durations, time
+    spans, bare events): it feeds the ledger's per-dispatch sink and the
+    innermost open span of the tracer."""
     global _monitor_installed
     if _monitor_installed:
         return
     _monitor_installed = True  # one attempt only, even on failure
     try:
         jax.monitoring.register_event_duration_secs_listener(
-            _on_compile_event
+            _on_compile_duration
         )
+        jax.monitoring.register_event_time_span_listener(_on_compile_span)
+        jax.monitoring.register_event_listener(_on_cache_event)
     except Exception:  # noqa: BLE001 — attribution is best-effort
         pass
 
@@ -371,7 +410,7 @@ class LedgerDispatch:
         self._registered = 0
         self._traces = 0
         self._register_lock = threading.Lock()
-        _install_monitor()
+        install_compile_listener()
 
     # jit surface passthrough (.lower(), ._cache_size(), ...): callers
     # that treated the wrapped object as a jitted function keep working.

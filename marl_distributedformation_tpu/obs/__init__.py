@@ -82,6 +82,7 @@ from marl_distributedformation_tpu.obs.tracer import (  # noqa: F401
     get_tracer,
     new_trace_id,
     sanitize_trace_id,
+    self_seconds,
     set_tracer,
 )
 
@@ -115,6 +116,7 @@ __all__ = [
     "set_ledger",
     "prometheus_exposition",
     "sanitize_trace_id",
+    "self_seconds",
     "set_registry",
     "set_tracer",
     "wants_prometheus",

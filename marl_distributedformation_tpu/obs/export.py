@@ -6,9 +6,11 @@ Two read-side formats over the same data:
   a ``Tracer.dump`` file's ``records``) into the Chrome trace-event
   format Perfetto and ``chrome://tracing`` load — one lane (tid) per
   recording thread, complete-events (``ph: "X"``) for spans, instants
-  (``ph: "i"``) for events, trace IDs and attrs in ``args``. Timestamps
-  are epoch microseconds, so a file produced here merges cleanly
-  alongside ``TraceWindow``'s XLA captures in the same viewer session.
+  (``ph: "i"``) for events, trace IDs, attrs and a span's counters in
+  ``args``. Timestamps are epoch microseconds: not the clock of a
+  ``jax.profiler`` capture, which lies a process-dependent offset away
+  (``benchmarks/program_spans.py`` ``clock_offset`` measures it from the
+  spans found in both).
 - :func:`prometheus_exposition` renders any flat ``{name: float}``
   snapshot (the shape every metrics object in this repo already emits)
   as Prometheus text format 0.0.4: ``# TYPE`` lines, ``_total`` keys as
@@ -49,7 +51,7 @@ def chrome_trace(
             name = str(rec["name"])
             tid = lane(str(rec.get("thread", "main")))
             ts = float(rec["t0"]) * 1e6
-            args = dict(rec.get("attrs") or {})
+            args = {**(rec.get("attrs") or {}), **(rec.get("counters") or {})}
             if rec.get("trace_id"):
                 args["trace_id"] = rec["trace_id"]
             if rec.get("kind") == "span":
@@ -142,7 +144,7 @@ _PROGRAM_KEY = re.compile(
     r"^program_(.+)_("
     r"flops|bytes_accessed|argument_bytes|output_bytes|temp_bytes|"
     r"alias_bytes|generated_code_bytes|trace_seconds|lower_seconds|"
-    r"compile_seconds|first_dispatch_seconds|traces_total|"
+    r"compile_seconds|cache_load_seconds|first_dispatch_seconds|traces_total|"
     r"dispatches_total|dispatch_seconds_(?:p(?:50|95|99)|count|sum)"
     r")$"
 )

@@ -14,8 +14,9 @@ registers its executable at lowering time, with
   alias/generated-code bytes (present-or-explicitly-unavailable: the
   record says which analysis source produced them, or why none could);
 - **build timings** — trace / MLIR-lowering / backend-compile wall
-  seconds (attributed per program via ``jax.monitoring`` events) plus
-  the first-dispatch wall;
+  seconds (attributed per program via ``jax.monitoring`` events; the
+  backend's seconds hold a load from the persistent cache, whose own
+  seconds are ``cache_load_seconds``) plus the first-dispatch wall;
 - **live dispatch-latency histograms** per program, recorded at the
   existing host dispatch seams (the same per-thread-sharded reservoir
   machinery as the MetricsRegistry — this ledger owns a private one);
@@ -78,6 +79,7 @@ TIMING_FIELDS = (
     "trace_seconds",
     "lower_seconds",
     "compile_seconds",
+    "cache_load_seconds",
     "first_dispatch_seconds",
 )
 # How the cost/memory facts were obtained. "executable": claimed from

@@ -32,6 +32,15 @@ anchor pair so exporters can place spans on the wall clock (and so a
 span can be back-dated to a file mtime, e.g. the pipeline's
 ``stream_poll`` stage).
 
+**Parents and counters.** Each thread keeps a stack of the spans open on
+it: a span records the innermost one open when it started as its
+``parent`` (by ``id``), so a layer's self time is its duration less its
+children's (:func:`self_seconds`). :meth:`Tracer.tally` and
+:meth:`Tracer.tally_interval` add to a counter of the innermost open
+span, so a count lands on the boundary where the work happened (the
+compile listener in ``analysis/guards.py`` counts each compile event
+there, once).
+
 The **process-global registry** is the default tracer: ``get_tracer()``
 returns it, ``configure(...)`` re-shapes it in place (enabled flag, ring
 size, flight-recorder attachment), and every instrumented subsystem
@@ -43,6 +52,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import json
 import threading
 import time
@@ -85,13 +95,24 @@ def sanitize_trace_id(raw: Optional[str]) -> Optional[str]:
 @dataclasses.dataclass
 class Span:
     """One closed interval on one thread. ``t0``/``t1`` are monotonic
-    (``perf_counter``); exporters convert via the tracer's anchor."""
+    (``perf_counter``); exporters convert via the tracer's anchor.
+    ``parent`` is the ``span_id`` of the span that was innermost open on
+    the thread when this one started; ``counters`` are what
+    :meth:`Tracer.tally` and :meth:`Tracer.tally_interval` added while
+    this span was the innermost."""
 
     name: str
     t0: float
     t1: float
     trace_id: Optional[str] = None
     attrs: Optional[Dict[str, Any]] = None
+    span_id: Optional[int] = None
+    parent: Optional[int] = None
+    counters: Optional[Dict[str, float]] = None
+    # the outermost intervals tallied while open (Tracer.tally_interval)
+    intervals: Optional[List[Tuple[float, float, str]]] = dataclasses.field(
+        default=None, repr=False
+    )
 
     kind = "span"
 
@@ -140,6 +161,8 @@ class Tracer:
         # after a worker death exists to read exactly that history.
         # Bounded: at most maxlen dead rings of ring_size records each.
         self._retired: deque = deque(maxlen=8)  # graftlock: guarded-by=_rings_lock
+        # Span identities; next() on a count is atomic under the GIL.
+        self._ids = itertools.count(1)
         # Epoch<->monotonic anchor, sampled together at construction.
         self.epoch_anchor = time.time()
         self.mono_anchor = time.perf_counter()
@@ -171,22 +194,78 @@ class Tracer:
             return ring
         return prev
 
+    def _open(self) -> List[Span]:
+        """This thread's stack of open spans, innermost last."""
+        stack = getattr(self._local, "open", None)
+        if stack is None:
+            stack = self._local.open = []
+        return stack
+
     @contextlib.contextmanager
     def span(
         self, name: str, trace_id: Optional[str] = None, **attrs: Any
     ) -> Iterator[None]:
-        """Record the wall time of the ``with`` body as one span.
-        Disabled tracers yield immediately — the body runs either way."""
+        """Record the wall time of the ``with`` body as one span, the
+        child of the span innermost open on this thread. Disabled
+        tracers yield immediately — the body runs either way."""
         if not self.enabled:
             yield
             return
-        t0 = time.perf_counter()
+        stack = self._open()
+        record = Span(
+            name,
+            time.perf_counter(),
+            0.0,
+            trace_id,
+            attrs or None,
+            next(self._ids),
+            stack[-1].span_id if stack else None,
+        )
+        stack.append(record)
         try:
             yield
         finally:
-            self._ring().append(
-                Span(name, t0, time.perf_counter(), trace_id, attrs or None)
-            )
+            record.t1 = time.perf_counter()
+            record.intervals = None
+            stack.pop()
+            self._ring().append(record)
+
+    def _innermost(self) -> Optional[Span]:
+        """The span innermost open on this thread, its counters made;
+        None where none is open (a disabled tracer opens none)."""
+        stack = getattr(self._local, "open", None)
+        if not stack:
+            return None
+        span = stack[-1]
+        if span.counters is None:
+            span.counters = {}
+        return span
+
+    def tally(self, key: str, amount: float = 1.0) -> None:
+        """Add ``amount`` to counter ``key`` of the span innermost open
+        on this thread; nothing where none is open."""
+        span = self._innermost()
+        if span is not None:
+            span.counters[key] = span.counters.get(key, 0) + amount
+
+    def tally_interval(self, key: str, start: float, end: float) -> None:
+        """Add ``end - start`` to counter ``key`` of the span innermost
+        open on this thread, counting each instant once. Intervals on one
+        thread nest or are disjoint and arrive as they end, inner first:
+        one that encloses intervals already tallied on the span takes
+        their time over from their counters, so an instant counts for the
+        outermost interval that holds it."""
+        span = self._innermost()
+        if span is None:
+            return
+        if span.intervals is None:
+            span.intervals = []
+        counters, held = span.counters, span.intervals
+        while held and held[-1][0] >= start:
+            inner_start, inner_end, inner_key = held.pop()
+            counters[inner_key] -= inner_end - inner_start
+        counters[key] = counters.get(key, 0) + (end - start)
+        held.append((start, end, key))
 
     def add_span(
         self,
@@ -201,7 +280,9 @@ class Tracer:
         on-disk wait back-dated to its mtime (``epoch_to_mono`` converts)."""
         if not self.enabled:
             return
-        self._ring().append(Span(name, t0, t1, trace_id, attrs or None))
+        self._ring().append(
+            Span(name, t0, t1, trace_id, attrs or None, next(self._ids))
+        )
 
     def event(
         self, name: str, trace_id: Optional[str] = None, **attrs: Any
@@ -256,7 +337,11 @@ class Tracer:
                         "t0": self.mono_to_epoch(r.t0),
                         "t1": self.mono_to_epoch(r.t1),
                         "duration_s": r.t1 - r.t0,
+                        "id": r.span_id,
+                        "parent": r.parent,
                     }
+                    if r.counters:
+                        rec["counters"] = dict(r.counters)
                 else:
                     rec = {
                         "kind": "event",
@@ -289,6 +374,17 @@ class Tracer:
         tmp.write_text(json.dumps(payload))
         tmp.replace(path)
         return path
+
+
+def self_seconds(records: List[dict]) -> Dict[int, float]:
+    """``{span id: self time}`` over snapshot records: a span's duration
+    less the durations of the spans whose ``parent`` it is."""
+    spans = [r for r in records if r.get("kind") == "span"]
+    own = {r["id"]: r["duration_s"] for r in spans}
+    for r in spans:
+        if r.get("parent") in own:
+            own[r["parent"]] -= r["duration_s"]
+    return own
 
 
 # ----------------------------------------------------------------------

@@ -363,20 +363,22 @@ class Trainer:
             )
         else:
             dummy_obs = jnp.zeros((1, env_params.obs_dim), jnp.float32)
-        # jitted: only the parameters leave it, so the forward pass that
-        # flax's init traces is dead code and never compiled or run
-        params = jax.jit(self.model.init)(k_init, dummy_obs)
-        # lr backoff needs the rate IN the optimizer state (pure data,
-        # no recompile on a rollback) — inject only when the knob is
-        # live so the default opt-state layout (and its checkpoints)
-        # stays bit-identical.
-        self.train_state = TrainState.create(
-            apply_fn=self.model.apply,
-            params=params,
-            tx=ppo.make_optimizer(
-                inject_lr=config.recovery_lr_backoff != 1.0
-            ),
-        )
+        with profiling.host_span("setup_param_init"):
+            # jitted: only the parameters leave it, so the forward pass
+            # that flax's init traces is dead code and never compiled or
+            # run
+            params = jax.jit(self.model.init)(k_init, dummy_obs)
+            # lr backoff needs the rate IN the optimizer state (pure
+            # data, no recompile on a rollback) — inject only when the
+            # knob is live so the default opt-state layout (and its
+            # checkpoints) stays bit-identical.
+            self.train_state = TrainState.create(
+                apply_fn=self.model.apply,
+                params=params,
+                tx=ppo.make_optimizer(
+                    inject_lr=config.recovery_lr_backoff != 1.0
+                ),
+            )
 
         self._shard_fn = shard_fn
         # Agent-axis ('sp') sharding: swap the vmapped env step for the
@@ -411,38 +413,41 @@ class Trainer:
 
             self._env_step_fn = make_dp_step(env_params, mesh)
         self._multihost = jax.process_count() > 1
-        if self._multihost:
-            # Multi-host: every process builds only its own formation shard
-            # (parallel/distributed.py) — device_put onto a global mesh from
-            # full host arrays is not possible across processes.
-            assert shard_fn is not None and getattr(
-                shard_fn, "mesh", None
-            ), "multi-host training needs a mesh (cfg.mesh / make_shard_fn)"
-            from marl_distributedformation_tpu.parallel import (
-                replicate,
-                reset_batch_sharded,
-            )
-
-            mesh = shard_fn.mesh
-            self.env_state = reset_batch_sharded(
-                k_env, env_params, config.num_formations, mesh
-            )
-            self.obs = jax.jit(
-                functools.partial(compute_obs, params=env_params)
-            )(self.env_state.agents, self.env_state.goal)
-            self.train_state = replicate(self.train_state, mesh)
-        else:
-            self.env_state = self.env_spec.reset_batch(
-                k_env, env_params, config.num_formations
-            )
-            # The spec's obs is shape-generic over the leading formation
-            # axis and routes knn obs through the batched (Pallas-capable)
-            # search — for formation these ARE reset_batch/compute_obs.
-            self.obs = self.env_spec.obs(self.env_state, env_params)
-            if shard_fn is not None:
-                self.train_state, self.env_state, self.obs = shard_fn(
-                    self.train_state, self.env_state, self.obs
+        with profiling.host_span("setup_env_reset"):
+            if self._multihost:
+                # Multi-host: every process builds only its own formation
+                # shard (parallel/distributed.py) — device_put onto a
+                # global mesh from full host arrays is not possible across
+                # processes.
+                assert shard_fn is not None and getattr(
+                    shard_fn, "mesh", None
+                ), "multi-host training needs a mesh (cfg.mesh / make_shard_fn)"
+                from marl_distributedformation_tpu.parallel import (
+                    replicate,
+                    reset_batch_sharded,
                 )
+
+                mesh = shard_fn.mesh
+                self.env_state = reset_batch_sharded(
+                    k_env, env_params, config.num_formations, mesh
+                )
+                self.obs = jax.jit(
+                    functools.partial(compute_obs, params=env_params)
+                )(self.env_state.agents, self.env_state.goal)
+                self.train_state = replicate(self.train_state, mesh)
+            else:
+                self.env_state = self.env_spec.reset_batch(
+                    k_env, env_params, config.num_formations
+                )
+                # The spec's obs is shape-generic over the leading
+                # formation axis and routes knn obs through the batched
+                # (Pallas-capable) search — for formation these ARE
+                # reset_batch/compute_obs.
+                self.obs = self.env_spec.obs(self.env_state, env_params)
+                if shard_fn is not None:
+                    self.train_state, self.env_state, self.obs = shard_fn(
+                        self.train_state, self.env_state, self.obs
+                    )
 
         # Scenario training (scenarios/, docs/scenarios.md): env stepping
         # routes through the disturbance stack and the iteration takes the
@@ -878,10 +883,11 @@ class Trainer:
                 extra = (self._next_scenario_chunk(rollouts),)
             else:
                 extra = (self.scenario_params,)
-            # On the profiler's own clock (a relaxed atomic load while no
-            # trace is open): the dispatch index is the identifier every
-            # later span of this chunk shares.
-            with jax.profiler.StepTraceAnnotation(
+            # In the tracer's ring and, while a trace is open, on the
+            # profiler's clock: the dispatch index is the identifier every
+            # later span of this chunk shares. Dispatch 0 compiles or
+            # loads the program: its counters say which.
+            with profiling.host_span(
                 "train_dispatch", step_num=self._dispatches
             ):
                 (
@@ -1160,9 +1166,7 @@ class Trainer:
         Called after the NEXT chunk has been dispatched, so this blocks on
         the finished chunk while the device already runs the new one."""
         t_drain = time.perf_counter()
-        with jax.profiler.TraceAnnotation(
-            "train_drain", chunk=first_iteration
-        ):
+        with profiling.host_span("train_drain", chunk=first_iteration):
             host = jax.device_get(stacked)
         meter.tick(
             self._fused_chunk * self.ppo.n_steps * self.config.num_formations

@@ -44,5 +44,4 @@ from marl_distributedformation_tpu.utils.checkpoint import (  # noqa: F401
 from marl_distributedformation_tpu.utils.logging import MetricsLogger  # noqa: F401
 from marl_distributedformation_tpu.utils.profiling import (  # noqa: F401
     Throughput,
-    trace,
 )

@@ -7,8 +7,9 @@ flag (train/trainer.py): the trainer captures a trace of a few post-warmup
 iterations into ``{log_dir}/profile/`` and the jitted iteration is
 ``jax.named_scope``-annotated (``DEVICE_SCOPES``) so the trace viewer
 attributes device time to pipeline stages, while the trainer's dispatch
-and drain seams carry ``jax.profiler`` annotations (``HOST_SPANS``) on
-the same clock.
+and drain seams and its set-up carry ``host_span``s (``HOST_SPANS``):
+``jax.profiler`` annotations on the same clock, and spans of the obs
+spine that record their parents and the compile events inside them.
 """
 
 from __future__ import annotations
@@ -16,9 +17,11 @@ from __future__ import annotations
 import collections
 import contextlib
 import time
-from typing import Iterator, Optional
+from typing import Any, Iterator, Optional
 
 import jax
+
+from marl_distributedformation_tpu.obs.tracer import get_tracer
 
 # Runtime tracing guards (the dynamic half of graftlint — see
 # analysis/guards.py and docs/static_analysis.md): re-exported here so
@@ -30,6 +33,7 @@ from marl_distributedformation_tpu.analysis.guards import (  # noqa: F401
     RetraceError,
     RetraceGuard,
     device_memory_bytes,
+    install_compile_listener,
     ledgered_jit,
     nan_guard,
     no_host_transfers,
@@ -78,9 +82,40 @@ DEVICE_SCOPES = (
 # ``pl.pallas_call(name=...)`` of the two k-NN kernels (ops/knn_pallas.py):
 # N <= 512 fused, larger N streaming. Both keep the substring ``knn``.
 KERNEL_NAMES = ("knn_fused", "knn_streaming")
-# ``jax.profiler`` annotations on the trainer's host seams
-# (train/trainer.py ``_dispatch`` and ``_drain_chunk``).
-HOST_SPANS = ("train_dispatch", "train_drain")
+# ``host_span`` names: the trainer's host seams (train/trainer.py
+# ``_dispatch`` and ``_drain_chunk``) and set-up (``train.build_trainer``
+# whole; ``Trainer.__init__``'s parameter draw and environment reset).
+HOST_SPANS = (
+    "train_dispatch",
+    "train_drain",
+    "setup_build_trainer",
+    "setup_param_init",
+    "setup_env_reset",
+)
+
+
+@contextlib.contextmanager
+def host_span(
+    name: str, step_num: Optional[int] = None, **attrs: Any
+) -> Iterator[None]:
+    """One host span, in two records of the same name: a span of the
+    process's tracer (``obs.get_tracer``), the child of the span innermost
+    open on the thread, whose counters take the compile events that fire
+    inside it (``analysis.guards.install_compile_listener``); and a
+    ``jax.profiler`` annotation (``StepTraceAnnotation`` where a
+    ``step_num`` is given), on the profiler's clock while a session is
+    open. The tracer's ``enabled`` switch (``obs_trace``) turns off the
+    first alone."""
+    install_compile_listener()
+    if step_num is None:
+        annotation = jax.profiler.TraceAnnotation(name, **attrs)
+    else:
+        annotation = jax.profiler.StepTraceAnnotation(
+            name, step_num=step_num, **attrs
+        )
+        attrs = {"step_num": step_num, **attrs}
+    with get_tracer().span(name, **attrs), annotation:
+        yield
 
 
 class TraceWindow:
@@ -211,19 +246,6 @@ class TraceWindow:
             jax.profiler.stop_trace()
             self.active = False
             self._audit_line(completed=False)
-
-
-@contextlib.contextmanager
-def trace(log_dir: Optional[str]) -> Iterator[None]:
-    """Capture a ``jax.profiler`` trace into ``log_dir`` (no-op if None)."""
-    if log_dir is None:
-        yield
-        return
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
 
 
 class Throughput:

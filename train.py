@@ -27,6 +27,7 @@ from marl_distributedformation_tpu.utils import (
     scenario_schedule_from_config,
     setup_platform,
 )
+from marl_distributedformation_tpu.utils.profiling import host_span
 
 
 def ppo_from_config(cfg) -> PPOConfig:
@@ -201,6 +202,13 @@ def shard_fn_from_config(cfg):
 
 
 def build_trainer(cfg) -> Trainer:
+    """The trainer ``cfg`` asks for, whatever its kind, built under the
+    ``setup_build_trainer`` span (``utils.profiling.host_span``)."""
+    with host_span("setup_build_trainer"):
+        return _build_trainer(cfg)
+
+
+def _build_trainer(cfg) -> Trainer:
     if cfg.backend != "jax":
         raise SystemExit(
             f"backend={cfg.backend!r} is not available in this repo; the "
