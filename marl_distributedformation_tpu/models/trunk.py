@@ -47,7 +47,10 @@ How it is computed here:
   queries against the keys the block can see, each block recomputed in the
   backward pass, so the ``heads x S x S`` scores never exist whole
   (``gated_gqa``: by pairs of a query block and a tile of keys under the
-  diagonal, all of one shape, a block's tiles put together afterwards);
+  diagonal, all of one shape, a block's tiles put together afterwards). On
+  the chip ``sparse_gqa``'s blocks select their keys alone and one Pallas
+  kernel attends over the swarm under their mask, its score tiles in VMEM
+  forward and backward (``ops/sparse_attention.py``);
 - a mixer's projections that read the normalised input are one product
   with one parameter leaf, the matrices side by side (``w_in`` of
   ``gated_gqa`` and ``kda``, ``s_in`` of the shared expert);
@@ -88,6 +91,7 @@ from marl_distributedformation_tpu.models.common import (
     PooledValueHead,
 )
 from marl_distributedformation_tpu.models.kda import chunked_delta_rule, short_conv
+from marl_distributedformation_tpu.ops.sparse_attention import sparse_flash_attention
 
 Array = jax.Array
 
@@ -420,6 +424,13 @@ def _query_groups(s: int, chunk: int, topk: int):
 # ----------------------------------------------------------------------
 
 
+def _flash() -> Optional[Callable]:
+    """Attention over the selected keys as one Pallas kernel over the swarm
+    (``ops/sparse_attention.py``), where the chip runs it; elsewhere None,
+    and each block goes through ``_attend_block``."""
+    return sparse_flash_attention if jax.default_backend() == "tpu" else None
+
+
 @jax.checkpoint
 def _attend_block(q: Array, k: Array, v: Array, mask: Array) -> Array:
     """``q (T, kv, group, d)``, ``k, v (S, kv, d)``, ``mask (T, S)`` ->
@@ -586,31 +597,44 @@ def _sparse_gqa(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
         )
         w = h @ lp["idx_w"]
 
+    flash = _flash()
+    keep_masks = collect or flash is not None
     outs, masks, selected = [], [], 0.0
     for start, end, chunk in _query_groups(s, arch.q_chunk_size, arch.topk):
 
         def block(i, start=start, end=end, chunk=chunk):
-            """Queries ``start + i * chunk ...`` against keys ``0 .. end``."""
+            """Queries ``start + i * chunk ...`` against keys ``0 .. end``: the
+            block's attention (not with the kernel), its mask where the kernel
+            or ``collect`` wants it, and how many keys it selected."""
             first = start + i * chunk
             rows = lambda a: jax.lax.dynamic_slice_in_dim(a, first, chunk)  # noqa: E731
             with jax.named_scope("trunk_indexer"):
                 mask = _select_block(
                     rows(qi), ki[:end], rows(w), first, arch.topk, end <= arch.topk
                 )
-            with jax.named_scope("trunk_attention"):
-                out = _attend_block(rows(q), k[:end], v[:end], mask)
-            return out, (mask if collect else mask.sum())
+            out = None
+            if flash is None:
+                with jax.named_scope("trunk_attention"):
+                    out = _attend_block(rows(q), k[:end], v[:end], mask)
+            return out, (mask if keep_masks else None), mask.sum()
 
-        out, found = jax.lax.map(block, jnp.arange((end - start) // chunk))
-        outs.append(out.reshape(end - start, -1))
-        if collect:
+        out, kept, found = jax.lax.map(block, jnp.arange((end - start) // chunk))
+        if flash is None:
+            outs.append(out.reshape(end - start, -1))
+        if keep_masks:
             masks.append(
-                jnp.pad(found.reshape(end - start, end), ((0, 0), (0, s - end)))
+                jnp.pad(kept.reshape(end - start, end), ((0, 0), (0, s - end)))
             )
         selected = selected + found.sum()
+    with jax.named_scope("trunk_indexer"):
+        mask = jnp.concatenate(masks) if keep_masks else None  # (S, S)
     with jax.named_scope("trunk_attention"):
-        part = jnp.concatenate(outs) @ lp["wo"]
-    collected = {"selected_keys": jnp.concatenate(masks)} if collect else {}
+        if flash is None:
+            attended = jnp.concatenate(outs)
+        else:  # the swarm at once; no mask where every visible key is selected
+            attended = flash(q, k, v, mask if s > arch.topk else None)
+        part = attended @ lp["wo"]
+    collected = {"selected_keys": mask} if collect else {}
     return part, {"indexer_selected_mean": selected}, collected
 
 

@@ -80,8 +80,11 @@ DEVICE_SCOPES = (
     "dense_ffn",  # a leading dense layer's SwiGLU
 )
 # ``pl.pallas_call(name=...)`` of the two k-NN kernels (ops/knn_pallas.py):
-# N <= 512 fused, larger N streaming. Both keep the substring ``knn``.
-KERNEL_NAMES = ("knn_fused", "knn_streaming")
+# N <= 512 fused, larger N streaming. Both keep the substring ``knn``. Then
+# the sparse attention's forward and backward kernels
+# (ops/sparse_attention.py, under ``trunk_attention`` on the chip), which
+# keep ``sparse_flash``.
+KERNEL_NAMES = ("knn_fused", "knn_streaming", "sparse_flash_fwd", "sparse_flash_bwd")
 # ``host_span`` names: the trainer's host seams (train/trainer.py
 # ``_dispatch`` and ``_drain_chunk``) and set-up (``train.build_trainer``
 # whole; ``Trainer.__init__``'s parameter draw and environment reset).

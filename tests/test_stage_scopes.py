@@ -20,6 +20,7 @@ from marl_distributedformation_tpu.ops.knn_pallas import (
     knn_batch_pallas,
     knn_batch_pallas_big,
 )
+from marl_distributedformation_tpu.ops.sparse_attention import sparse_flash_attention
 from marl_distributedformation_tpu.train import TrainConfig, Trainer
 from marl_distributedformation_tpu.utils.profiling import (
     DEVICE_SCOPES,
@@ -283,7 +284,9 @@ def test_no_name_is_a_primitive_or_helper_of_jax():
     assert len(set(names)) == len(names)
     # what jax itself writes into op_name on the update's path
     assert not set(names) & {"gather", "sort", "shuffle", "scatter-add", "while", "body"}
-    assert all("knn" in name for name in KERNEL_NAMES)  # knn_roofline matches on it
+    # knn_roofline matches on ``knn``, trunk_attention_roofline on
+    # ``sparse_flash``: each kernel is read by one of them
+    assert all(("knn" in name) != ("sparse_flash" in name) for name in KERNEL_NAMES)
 
 
 @pytest.mark.parametrize(
@@ -305,6 +308,27 @@ def test_pallas_call_carries_its_name(name, kernel, n):
     )
     assert "tpu_custom_call" in text
     assert re.findall(r'kernel_name = "(\w+)"', text) == [name]
+
+
+def test_the_attention_kernels_carry_their_names():
+    """Lowered for the TPU from here: the sparse attention alone is its
+    forward kernel; its gradient adds the backward kernel."""
+    s, heads, group, d = 256, 1, 8, 128
+    shapes = (
+        jax.ShapeDtypeStruct((s, heads, group, d), jnp.float32),
+        jax.ShapeDtypeStruct((s, heads, d), jnp.float32),
+        jax.ShapeDtypeStruct((s, heads, d), jnp.float32),
+        jax.ShapeDtypeStruct((s, s), jnp.bool_),
+    )
+
+    def names(fn):
+        text = jax.jit(fn).trace(*shapes).lower(lowering_platforms=("tpu",)).as_text()
+        return sorted(re.findall(r'kernel_name = "(\w+)"', text))
+
+    loss = lambda *args: sparse_flash_attention(*args).sum()  # noqa: E731
+    assert names(sparse_flash_attention) == ["sparse_flash_fwd"]
+    assert names(jax.grad(loss, argnums=(0, 1, 2))) == ["sparse_flash_bwd", "sparse_flash_fwd"]
+    assert {"sparse_flash_fwd", "sparse_flash_bwd"} <= set(KERNEL_NAMES)
 
 
 def test_host_spans_one_dispatch_a_chunk_and_one_drain(tmp_path):
@@ -418,6 +442,34 @@ def test_unattributed_is_the_update_less_its_stages():
     assert read(_context({"ppo_update": 10.0, "minibatch_gather": 6.0})) == pytest.approx(2000.0)
     assert read(_context({"ppo_update": 10.0})) is None
     assert read(_context({"minibatch_gather": 6.0})) is None
+
+
+def test_attention_roofline_reads_the_kernels_or_nothing():
+    """The FLOP attention over the selected keys requires an iteration of
+    Keye's cell (2.79e13), over the seconds of the
+    ``sparse_flash`` kernels' events alone; nothing where they are absent
+    (the blocked XLA path) or where the policy selects no keys."""
+    from benchmarks import costs_attention
+
+    read = _reader("trunk_attention_roofline")
+    cell = harness.load_cell("keye-vl2-a3b-ep8-s8k-train-m2", ROOT)
+    work = costs_attention.selected_attention_flops_per_iteration(cell.config, cell.job)
+    assert work == pytest.approx(2.79e13, rel=2e-3)
+    knn = {"rollout/env_step/knn_streaming/pallas_call::custom-call.3": (12.0, 0.02)}
+    kernels = {
+        **knn,
+        "policy/trunk_attention/sparse_flash_fwd/pallas_call::custom-call.1": (60.0, 0.9),
+        "transpose/trunk_attention/sparse_flash_bwd/pallas_call::custom-call.2": (16.0, 1.1),
+    }
+    context = {
+        "cell": cell, "iterations": 4, "peaks": {"bf16_flops_per_s": 197e12},
+        "trace": {"kernel_s": kernels},
+    }
+    assert read(context) == pytest.approx(100.0 * 4 * work / 2.0 / 197e12)
+    assert read({**context, "trace": {"kernel_s": knn}}) is None
+    assert read({**context, "trace": {}}) is None
+    mlp5 = harness.load_cell("mlp5-train-m262k", ROOT)
+    assert read({**context, "cell": mlp5}) is None
 
 
 def test_dispatch_enqueue_reads_the_mean_span_or_nothing(tmp_path, monkeypatch):

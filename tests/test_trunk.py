@@ -13,6 +13,7 @@ re-rank it, and the seeds here have no such pair.
 """
 
 import dataclasses
+import functools
 import json
 import sys
 import time
@@ -348,6 +349,47 @@ def test_forward_counters_account_for_one_pass(params, obs):
     assert counters["indexer_selected_mean"] == pytest.approx(7.125)
     assert 0.0 < counters["moe_held_share"] < 1.0
     assert 0.0 <= counters["moe_load_max_over_mean"] <= 2.0  # of 2 held; 0: none loaded
+
+
+@pytest.mark.parametrize("topk", [8, S])
+def test_the_attention_kernel_gives_the_blocked_paths_layer(params, monkeypatch, topk):
+    """The chip's path, the Pallas kernel over the swarm (in its interpreter
+    here), against the blocked XLA path this CPU runs: one ``sparse_gqa``
+    layer's output, its gradients with respect to the input and every
+    leaf, and its counters and collected selection; with ``topk`` 8 the
+    indexer masks the keys, with ``topk`` = S the kernel gets no mask.
+    The kernel's products are three bf16 passes: 1e-4 of the largest
+    entry (observed 1e-5)."""
+    from marl_distributedformation_tpu.ops.sparse_attention import sparse_flash_attention
+
+    arch = _arch(topk=topk)
+    lp = jax.tree_util.tree_map(lambda a: a[0], params["params"]["layers"])
+    x = jax.random.normal(jax.random.PRNGKey(7), (S, 64))
+    w = jax.random.normal(jax.random.PRNGKey(8), (S, 64))
+
+    def run():  # a new function each call: traced anew under the path in force
+        layer = lambda x, lp: trunk.trunk_layer(x, lp, arch, collect=True)  # noqa: E731
+        loss = lambda x, lp: (trunk.trunk_layer(x, lp, arch)[0] * w).sum()  # noqa: E731
+        return jax.jit(layer)(x, lp), jax.jit(jax.grad(loss, argnums=(0, 1)))(x, lp)
+
+    (out, counters), grads = run()
+    monkeypatch.setattr(
+        trunk, "_flash", lambda: functools.partial(sparse_flash_attention, interpret=True)
+    )
+    (k_out, k_counters), k_grads = run()
+    _close(k_out, out, 1e-5)
+    assert set(k_counters) == set(counters)
+    for name in counters:
+        np.testing.assert_array_equal(k_counters[name], counters[name])
+    assert float(k_counters["indexer_selected_mean"]) == pytest.approx(
+        7.125 if topk == 8 else (S + 1) / 2
+    )
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, theirs), ours in zip(flat, jax.tree_util.tree_leaves(k_grads)):
+        if np.asarray(theirs).any():
+            _close(ours, theirs, 1e-4)
+        else:  # the indexer's leaves: no gradient on either path
+            assert not np.asarray(ours).any(), jax.tree_util.keystr(path)
 
 
 def test_train_checkpoint_evaluate_round_trip(tmp_path, capsys):
