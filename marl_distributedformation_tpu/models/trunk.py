@@ -11,12 +11,14 @@ A layer is a token mixer, then RMSNorm and a routed expert layer that is
 told which experts it holds, routes over all of them and computes the part
 its own give, with no token dropped (plus the shared expert, where the
 model has one: whole on every chip); the leading ``first_k_dense_replace``
-layers of a model that has them end in a dense SwiGLU instead. How a
+(LFM2: ``num_dense_layers``) layers of a model that has them end in a dense
+SwiGLU instead, each with its own mixer. How a
 sublayer reads what the layers carry and writes its part back is the
 residual path's (``RESIDUALS``): a plain sum, or hyper-connections over
 ``hc_mult`` streams. The mixers (``MIXERS``; equations in
-``benchmarks/reference/policy_trunk.py``, ``policy_trunk_hybrid.py`` and
-``policy_trunk_mla_hc.py``, which the tests hold this module to):
+``benchmarks/reference/policy_trunk.py``, ``policy_trunk_hybrid.py``,
+``policy_trunk_mla_hc.py`` and ``policy_trunk_lfm2.py``, which the tests
+hold this module to):
 
 - ``sparse_gqa`` (Keye-VL-2.0's): grouped-query attention with q/k head
   norms and RoPE over the keys a learned indexer selects (its ``topk``
@@ -32,7 +34,14 @@ residual path's (``RESIDUALS``): a plain sum, or hyper-connections over
   it, queries and keys and values through low-rank halves with a norm
   between, a rotary part under YaRN that all heads' keys share,
   uncompressed keys of ``qk_nope_head_dim + qk_rope_head_dim`` and values of
-  ``v_head_dim`` in ``gated_gqa``'s softmax.
+  ``v_head_dim`` in ``gated_gqa``'s softmax;
+- ``conv`` (LFM2's ``conv`` layers): a gated short convolution, ``[b | c |
+  u] = h W_in``, then ``c`` times a depthwise causal convolution of ``b u``
+  over ``conv_L_cache`` agents (``models/kda.py::short_conv``), then
+  ``W_out``;
+- ``gqa`` (LFM2's ``full_attention`` layers): dense causal grouped-query
+  attention with q/k head norms and RoPE and no gate, in ``gated_gqa``'s
+  softmax.
 
 Of a mixer's heads the chip may hold a share (``head_share``: share i of n
 holds ``heads / n`` query heads with their key heads): the projections'
@@ -53,7 +62,8 @@ How it is computed here:
   forward and backward (``ops/sparse_attention.py``);
 - a mixer's projections that read the normalised input are one product
   with one parameter leaf, the matrices side by side (``w_in`` of
-  ``gated_gqa`` and ``kda``, ``s_in`` of the shared expert);
+  ``gated_gqa``, ``kda``, ``conv`` and ``gqa``, ``s_in`` of the shared
+  expert);
 - the selection needs no sort: a query's ``topk``-th largest index score
   is found by bisection on the float's bits (32 counting passes), ties go
   to the lower index by a running count, and the result is a mask on the
@@ -61,7 +71,8 @@ How it is computed here:
   the recomputation selects nothing twice. The group whose queries see at
   most ``topk`` keys selects everything and skips it;
 - precision: float32 state and activations; products in three bf16 passes
-  (jax's ``high``), the delta rule's state update among them; both
+  (jax's ``high``), the delta rule's state update among them (a
+  convolution's taps and gates are float32 elementwise); both
   selections (the indexer's ``qI . kI`` and the router's logits) and the
   observation embedding at float32 ``highest``: fewer passes there re-rank
   keys around rank ``topk`` and experts around rank
@@ -111,6 +122,10 @@ COUNTERS = (
 # How a counter's values over tokens, sublayers and layers become one: a
 # largest value for these, a mean for the others.
 _OVER = {"hc_sinkhorn_row_err": jnp.max}
+# LFM2's ``layer_types`` -> the mixer of that layer
+_LFM2_KINDS = {"conv": "conv", "full_attention": "gqa"}
+# what LFM2's router adds to the chosen scores' sum before it divides by it
+_LFM2_TOPK_NORM_EPS = 1e-6
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,6 +162,8 @@ class TrunkArch:
     kda_head_dim: int = 0
     kda_conv_size: int = 0
     kda_chunk_size: int = 0
+    # conv: the taps a channel of its convolution
+    conv_size: int = 0
     # mla
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -159,10 +176,12 @@ class TrunkArch:
     dense_layers: int = 0
     intermediate_size: int = 0
     # the router: its scores, a selection bias that does not enter the
-    # weights (``noaux_tc``), the weights' scale
+    # weights (``noaux_tc``), the weights' scale, and what the renormalised
+    # weights' sum is taken with (0: none)
     scoring_func: str = "softmax"
     router_bias: bool = False
     routed_scaling_factor: float = 1.0
+    topk_norm_eps: float = 0.0
     # the residual path (a key of RESIDUALS) and the hyper-connections' numbers
     residual: str = "plain"
     hc_mult: int = 1
@@ -186,52 +205,71 @@ class TrunkArch:
 
     @classmethod
     def from_dict(cls, name: str, data: dict) -> "TrunkArch":
-        """Three shapes of file: Keye-VL-2.0's (``sa_config``, ``num_experts``;
+        """Four shapes of file: Keye-VL-2.0's (``sa_config``, ``num_experts``;
         every layer ``sparse_gqa``), Solar-Open2's (``gqa_layers``,
-        ``linear_attn_config``, ``n_routed_experts``, ``n_shared_experts``) and
+        ``linear_attn_config``, ``n_routed_experts``, ``n_shared_experts``),
         Xing4.0's (``kv_lora_rank``, ``hc_mult``, ``first_k_dense_replace``,
         ``scoring_func``, ``routed_scaling_factor``; every layer ``mla``, the
-        residual path ``hyper``, held from layer ``first_layer_held`` on)."""
+        residual path ``hyper``, held from layer ``first_layer_held`` on) and
+        LFM2's (``layer_types``: ``conv`` and ``full_attention`` layers,
+        ``num_dense_layers``, ``use_expert_bias``, ``conv_L_cache``,
+        ``norm_eps``, ``rope_parameters``; a head ``hidden_size /
+        num_attention_heads`` wide; sigmoid routing)."""
         sa = data.get("sa_config") or {}
         kda = data.get("linear_attn_config") or {}
         yarn = data.get("rope_scaling") or {}
+        rope = data.get("rope_parameters") or {}
         hybrid = "gqa_layers" in data
         mla = "kv_lora_rank" in data
+        lfm2 = "layer_types" in data
         layers = int(data["layers_held"])
         first = int(data.get("first_layer_held", 0))
         share, count = (int(v) for v in data["expert_share"])
         head, head_count = (int(v) for v in data.get("head_share", (0, 1)))
-        # what only Xing4.0's shape of file states, and its reference computes
-        own = dict(
-            q_lora_rank=data["q_lora_rank"],
-            kv_lora_rank=data["kv_lora_rank"],
-            qk_nope_head_dim=data["qk_nope_head_dim"],
-            qk_rope_head_dim=data["qk_rope_head_dim"],
-            v_head_dim=data["v_head_dim"],
-            rope_scaling=tuple(sorted(
-                (key, value) for key, value in yarn.items() if key != "type"
-            )),
-            dense_layers=min(max(int(data["first_k_dense_replace"]) - first, 0), layers),
-            intermediate_size=data["intermediate_size"],
-            scoring_func=data.get("scoring_func", "softmax"),
-            router_bias=data.get("topk_method") == "noaux_tc",
-            routed_scaling_factor=float(data.get("routed_scaling_factor", 1)),
-            residual="hyper",
-            hc_mult=int(data["hc_mult"]),
-            hc_sinkhorn_iters=int(data["hc_sinkhorn_iters"]),
-            hc_eps=float(data["hc_eps"]),
-            hc_res_clamp=(
-                float(data["mhc_h_res_clamp_min"]), float(data["mhc_h_res_clamp_max"])
-            ),
-        ) if mla else {}
+        types = list(data.get("layer_types") or ())
+        own = {}
+        if lfm2:  # what only LFM2's shape of file states, and its reference computes
+            own = dict(
+                conv_size=int(data["conv_L_cache"]),
+                dense_layers=min(max(int(data["num_dense_layers"]) - first, 0), layers),
+                intermediate_size=data["intermediate_size"],
+                scoring_func="sigmoid",
+                router_bias=True,  # use_expert_bias (refused when false)
+                routed_scaling_factor=float(data.get("routed_scaling_factor", 1)),
+                topk_norm_eps=_LFM2_TOPK_NORM_EPS,
+            )
+        if mla:  # what only Xing4.0's shape of file states, and its reference computes
+            own = dict(
+                q_lora_rank=data["q_lora_rank"],
+                kv_lora_rank=data["kv_lora_rank"],
+                qk_nope_head_dim=data["qk_nope_head_dim"],
+                qk_rope_head_dim=data["qk_rope_head_dim"],
+                v_head_dim=data["v_head_dim"],
+                rope_scaling=tuple(sorted(
+                    (key, value) for key, value in yarn.items() if key != "type"
+                )),
+                dense_layers=min(max(int(data["first_k_dense_replace"]) - first, 0), layers),
+                intermediate_size=data["intermediate_size"],
+                scoring_func=data.get("scoring_func", "softmax"),
+                router_bias=data.get("topk_method") == "noaux_tc",
+                routed_scaling_factor=float(data.get("routed_scaling_factor", 1)),
+                residual="hyper",
+                hc_mult=int(data["hc_mult"]),
+                hc_sinkhorn_iters=int(data["hc_sinkhorn_iters"]),
+                hc_eps=float(data["hc_eps"]),
+                hc_res_clamp=(
+                    float(data["mhc_h_res_clamp_min"]), float(data["mhc_h_res_clamp_max"])
+                ),
+            )
         arch = cls(
             name=name,
             hidden_size=data["hidden_size"],
             num_attention_heads=data["num_attention_heads"],
             num_key_value_heads=data["num_key_value_heads"],
-            head_dim=data["qk_nope_head_dim"] + data["qk_rope_head_dim"]
-            if mla else data["head_dim"],
-            rms_norm_eps=data["rms_norm_eps"],
+            head_dim=data["qk_nope_head_dim"] + data["qk_rope_head_dim"] if mla
+            else data["hidden_size"] // data["num_attention_heads"] if lfm2
+            else data["head_dim"],
+            rms_norm_eps=data["norm_eps" if lfm2 else "rms_norm_eps"],
             q_chunk_size=(sa if "q_chunk_size" in sa else data)["q_chunk_size"],
             num_experts=data["n_routed_experts" if hybrid or mla else "num_experts"],
             num_experts_per_tok=data["num_experts_per_tok"],
@@ -242,6 +280,8 @@ class TrunkArch:
             expert_share=(share, count),
             head_share=(head, head_count),
             layer_kinds=tuple(
+                _LFM2_KINDS.get(kind, kind) for kind in types[first : first + layers]
+            ) if lfm2 else tuple(
                 "mla" if mla
                 else ("gated_gqa" if i in data["gqa_layers"] else "kda") if hybrid
                 else "sparse_gqa"
@@ -249,7 +289,9 @@ class TrunkArch:
             ),
             shared_expert_size=data["moe_intermediate_size"]
             * int(data.get("n_shared_experts", 0)),
-            rope_theta=0.0 if hybrid else data["rope_theta"],
+            rope_theta=0.0 if hybrid
+            else float(rope.get("rope_theta", 0.0)) if lfm2
+            else data["rope_theta"],
             indexer_num_heads=sa.get("indexer_num_heads", 0),
             indexer_head_dim=sa.get("indexer_head_dim", 0),
             topk=sa.get("topk", 0),
@@ -272,8 +314,19 @@ class TrunkArch:
             "head_share": not 0 <= head < head_count
             or arch.num_key_value_heads % head_count != 0
             or arch.kda_num_heads % head_count != 0,
-            "sa_config": not hybrid and not mla and not sa,
+            "sa_config": not hybrid and not mla and not lfm2 and not sa,
         }
+        if lfm2:  # what the two mixers, the router and this shape's reference do not compute
+            unsupported.update({
+                "conv_bias": bool(data.get("conv_bias", False)),
+                "layer_types": len(types) != int(data["num_hidden_layers"])
+                or any(kind not in _LFM2_KINDS for kind in types),
+                "use_expert_bias": not data.get("use_expert_bias", False),
+                "rope_parameters": rope.get("rope_type") != "default",
+                "num_dense_layers": int(data["num_dense_layers"]) - first >= layers,
+                "num_attention_heads": data["hidden_size"] % arch.num_attention_heads != 0,
+                "head_dim": data.get("head_dim") not in (None, arch.head_dim),
+            })
         if hybrid:  # what the two mixers and this shape's reference do not compute
             unsupported.update({
                 "use_rope": bool(data.get("use_rope", False)),
@@ -448,13 +501,14 @@ def _attend_block(q: Array, k: Array, v: Array, mask: Array) -> Array:
 
 def route(
     h2: Array, router: Array, top: int, normalise: bool, scoring: str = "softmax",
-    bias: Optional[Array] = None, scale: float = 1.0,
+    bias: Optional[Array] = None, scale: float = 1.0, eps: float = 0.0,
 ):
     """Each token's ``top`` experts of all the router's and the weights
     they combine with; the logits at float32 ``highest``. ``scoring`` makes
     the scores of the logits (``softmax`` over the experts, or ``sigmoid``
     an expert); ``bias`` is added to them for the selection alone (it gets no
-    gradient: the selection is indices); ``scale`` multiplies the weights."""
+    gradient: the selection is indices); ``normalise`` divides the chosen
+    scores by their sum plus ``eps``; ``scale`` multiplies the weights."""
     logits = jnp.einsum("sh,he->se", h2, router, precision=HIGHEST)
     if scoring == "sigmoid":
         scores = jax.nn.sigmoid(logits)
@@ -465,7 +519,11 @@ def route(
     else:
         _, e_top = jax.lax.top_k(scores + bias, top)
         r_top = jnp.take_along_axis(scores, e_top, axis=-1)
-    c = r_top / r_top.sum(-1, keepdims=True) if normalise else r_top
+    if normalise:
+        total = r_top.sum(-1, keepdims=True)
+        c = r_top / (total + eps if eps else total)
+    else:
+        c = r_top
     return e_top, c if scale == 1.0 else scale * c
 
 
@@ -718,18 +776,75 @@ def _causal_softmax(q: Array, k: Array, v: Array, chunk_size: int, whole_pairs: 
     return (summed / jnp.moveaxis(total, -1, 1)[..., None]).reshape(s, -1)
 
 
-def _gated_gqa(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
+def _gqa_project(x: Array, lp: Dict[str, Array], arch: TrunkArch):
+    """A GQA mixer's one product: ``rms(x) @ w_in``, ``w_in = [wq | wk | wv |
+    ...]``, as ``q (S, kv, group, d)``, ``k, v (S, kv, d)`` and the columns
+    after ``wv`` (``gated_gqa``'s gate; none in ``gqa``)."""
     s, hd = x.shape[0], arch.head_dim
     nq, nkv = arch.held(arch.num_attention_heads), arch.held(arch.num_key_value_heads)
+    h = _rms(x, lp["attn_norm"], arch.rms_norm_eps)
+    q, k, v, rest = jnp.split(
+        h @ lp["w_in"], (nq * hd, (nq + nkv) * hd, (nq + 2 * nkv) * hd), 1
+    )
+    q = q.reshape(s, nkv, nq // nkv, hd)
+    k, v = k.reshape(s, nkv, hd), v.reshape(s, nkv, hd)
+    return q, k, v, rest
+
+
+def _gated_gqa(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
     with jax.named_scope("trunk_gated_attention"):
-        h = _rms(x, lp["attn_norm"], arch.rms_norm_eps)
-        q, k, v, gate = jnp.split(
-            h @ lp["w_in"], (nq * hd, (nq + nkv) * hd, (nq + 2 * nkv) * hd), 1
-        )
-        q = q.reshape(s, nkv, nq // nkv, hd)
-        k, v = k.reshape(s, nkv, hd), v.reshape(s, nkv, hd)
+        q, k, v, gate = _gqa_project(x, lp, arch)
         attended = _causal_softmax(q, k, v, arch.q_chunk_size, whole_pairs=False)
         part = (attended * jax.nn.sigmoid(gate)) @ lp["wo"]
+    return part, {}, {}
+
+
+def _gqa_shapes(a: TrunkArch):
+    h, hd = a.hidden_size, a.head_dim
+    nq, nkv = a.held(a.num_attention_heads), a.held(a.num_key_value_heads)
+    return {
+        "attn_norm": (nn.initializers.ones, (h,)),
+        "w_in": (_rbg_normal, (h, (nq + 2 * nkv) * hd)),  # [wq | wk | wv]
+        "q_norm": (nn.initializers.ones, (hd,)),
+        "k_norm": (nn.initializers.ones, (hd,)),
+        "wo": (_rbg_normal, (nq * hd, h)),
+    }
+
+
+def _gqa(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
+    """Dense causal GQA with RMSNorm over each query and key head, then
+    RoPE over the whole head, and no gate: ``gated_gqa``'s projection and
+    softmax."""
+    eps, theta = arch.rms_norm_eps, arch.rope_theta
+    with jax.named_scope("trunk_gqa"):
+        q, k, v, _ = _gqa_project(x, lp, arch)
+        q = _rope(_rms(q, lp["q_norm"], eps), theta)
+        k = _rope(_rms(k, lp["k_norm"], eps), theta)
+        attended = _causal_softmax(q, k, v, arch.q_chunk_size, whole_pairs=False)
+        part = attended @ lp["wo"]
+    return part, {}, {}
+
+
+def _conv_shapes(a: TrunkArch):
+    h = a.hidden_size
+    return {
+        "attn_norm": (nn.initializers.ones, (h,)),
+        "w_in": (_rbg_normal, (h, 3 * h)),  # [B | C | x], the published order
+        "conv": (_rbg_normal, (a.conv_size, h)),  # taps, the last on the token
+        "wo": (_rbg_normal, (h, h)),
+    }
+
+
+def _conv(x: Array, lp: Dict[str, Array], arch: TrunkArch, collect: bool):
+    """LFM2's gated short convolution: ``[b | c | u] = rms(x) W_in``, then
+    ``c * conv(b * u)`` (depthwise, causal over the agents, no bias), then
+    ``W_out``."""
+    with jax.named_scope("trunk_conv"):
+        h = _rms(x, lp["attn_norm"], arch.rms_norm_eps)
+        b, c, u = jnp.split(h @ lp["w_in"], 3, 1)
+        with jax.named_scope("conv_taps"):
+            y = c * short_conv(b * u, lp["conv"])
+        part = y @ lp["wo"]
     return part, {}, {}
 
 
@@ -880,6 +995,8 @@ MIXERS = {
     "gated_gqa": Mixer(_gated_gqa_shapes, _gated_gqa, _rbg_normal, "trunk_gated_attention"),
     "kda": Mixer(_kda_shapes, _kda, _rbg_normal, "trunk_kda"),
     "mla": Mixer(_mla_shapes, _mla, _rbg_normal, "trunk_mla"),
+    "conv": Mixer(_conv_shapes, _conv, _rbg_normal, "trunk_conv"),
+    "gqa": Mixer(_gqa_shapes, _gqa, _rbg_normal, "trunk_gqa"),
 }
 
 
@@ -1060,6 +1177,7 @@ def trunk_layer(
                 e_top, c = route(
                     h2, lp["router"], arch.num_experts_per_tok, arch.norm_topk_prob,
                     arch.scoring_func, lp.get("router_bias"), arch.routed_scaling_factor,
+                    arch.topk_norm_eps,
                 )
             with jax.named_scope("routed_experts"):
                 added, counters = expert_layer(

@@ -78,6 +78,9 @@ DEVICE_SCOPES = (
     "trunk_residual",  # hyper-connections: coefficients, pre-mix, write-back
     "hc_sinkhorn",  # under trunk_residual: the 20 normalisations of H_res
     "dense_ffn",  # a leading dense layer's SwiGLU
+    "trunk_conv",  # a gated short convolution layer's mixer whole
+    "conv_taps",  # under trunk_conv: the B x gate, the taps, the C gate
+    "trunk_gqa",  # dense RoPE GQA: products, head norms, RoPE, blocked softmax
 )
 # ``pl.pallas_call(name=...)`` of the two k-NN kernels (ops/knn_pallas.py):
 # N <= 512 fused, larger N streaming. Both keep the substring ``knn``. Then

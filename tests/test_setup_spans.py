@@ -104,7 +104,24 @@ def test_nested_intervals_count_each_instant_once(tracer):
     assert "counters" not in _spans(tracer)[-1]
 
 
-def test_a_cache_load_is_counted_as_a_hit_not_a_build(tracer, private_ledger):
+@pytest.fixture
+def no_compile_cache():
+    """The persistent cache off: a program an earlier run wrote there
+    would load for real, and add a hit and take away the build that the
+    fed events are counted against."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def test_a_cache_load_is_counted_as_a_hit_not_a_build(
+    tracer, private_ledger, no_compile_cache
+):
     """Fed through ``jax.monitoring`` from inside a ledgered program's
     trace: the span counts the load as ``cache_hits`` and ``cache_load_s``
     and the program's real build as one of ``programs``; the ledger keeps

@@ -61,6 +61,9 @@ PARENTS = {
     ),
     "hc_sinkhorn": ("trunk_residual",),
     "dense_ffn": ("policy", "loss_and_grad"),
+    "trunk_conv": ("policy", "loss_and_grad"),
+    "conv_taps": ("trunk_conv",),
+    "trunk_gqa": ("policy", "loss_and_grad"),
 }
 # In a sparse_gqa trunk, what of these has no tangent (the selection's
 # counting passes, the routing) jax hoists out of the differentiated function,
@@ -68,11 +71,14 @@ PARENTS = {
 # hybrid's layers (each kind one jitted function, traced once) the softmax
 # pairs' causal masks, the routing and the KDA chunks' masks are; of a latent
 # attention layer's, the pairs' masks, the routing, and the loops of the dense
-# layer's blocks and of the residual path's reads.
+# layer's blocks and of the residual path's reads; of a trunk of gated
+# convolutions, the same but for the residual path, and the convolution's
+# zero padding and the slices of its taps.
 HOISTED = {
     "trunk": ("trunk_indexer", "trunk_moe", "routed_experts"),
     "trunk_hybrid": ("trunk_gated_attention", "trunk_moe", "trunk_kda"),
     "trunk_mla_hc": ("trunk_mla", "trunk_moe", "trunk_residual", "dense_ffn"),
+    "trunk_lfm2": ("trunk_conv", "trunk_gqa", "trunk_moe", "dense_ffn"),
 }
 GNN_ONLY = ("neighbor_gather",)
 # what only a sparse_gqa layer opens, what every trunk layer opens, and what
@@ -80,10 +86,14 @@ GNN_ONLY = ("neighbor_gather",)
 SPARSE_ONLY = ("trunk_attention", "trunk_indexer")
 HYBRID_ONLY = ("trunk_gated_attention", "trunk_kda", "kda_recurrence")
 # what only a trunk of latent attention under the hyper residual opens
-MLA_ONLY = ("trunk_mla", "mla_softmax", "trunk_residual", "hc_sinkhorn", "dense_ffn")
+MLA_ONLY = ("trunk_mla", "mla_softmax", "trunk_residual", "hc_sinkhorn")
+# what only a trunk of gated short convolutions and dense RoPE GQA opens, and
+# what it shares with the hyper trunk alone
+LFM2_ONLY = ("trunk_conv", "conv_taps", "trunk_gqa")
+DENSE_LAYER = ("dense_ffn",)
 TRUNK_ONLY = (
     SPARSE_ONLY + ("trunk_moe", "router", "routed_experts", "shared_expert")
-    + HYBRID_ONLY + MLA_ONLY
+    + HYBRID_ONLY + MLA_ONLY + LFM2_ONLY + DENSE_LAYER
 )
 # Rows of 13 floats pack, eight to a 128-lane row of the table, so the
 # sub-row is picked; a formation's rows (8 agents x 21 floats) are over one
@@ -91,7 +101,7 @@ TRUNK_ONLY = (
 MLP_ONLY = ("row_pack", "subrow_pick")
 
 
-POLICIES = ("mlp", "gnn", "trunk", "trunk_hybrid", "trunk_mla_hc")
+POLICIES = ("mlp", "gnn", "trunk", "trunk_hybrid", "trunk_mla_hc", "trunk_lfm2")
 
 
 def _tiny_trainer(policy, tmp_path, **config):
@@ -110,6 +120,7 @@ def _tiny_trainer(policy, tmp_path, **config):
     else:  # 16 agents: the second block of 8 queries sees more than topk 8
         name = {
             "trunk": "tiny", "trunk_hybrid": "tiny-hybrid", "trunk_mla_hc": "tiny-mla-hc",
+            "trunk_lfm2": "tiny-lfm2",
         }[policy]
         agents, model = 16, TrunkActorCritic(arch=load_trunk_arch(name), k=3)
     return Trainer(
@@ -160,9 +171,12 @@ def test_scope_is_an_exact_path_part_under_its_parent(op_paths, scope, policy):
     absent = {
         "mlp": GNN_ONLY + TRUNK_ONLY,
         "gnn": MLP_ONLY + TRUNK_ONLY,
-        "trunk": MLP_ONLY + GNN_ONLY + ("shared_expert",) + HYBRID_ONLY + MLA_ONLY,
-        "trunk_hybrid": MLP_ONLY + GNN_ONLY + SPARSE_ONLY + MLA_ONLY,
-        "trunk_mla_hc": MLP_ONLY + GNN_ONLY + SPARSE_ONLY + HYBRID_ONLY,
+        "trunk": MLP_ONLY + GNN_ONLY + ("shared_expert",) + HYBRID_ONLY + MLA_ONLY
+        + LFM2_ONLY + DENSE_LAYER,
+        "trunk_hybrid": MLP_ONLY + GNN_ONLY + SPARSE_ONLY + MLA_ONLY + LFM2_ONLY + DENSE_LAYER,
+        "trunk_mla_hc": MLP_ONLY + GNN_ONLY + SPARSE_ONLY + HYBRID_ONLY + LFM2_ONLY,
+        "trunk_lfm2": MLP_ONLY + GNN_ONLY + SPARSE_ONLY + ("shared_expert",) + HYBRID_ONLY
+        + MLA_ONLY,
     }
     if scope in absent[policy]:
         assert not found
@@ -184,7 +198,10 @@ def _minibatch_gathers(text):
 
 @pytest.mark.parametrize(
     "policy,gathers",
-    [("mlp", 1), ("gnn", 5), ("trunk", 5), ("trunk_hybrid", 5), ("trunk_mla_hc", 5)],
+    [
+        ("mlp", 1), ("gnn", 5), ("trunk", 5), ("trunk_hybrid", 5), ("trunk_mla_hc", 5),
+        ("trunk_lfm2", 5),
+    ],
 )
 def test_a_minibatch_is_one_gather_where_rows_pack(compiled_text, policy, gathers):
     """Packed rows are looked up once a minibatch; a leaf at a time (five
@@ -407,6 +424,9 @@ SCOPE_READERS = {
     "trunk_hyper_residual_ms": "trunk_residual",
     "trunk_hyper_sinkhorn_ms": "hc_sinkhorn",
     "trunk_dense_ffn_ms": "dense_ffn",
+    "trunk_conv_ms": "trunk_conv",
+    "trunk_conv_taps_ms": "conv_taps",
+    "trunk_gqa_ms": "trunk_gqa",
 }
 
 
