@@ -59,7 +59,8 @@ How it is computed here:
   diagonal, all of one shape, a block's tiles put together afterwards). On
   the chip ``sparse_gqa``'s blocks select their keys alone and one Pallas
   kernel attends over the swarm under their mask, its score tiles in VMEM
-  forward and backward (``ops/sparse_attention.py``);
+  forward and backward (``ops/sparse_attention.py``); the dense mixers
+  (``gated_gqa``, ``gqa``, ``mla``) take the same kernel with no mask;
 - a mixer's projections that read the normalised input are one product
   with one parameter leaf, the matrices side by side (``w_in`` of
   ``gated_gqa``, ``kda``, ``conv`` and ``gqa``, ``s_in`` of the shared
@@ -478,9 +479,10 @@ def _query_groups(s: int, chunk: int, topk: int):
 
 
 def _flash() -> Optional[Callable]:
-    """Attention over the selected keys as one Pallas kernel over the swarm
-    (``ops/sparse_attention.py``), where the chip runs it; elsewhere None,
-    and each block goes through ``_attend_block``."""
+    """Attention over the selected keys, or under the causal mask alone, as
+    one Pallas kernel over the swarm (``ops/sparse_attention.py``), where the
+    chip runs it; elsewhere None, and each block goes through
+    ``_attend_block`` (``_causal_softmax``'s pairs for the dense mixers)."""
     return sparse_flash_attention if jax.default_backend() == "tpu" else None
 
 
@@ -739,13 +741,23 @@ def _causal_tiles(s: int, chunk: int):
 def _causal_softmax(q: Array, k: Array, v: Array, chunk_size: int, whole_pairs: bool):
     """Dense causal attention without the keys the mask hides, for ``q (S, kv,
     group, d)``, ``k (S, kv, d)`` and ``v (S, kv, dv)`` -> ``(S, kv * group *
-    dv)``; the keys' width and the values' may differ. One loop over the
-    (query block, key tile) pairs under the diagonal, every pair of one shape
-    (so one loop body in each pass), and a block's tiles put together by their
-    largest scores and weight sums, as a softmax over all of them. With
-    ``whole_pairs`` a pair takes its slices again in the backward pass: where
-    every query head has a key head of its own a tile of keys and values is
-    too large to keep for each pair."""
+    dv)``; the keys' width and the values' may differ. Where the chip runs it
+    (``_flash()``), the flash kernel over the swarm with no mask, its score
+    tiles in VMEM; elsewhere ``_causal_pairs``."""
+    flash = _flash()
+    if flash is None:
+        return _causal_pairs(q, k, v, chunk_size, whole_pairs)
+    return flash(q, k, v)
+
+
+def _causal_pairs(q: Array, k: Array, v: Array, chunk_size: int, whole_pairs: bool):
+    """``_causal_softmax`` as one loop over the (query block, key tile) pairs
+    under the diagonal, every pair of one shape (so one loop body in each
+    pass), and a block's tiles put together by their largest scores and
+    weight sums, as a softmax over all of them. With ``whole_pairs`` a pair
+    takes its slices again in the backward pass: where every query head has a
+    key head of its own a tile of keys and values is too large to keep for
+    each pair."""
     s = q.shape[0]
     chunk, tile, pairs = _causal_tiles(s, chunk_size)
     block_of = jnp.array([i for i, _ in pairs])

@@ -3,17 +3,20 @@ kernels: the flash form, an online softmax over tiles of keys whose scores
 and weights stay in VMEM, forward and backward, tied by ``jax.custom_vjp``.
 
 What it computes is ``models/trunk.py``'s ``_attend_block`` over a whole
-swarm: ``q (S, kv, group, d)``, ``k, v (S, kv, d)`` and a mask ``(S, S)`` of
-the keys each query selects (the trunk's indexer; ``None`` is the causal
-mask) -> ``(S, kv * group * d)``, a softmax at scale ``d ** -0.5`` over the
-selected keys alone. Every query must select at least one key.
+swarm: ``q (S, kv, group, dk)``, ``k (S, kv, dk)``, ``v (S, kv, dv)`` and a
+mask ``(S, S)`` of the keys each query selects (the trunk's indexer; ``None``
+is the causal mask alone, the trunk's dense mixers) -> ``(S, kv * group *
+dv)``, a softmax at scale ``dk ** -0.5`` over the selected keys alone. Every
+query must select at least one key. Values may be narrower than keys
+(latent attention's 128 against 192).
 
 How:
 
 - a grid step is one key head, a tile of ``block_q`` queries and a tile of
   ``block_k`` keys; the ``group`` query heads that share the key head are
   folded into the tile's rows (head-major), so a tile of keys, values and
-  the mask is read once for all of them;
+  the mask is read once for all of them. ``block_q`` follows the group: about
+  1,024 rows a tile whatever the group (``query_block``);
 - tiles wholly above the diagonal are not computed, forward or backward,
   and their index maps repeat the last tile under it, so nothing is fetched
   for them either;
@@ -26,8 +29,9 @@ How:
   kernels; queries and output gradients once a tile of queries; the
   weights and their gradients in every step;
 - the backward pass is one kernel: with the query tile outer it sums dQ
-  in a scratch, and dK, dV (as ``(d, keys)``, so that no tile is
-  transposed in the kernel) in blocks that stay in VMEM for the key head.
+  in a scratch, and dK, dV (as ``(dk, keys)`` and ``(dv, keys)``, so that no
+  tile is transposed in the kernel) in blocks that stay in VMEM for the key
+  head.
 
 Where the chip does not run it, ``interpret=True`` runs the same kernels
 through Pallas's interpreter (the CPU tests).
@@ -46,14 +50,17 @@ from jax.experimental.pallas import tpu as pltpu
 Array = jax.Array
 
 # Queries and keys of a grid step. 128 queries x 8 heads make 1,024 rows
-# of scores over 512 keys: 2 MiB of float32 a score-sized temporary.
+# of scores over 512 keys: 2 MiB of float32 a score-sized temporary. A group
+# of fewer heads takes more queries a tile, to the same rows (``query_block``).
 BLOCK_Q = 128
 BLOCK_K = 512
+_ROWS = 1024
 _LANES = 128
 # A masked score: finite, so that exp(masked - masked) is 1 and not NaN.
 _MASKED = -0.7 * float(jnp.finfo(jnp.float32).max)
 # The backward kernel keeps dK and dV of a key head, 4 MiB each at 8,192
-# keys of 128, twice over for the pipeline, beside its tiles.
+# keys of 128 (dK 6 MiB at keys of 192), twice over for the pipeline, beside
+# its tiles.
 _VMEM_LIMIT = 96 * 1024 * 1024
 _ONE_PASS = jax.lax.Precision.DEFAULT
 _NN = (((1,), (0,)), ((), ()))
@@ -85,11 +92,18 @@ def _dot3(a, b, dims):
 
 def _lanes(stat: Array, width: int) -> Array:
     """A row statistic held as ``(rows, 128)`` equal lanes, at ``width``."""
-    if width == _LANES:
-        return stat
-    if width % _LANES == 0:
-        return jnp.tile(stat, (1, width // _LANES))
-    return stat[:, :width]
+    if width > _LANES:
+        stat = jnp.tile(stat, (1, -(-width // _LANES)))
+    return stat if stat.shape[1] == width else stat[:, :width]
+
+
+def query_block(group: int, s: int, block_k: int = BLOCK_K) -> int:
+    """Queries a tile for ``group`` query heads a key head: ``_ROWS`` rows of
+    scores (group 8 takes ``BLOCK_Q``, group 1 eight times that), at least
+    ``BLOCK_Q`` and no more than the swarm padded to whole key tiles. Beside
+    the backward's resident dK and dV a tile's temporaries then take what
+    they take at group 8."""
+    return max(BLOCK_Q, min(_ROWS // group, -(-s // block_k) * block_k))
 
 
 def _last_tile(i, block_q: int, block_k: int):
@@ -129,14 +143,14 @@ def _forward_kernel(
 ):
     mask_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
     o_ref, lse_ref, q_hi, q_lo, m_ref, l_ref, acc_ref = refs
-    group, _, d = q_ref.shape
+    (group, _, dk), dv = q_ref.shape, o_ref.shape[-1]
     rows = group * block_q
     i, j = pl.program_id(1), pl.program_id(2)
     last = _last_tile(i, block_q, block_k)
 
     @pl.when(j == 0)
     def _start():
-        q_hi[...], q_lo[...] = split(q_ref[...].reshape(rows, d))
+        q_hi[...], q_lo[...] = split(q_ref[...].reshape(rows, dk))
         m_ref[...] = jnp.full(m_ref.shape, -jnp.inf, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
@@ -154,18 +168,19 @@ def _forward_kernel(
         l_ref[...] = alpha * l_ref[...] + p.sum(axis=1, keepdims=True)
         m_ref[...] = m_next
         pv = _dot3(split(p), (v_hi_ref[...], v_lo_ref[...]), _NN)
-        acc_ref[...] = acc_ref[...] * _lanes(alpha, d) + pv
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, dv) + pv
 
     @pl.when(j == last)
     def _end():
         l = l_ref[...]
-        o_ref[...] = (acc_ref[...] / _lanes(l, d)).reshape(group, block_q, d)
+        o_ref[...] = (acc_ref[...] / _lanes(l, dv)).reshape(group, block_q, dv)
         lse_ref[...] = (m_ref[...] + jnp.log(l)).reshape(group, block_q, _LANES)
 
 
-def _kv_specs(block_q: int, block_k: int, d: int):
-    """Keys, values and the mask of step ``(h, i, j)``; a step above the
-    diagonal keeps the tile it had, so it fetches nothing."""
+def _kv_specs(block_q: int, block_k: int, widths):
+    """Keys' and values' parts of the given ``widths`` and the mask of step
+    ``(h, i, j)``; a step above the diagonal keeps the tile it had, so it
+    fetches nothing."""
 
     def kv(h, i, j):
         return h, jnp.minimum(j, _last_tile(i, block_q, block_k)), 0
@@ -173,39 +188,44 @@ def _kv_specs(block_q: int, block_k: int, d: int):
     def mask(h, i, j):
         return i, jnp.minimum(j, _last_tile(i, block_q, block_k))
 
-    return pl.BlockSpec((None, block_k, d), kv), pl.BlockSpec((block_q, block_k), mask)
+    specs = [pl.BlockSpec((None, block_k, d), kv) for d in widths]
+    return specs, pl.BlockSpec((block_q, block_k), mask)
+
+
+def _rows_spec(group: int, block_q: int, d: int):
+    """The tile of queries' rows ``(group, block_q, d)`` of step ``(h, i, j)``."""
+    return pl.BlockSpec((None, group, block_q, d), lambda h, i, j: (h, 0, i, 0))
 
 
 def _forward(q, kv_parts, mask, block_q: int, block_k: int, interpret: bool):
-    """``q (kv, group, S, d)`` float32, keys' and values' parts ``(kv, S,
-    d)`` bf16 -> ``o (kv, group, S, d)`` and the rows' log-sum-exp ``(kv,
-    group, S, 128)``."""
-    heads, group, s, d = q.shape
+    """``q (kv, group, S, dk)`` float32, keys' parts ``(kv, S, dk)`` and
+    values' ``(kv, S, dv)`` bf16 -> ``o (kv, group, S, dv)`` and the rows'
+    log-sum-exp ``(kv, group, S, 128)``."""
+    heads, group, s, dk = q.shape
+    dv = kv_parts[2].shape[-1]
     rows = group * block_q
-    kv_spec, mask_spec = _kv_specs(block_q, block_k, d)
-    q_spec = pl.BlockSpec((None, group, block_q, d), lambda h, i, j: (h, 0, i, 0))
-    lse_spec = pl.BlockSpec(
-        (None, group, block_q, _LANES), lambda h, i, j: (h, 0, i, 0)
-    )
+    kv_specs, mask_spec = _kv_specs(block_q, block_k, [p.shape[-1] for p in kv_parts])
+    lse_spec = _rows_spec(group, block_q, _LANES)
     masked = mask is not None
     return pl.pallas_call(
         functools.partial(
-            _forward_kernel, scale=d**-0.5, block_q=block_q, block_k=block_k,
+            _forward_kernel, scale=dk**-0.5, block_q=block_q, block_k=block_k,
             masked=masked,
         ),
         grid=(heads, s // block_q, s // block_k),
-        in_specs=[q_spec] + [kv_spec] * 4 + ([mask_spec] if masked else []),
-        out_specs=[q_spec, lse_spec],
+        in_specs=[_rows_spec(group, block_q, dk)] + kv_specs
+        + ([mask_spec] if masked else []),
+        out_specs=[_rows_spec(group, block_q, dv), lse_spec],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, jnp.float32),
+            jax.ShapeDtypeStruct((heads, group, s, dv), jnp.float32),
             jax.ShapeDtypeStruct((heads, group, s, _LANES), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((rows, d), jnp.bfloat16),
-            pltpu.VMEM((rows, d), jnp.bfloat16),
+            pltpu.VMEM((rows, dk), jnp.bfloat16),
+            pltpu.VMEM((rows, dk), jnp.bfloat16),
             pltpu.VMEM((rows, _LANES), jnp.float32),
             pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
@@ -229,7 +249,7 @@ def _backward_kernel(
     mask_ref, refs = (refs[0], refs[1:]) if masked else (None, refs)
     (dq_ref, dkt_ref, dvt_ref,
      q_hi, q_lo, qt_hi, qt_lo, do_hi, do_lo, dot_hi, dot_lo, di_ref, dq_acc) = refs
-    group, _, d = q_ref.shape
+    (group, _, dk), dv = q_ref.shape, do_ref.shape[-1]
     rows = group * block_q
     i, j = pl.program_id(1), pl.program_id(2)
     last = _last_tile(i, block_q, block_k)
@@ -241,13 +261,13 @@ def _backward_kernel(
 
     @pl.when(j == 0)
     def _start():
-        do = do_ref[...].reshape(rows, d)
-        q_hi[...], q_lo[...] = split(q_ref[...].reshape(rows, d))
+        do = do_ref[...].reshape(rows, dv)
+        q_hi[...], q_lo[...] = split(q_ref[...].reshape(rows, dk))
         do_hi[...], do_lo[...] = split(do)
         # (d, rows) from (group, d, block_q): the heads side by side
         qt_hi[...], qt_lo[...] = split(jnp.concatenate(list(qt_ref[...]), axis=1))
         dot_hi[...], dot_lo[...] = split(jnp.concatenate(list(dot_ref[...]), axis=1))
-        di = (o_ref[...].reshape(rows, d) * do).sum(axis=1, keepdims=True)
+        di = (o_ref[...].reshape(rows, dv) * do).sum(axis=1, keepdims=True)
         di_ref[...] = jnp.broadcast_to(di, di_ref.shape)
         dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
@@ -266,40 +286,51 @@ def _backward_kernel(
 
     @pl.when(j == last)
     def _end():
-        dq_ref[...] = (scale * dq_acc[...]).reshape(group, block_q, d)
+        dq_ref[...] = (scale * dq_acc[...]).reshape(group, block_q, dk)
 
 
 def _backward(q, kv_parts, mask, o, lse, do, block_q: int, block_k: int, interpret: bool):
-    """dQ ``(kv, group, S, d)``; dK, dV ``(kv, S, d)``."""
-    heads, group, s, d = q.shape
+    """dQ ``(kv, group, S, dk)``; dK ``(kv, S, dk)``, dV ``(kv, S, dv)``."""
+    heads, group, s, dk = q.shape
+    dv = do.shape[-1]
     rows, tiles = group * block_q, s // block_k
-    kv_spec, mask_spec = _kv_specs(block_q, block_k, d)
-    rows_spec = pl.BlockSpec((None, group, block_q, d), lambda h, i, j: (h, 0, i, 0))
-    cols_spec = pl.BlockSpec((None, group, d, block_q), lambda h, i, j: (h, 0, 0, i))
-    lse_spec = pl.BlockSpec(
-        (None, group, block_q, _LANES), lambda h, i, j: (h, 0, i, 0)
-    )
+    kv_specs, mask_spec = _kv_specs(block_q, block_k, [p.shape[-1] for p in kv_parts])
+
+    def rows_spec(d):
+        return _rows_spec(group, block_q, d)
+
+    def cols_spec(d):
+        return pl.BlockSpec((None, group, d, block_q), lambda h, i, j: (h, 0, 0, i))
+
     # dK and dV of the whole key head, as (tiles, d, block_k): in VMEM
     # across the head's steps, a tile at a time by its leading index
-    keys_spec = pl.BlockSpec((None, tiles, d, block_k), lambda h, i, j: (h, 0, 0, 0))
-    keys_shape = jax.ShapeDtypeStruct((heads, tiles, d, block_k), jnp.float32)
+    def keys_spec(d):
+        return pl.BlockSpec((None, tiles, d, block_k), lambda h, i, j: (h, 0, 0, 0))
+
+    def keys_shape(d):
+        return jax.ShapeDtypeStruct((heads, tiles, d, block_k), jnp.float32)
+
     masked = mask is not None
     bf16 = lambda shape: pltpu.VMEM(shape, jnp.bfloat16)  # noqa: E731
     dq, dkt, dvt = pl.pallas_call(
         functools.partial(
-            _backward_kernel, scale=d**-0.5, block_q=block_q, block_k=block_k,
+            _backward_kernel, scale=dk**-0.5, block_q=block_q, block_k=block_k,
             masked=masked,
         ),
         grid=(heads, s // block_q, tiles),
-        in_specs=[rows_spec, cols_spec, rows_spec, cols_spec, rows_spec, lse_spec]
-        + [kv_spec] * 4 + ([mask_spec] if masked else []),
-        out_specs=[rows_spec, keys_spec, keys_spec],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, jnp.float32), keys_shape, keys_shape],
+        in_specs=[
+            rows_spec(dk), cols_spec(dk), rows_spec(dv), cols_spec(dv), rows_spec(dv),
+            rows_spec(_LANES),
+        ] + kv_specs + ([mask_spec] if masked else []),
+        out_specs=[rows_spec(dk), keys_spec(dk), keys_spec(dv)],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, jnp.float32), keys_shape(dk), keys_shape(dv)
+        ],
         scratch_shapes=[
-            bf16((rows, d)), bf16((rows, d)), bf16((d, rows)), bf16((d, rows)),
-            bf16((rows, d)), bf16((rows, d)), bf16((d, rows)), bf16((d, rows)),
+            bf16((rows, dk)), bf16((rows, dk)), bf16((dk, rows)), bf16((dk, rows)),
+            bf16((rows, dv)), bf16((rows, dv)), bf16((dv, rows)), bf16((dv, rows)),
             pltpu.VMEM((rows, _LANES), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
+            pltpu.VMEM((rows, dk), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
@@ -313,9 +344,9 @@ def _backward(q, kv_parts, mask, o, lse, do, block_q: int, block_k: int, interpr
     )
 
     def keys(t):  # (kv, tiles, d, block_k) -> (kv, S, d)
-        return jnp.swapaxes(t, 2, 3).reshape(heads, s, d)
+        return jnp.swapaxes(t, 2, 3).reshape(heads, s, t.shape[2])
 
-    return dq, d**-0.5 * keys(dkt), keys(dvt)
+    return dq, dk**-0.5 * keys(dkt), keys(dvt)
 
 
 # ----------------------------------------------------------------------
@@ -348,16 +379,18 @@ def sparse_flash_attention(
     k: Array,
     v: Array,
     mask: Optional[Array] = None,
-    block_q: int = BLOCK_Q,
+    block_q: Optional[int] = None,
     block_k: int = BLOCK_K,
     interpret: bool = False,
 ) -> Array:
-    """``q (S, kv, group, d)``, ``k, v (S, kv, d)``, ``mask (S, S)`` (bool,
-    ``None``: causal) -> ``(S, kv * group * d)``: each query's softmax over
-    the keys it selects among those at or before it. A swarm the tiles do
-    not divide is padded to them: a padded query sees the keys causally (and
-    is dropped), a padded key is after every real query."""
-    s, heads, group, d = q.shape
+    """``q (S, kv, group, dk)``, ``k (S, kv, dk)``, ``v (S, kv, dv)``, ``mask
+    (S, S)`` (bool, ``None``: causal) -> ``(S, kv * group * dv)``: each
+    query's softmax over the keys it selects among those at or before it.
+    ``block_q`` None takes ``query_block``'s. A swarm the tiles do not divide
+    is padded to them: a padded query sees the keys causally (and is
+    dropped), a padded key is after every real query."""
+    s, heads, group, _ = q.shape
+    block_q = block_q or query_block(group, s, block_k)
     tile = max(block_q, block_k)
     padded = -(-s // tile) * tile
 

@@ -20,6 +20,7 @@ or of one (``test_a_short_sinkhorn_fails_these_tolerances``).
 """
 
 import dataclasses
+import functools
 import json
 import math
 import sys
@@ -39,6 +40,7 @@ from marl_distributedformation_tpu.algo import PPOConfig
 from marl_distributedformation_tpu.algo.ppo import MinibatchData, ppo_loss
 from marl_distributedformation_tpu.models import trunk
 from marl_distributedformation_tpu.models.trunk import TrunkActorCritic, TrunkArch
+from marl_distributedformation_tpu.ops.sparse_attention import sparse_flash_attention
 from marl_distributedformation_tpu.utils.config import _parse_value
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -209,6 +211,24 @@ def test_a_part_alone_matches_the_reference_forward_and_gradient(params, part):
         assert ((np.asarray(plain) > 0) != chosen).any()  # the bias selected
 
 
+def _minibatch(obs):
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    return {
+        "obs": obs,
+        "actions": jax.random.normal(keys[0], (3, S, 2)),
+        "log_probs": -2.0 + 0.1 * jax.random.normal(keys[1], (3, S)),
+        "advantages": jax.random.normal(keys[2], (3, S)),
+        "returns": jax.random.normal(keys[3], (3, S)),
+    }
+
+
+def _data(mb):
+    return MinibatchData(
+        obs=mb["obs"], actions=mb["actions"], old_log_probs=mb["log_probs"],
+        advantages=mb["advantages"], returns=mb["returns"],
+    )
+
+
 def test_the_whole_policy_matches_forward_and_ppo_gradient(params, obs):
     """Mean and value, then the program's ``ppo_loss`` through its policy
     against the reference's ``loss_fn`` through its own, leaf by leaf."""
@@ -220,14 +240,7 @@ def test_the_whole_policy_matches_forward_and_ppo_gradient(params, obs):
     _close(value, r_value, OUT)
     np.testing.assert_array_equal(log_std, r_log_std)
 
-    keys = jax.random.split(jax.random.PRNGKey(5), 4)
-    mb = {
-        "obs": obs,
-        "actions": jax.random.normal(keys[0], (3, S, 2)),
-        "log_probs": -2.0 + 0.1 * jax.random.normal(keys[1], (3, S)),
-        "advantages": jax.random.normal(keys[2], (3, S)),
-        "returns": jax.random.normal(keys[3], (3, S)),
-    }
+    mb = _minibatch(obs)
     config = {"policy": POLICY, "env": ENV, "ppo": {
         "normalize_advantage": True, "clip_range": 0.2, "ent_coef": 0.01,
         "vf_coef": 0.5,
@@ -235,10 +248,7 @@ def test_the_whole_policy_matches_forward_and_ppo_gradient(params, obs):
     r_loss, r_grads = jax.jit(
         jax.value_and_grad(lambda p: reference_ppo.loss_fn(p, config, r_apply, mb))
     )(params)
-    data = MinibatchData(
-        obs=mb["obs"], actions=mb["actions"], old_log_probs=mb["log_probs"],
-        advantages=mb["advantages"], returns=mb["returns"],
-    )
+    data = _data(mb)
     (loss, _), grads = jax.jit(
         jax.value_and_grad(
             lambda p: ppo_loss(p, model.apply, data, PPOConfig()), has_aux=True
@@ -253,6 +263,42 @@ def test_the_whole_policy_matches_forward_and_ppo_gradient(params, obs):
             continue
         assert np.asarray(theirs).any(), name  # everything else is trained
         _close(ours, theirs, GRAD)
+
+
+def test_the_flash_kernel_gives_the_pairs_paths_policy(params, obs, monkeypatch):
+    """The chip's path for ``mla``, the Pallas kernel with no mask (in its
+    interpreter here, the swarm padded to its tile), against the pairs loop
+    this CPU runs: the policy's mean and value, the PPO loss and its
+    gradient in every leaf. The kernel multiplies in three bf16 passes:
+    ``OUT`` and ``GRAD`` as above."""
+    model = TrunkActorCritic(arch=_arch(), k=K)
+    data = _data(_minibatch(obs))
+
+    def run():  # new functions each call: traced anew under the path in force
+        out = jax.jit(lambda p, x: model.apply(p, x))(params, obs)
+        loss = lambda p: ppo_loss(p, model.apply, data, PPOConfig())[0]  # noqa: E731
+        return out, jax.jit(jax.value_and_grad(loss))(params)
+
+    (mean, _, value), (loss, grads) = run()
+    taken = []
+
+    def flash():
+        taken.append(True)
+        return functools.partial(sparse_flash_attention, interpret=True)
+
+    monkeypatch.setattr(trunk, "_flash", flash)
+    jax.clear_caches()  # the layers' scan keeps what it traced
+    (k_mean, _, k_value), (k_loss, k_grads) = run()
+    assert taken
+    _close(k_mean, mean, OUT)
+    _close(k_value, value, OUT)
+    np.testing.assert_allclose(k_loss, loss, rtol=1e-5)
+    flat, _ = jax.tree_util.tree_flatten_with_path(k_grads)
+    for (path, ours), theirs in zip(flat, jax.tree_util.tree_leaves(grads)):
+        if np.asarray(theirs).any():
+            _close(ours, theirs, GRAD)
+        else:  # the router's bias, a held expert no token chose
+            assert not np.asarray(ours).any(), jax.tree_util.keystr(path)
 
 
 def test_the_bfloat16_control_fails_these_tolerances(params, obs):
